@@ -413,8 +413,7 @@ class Expander:
         commutative/associative even under wrap, so the two lowerings
         produce equal buffers) — ANY dot embedded in the fused engine
         step costs ~1.3s of XLA:CPU compile per traced program, which
-        tier-1 pays per engine instance (same fallback posture as the
-        Pallas dedup kernel's interpret mode)."""
+        tier-1 pays per engine instance)."""
         dg = self._dgroup
         tv = psi_c[jnp.asarray(dg["t_srcu"])] * \
             jnp.asarray(dg["t_w"])[:, None]               # [T, cap]

@@ -996,119 +996,6 @@ def raft_server_signature(fpr, svT: Dict, prep: Dict) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Pallas probe/claim-insert dedup kernel (the MXU-path third piece,
-# round 9).  The lax formulation (engine/bfs._probe_insert_lax) round-
-# trips every probe outcome through XLA gather/scatter ops — each outer
-# iteration is a walk (gathers) plus a 4-scatter resolve round, with
-# the whole FCAP lane vector re-materialized between them.  This kernel
-# fuses the entire probe → compare → claim walk per candidate block
-# into ONE device kernel: the table stays resident, each lane walks its
-# quadratic probe path with scalar loads and claims an empty slot with
-# an in-kernel store.
-#
-# Determinism/CAS note: lanes are processed in ascending index order
-# inside one sequential kernel, which IS the lax path's rank tie-break
-# (every engine passes ranks = jnp.arange(M)), so outcomes — fresh
-# set, final slots, table contents — are bit-identical to the parallel
-# claim/scatter-min formulation (the parallel loop converges to exactly
-# the sequential-by-rank fixpoint; _host_probe_assign is the same
-# sequential twin on host).  tests/test_guard_matmul.py pins kernel ≡
-# lax on forced-collision fixtures.
-#
-# interpret=True is the CPU fallback: tier-1 and the oracle
-# differential tests run the kernel through the Pallas interpreter
-# (dedup_kernel="on" off-TPU), so the TPU path's semantics are pinned
-# without TPU hardware attached.
-# ---------------------------------------------------------------------------
-
-
-def probe_claim_insert_pallas(table, keys, live, *, max_rounds: int,
-                              interpret: bool):
-    """Drop-in for the lax claim-insert (ranks == arange contract —
-    see engine/bfs._probe_insert): (table W×u32[VCAP], keys W×u32[M],
-    live bool[M]) -> (table', fresh bool[M], pos i32[M], hovf bool)."""
-    from functools import reduce
-
-    from jax.experimental import pallas as pl
-
-    from ..utils import HOME_SALT
-
-    W = len(table)
-    VCAP = int(table[0].shape[0])
-    M = int(keys[0].shape[0])
-    tbl = jnp.stack(table)                      # [W, VCAP]
-    ks = jnp.stack(keys)                        # [W, M]
-
-    def kernel(ks_ref, live_ref, tbl_in_ref, tbl_ref, fresh_ref,
-               pos_ref, hovf_ref):
-        # tbl_in_ref aliases tbl_ref (input_output_aliases): all table
-        # reads/writes go through the OUTPUT ref so the walk always
-        # sees its own earlier claims.
-        del tbl_in_ref
-
-        def lane(m, hovf):
-            lk = [ks_ref[w, m] for w in range(W)]
-            h = jnp.uint32(HOME_SALT)
-            for w in range(W):
-                h = fmix32(h ^ lk[w])
-            pos0 = (h & jnp.uint32(VCAP - 1)).astype(jnp.int32)
-            is_live = live_ref[m] != 0
-
-            def cond(st):
-                pos, t, resolved, fresh, rounds = st
-                return ~resolved & (rounds < max_rounds)
-
-            def body(st):
-                pos, t, resolved, fresh, rounds = st
-                cur = [tbl_ref[w, pos] for w in range(W)]
-                iskey = reduce(lambda a, b: a & b,
-                               [cur[w] == lk[w] for w in range(W)])
-                isempty = reduce(lambda a, b: a & b,
-                                 [cur[w] == jnp.uint32(0xFFFFFFFF)
-                                  for w in range(W)])
-                claim = isempty & ~iskey
-
-                @pl.when(claim)
-                def _():
-                    for w in range(W):
-                        tbl_ref[w, pos] = lk[w]
-
-                resolved2 = iskey | isempty
-                adv = ~resolved2
-                t2 = jnp.where(adv, t + 1, t)
-                pos2 = jnp.where(adv, (pos + t2) & (VCAP - 1), pos)
-                return (pos2, t2, resolved2, fresh | claim,
-                        rounds + 1)
-
-            pos, _t, resolved, fresh, _r = jax.lax.while_loop(
-                cond, body,
-                (pos0, jnp.int32(0), ~is_live, jnp.bool_(False),
-                 jnp.int32(0)))
-            fresh_ref[m] = (is_live & fresh).astype(jnp.int32)
-            pos_ref[m] = pos
-            # budget blown with the lane unresolved: table too full —
-            # the caller grows + rehashes + replays, like the lax path
-            return hovf | (is_live & ~resolved)
-
-        hovf = jax.lax.fori_loop(0, M, lane, jnp.bool_(False))
-        hovf_ref[0] = hovf.astype(jnp.int32)
-
-    out_tbl, fresh, pos, hovf = pl.pallas_call(
-        kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((W, VCAP), jnp.uint32),
-            jax.ShapeDtypeStruct((M,), jnp.int32),
-            jax.ShapeDtypeStruct((M,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        input_output_aliases={2: 0},
-        interpret=interpret,
-    )(ks, live.astype(jnp.int32), tbl)
-    return (tuple(out_tbl[w] for w in range(W)), fresh != 0, pos,
-            hovf[0] != 0)
-
-
-# ---------------------------------------------------------------------------
 # Best-effort novelty Bloom filter (sim/walker.py): the random-walk
 # engine cannot afford an authoritative visited set (walkers revisit
 # states by design), but a Bloom filter over the SAME symmetry-canonical

@@ -13,7 +13,7 @@ import ctypes
 import os
 import subprocess
 import threading
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
 from typing import List, Optional
 
@@ -41,6 +41,9 @@ INVARIANT_ORDER = (
 )
 _FAMILY = {NEXT_ASYNC: 0, NEXT_ASYNC_CRASH: 1, NEXT_FULL: 2,
            NEXT_DYNAMIC: 3}
+
+# keep in sync with raft_checker.cc MAX_LEVELS
+_MAX_LEVELS = 256
 
 _lock = threading.Lock()
 _lib = None
@@ -76,7 +79,8 @@ def _build() -> Path:
     return so
 
 
-def _load():
+def load():
+    """Build (once) and load the shared object."""
     global _lib
     with _lock:
         if _lib is None:
@@ -97,6 +101,9 @@ class NativeResult:
     violations: List[str]
     overflow_faults: int
     seconds: float = 0.0
+    # post-constraint frontier size after each level (the oracle's
+    # level_sizes); levels past _MAX_LEVELS are not recorded
+    level_sizes: List[int] = field(default_factory=list)
 
     @property
     def states_per_sec(self):
@@ -149,10 +156,10 @@ def check(cfg: ModelConfig, threads: int = os.cpu_count() or 8,
     or past the cap, so the returned count may exceed it by up to one
     level's worth of states."""
     import time
-    lib = _load()
+    lib = load()
     arr = _pack_cfg(cfg, threads, max_depth, max_states,
                     stop_on_violation)
-    out = np.zeros(8, dtype=np.int64)
+    out = np.zeros(8 + _MAX_LEVELS, dtype=np.int64)
     t0 = time.time()
     rc = lib.raft_check(
         arr.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
@@ -167,4 +174,6 @@ def check(cfg: ModelConfig, threads: int = os.cpu_count() or 8,
     return NativeResult(
         distinct_states=int(out[0]), generated_states=int(out[1]),
         depth=int(out[2]), violations=violations,
-        overflow_faults=int(out[4]), seconds=secs)
+        overflow_faults=int(out[4]), seconds=secs,
+        level_sizes=[int(x) for x in
+                     out[8:8 + min(int(out[2]), _MAX_LEVELS)]])

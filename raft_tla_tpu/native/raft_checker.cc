@@ -29,7 +29,7 @@
 #include <cstring>
 #include <mutex>
 #include <thread>
-#include <unordered_set>
+#include <unordered_map>
 #include <vector>
 
 namespace {
@@ -861,20 +861,35 @@ inline uint32_t check_invariants(const Cfg &c, const State &s) {
 
 constexpr int NSHARD = 64;
 
+// Deterministic first-seen under any thread count.  A VIEW fingerprint
+// drops the history counters, so WHICH of several VIEW-equal states
+// joins the frontier decides the constraint inputs downstream; TLC,
+// the oracle and the TPU engine keep the first one in BFS order.  Each
+// candidate therefore carries its level-local rank (frontier index,
+// then successor order), and the smallest rank per fingerprint wins.
 struct VisitedSet {
-  std::unordered_set<uint64_t> shard[NSHARD];
+  // fp -> 0 for a state of an earlier level, else 1 + the smallest
+  // rank offered for it in the current level so far
+  std::unordered_map<uint64_t, uint64_t> shard[NSHARD];
   std::mutex mu[NSHARD];
-  // returns true if newly inserted
-  bool insert(uint64_t fp) {
+  // true if (fp, rank) is, for now, the current level's best candidate
+  bool offer(uint64_t fp, uint64_t rank) {
     int sh = fp & (NSHARD - 1);
     std::lock_guard<std::mutex> g(mu[sh]);
-    return shard[sh].insert(fp).second;
+    auto it = shard[sh].find(fp);
+    if (it == shard[sh].end()) {
+      shard[sh].emplace(fp, rank + 1);
+      return true;
+    }
+    if (it->second == 0 || it->second <= rank + 1) return false;
+    it->second = rank + 1;
+    return true;
   }
-  size_t size() {
-    size_t n = 0;
-    for (auto &s : shard) n += s.size();
-    return n;
+  // after the level's workers joined (no lock needed)
+  bool won(uint64_t fp, uint64_t rank) {
+    return shard[fp & (NSHARD - 1)].find(fp)->second == rank + 1;
   }
+  void settle(uint64_t fp) { shard[fp & (NSHARD - 1)][fp] = 0; }
 };
 
 struct Stats {
@@ -882,23 +897,37 @@ struct Stats {
   uint32_t violated = 0;   // union of violated invariant bits
 };
 
+// a candidate that was the best for its fingerprint when offered;
+// the State itself is kept only when it passes the constraints
+struct Cand {
+  uint64_t fp, rank;
+  uint32_t violated;
+  uint8_t overflow;
+  int64_t keep;            // index into WorkerSink::keep, or -1
+};
+
 struct WorkerSink {
   const Cfg *c;
   VisitedSet *visited;
-  std::vector<State> next;
-  int64_t generated = 0, overflow = 0, distinct = 0;
-  uint32_t violated = 0;
+  uint64_t rank = 0;       // next candidate's level-local rank
+  std::vector<Cand> cand;
+  std::vector<State> keep;
+  int64_t generated = 0;
 };
 
 void worker_emit(void *sink_, const State &t) {
   auto *w = static_cast<WorkerSink *>(sink_);
   w->generated++;
+  uint64_t rank = w->rank++;
   uint64_t fp = fingerprint(*w->c, t);
-  if (!w->visited->insert(fp)) return;
-  w->distinct++;
-  if (t.overflow) w->overflow++;
-  w->violated |= check_invariants(*w->c, t);
-  if (constraints_ok(*w->c, t)) w->next.push_back(t);
+  if (!w->visited->offer(fp, rank)) return;
+  int64_t k = -1;
+  if (constraints_ok(*w->c, t)) {
+    k = (int64_t)w->keep.size();
+    w->keep.push_back(t);
+  }
+  w->cand.push_back(Cand{fp, rank, check_invariants(*w->c, t),
+                         t.overflow, k});
 }
 
 }  // namespace
@@ -914,6 +943,9 @@ extern "C" {
 //  [30]=stop_on_violation [31]=value_bits
 //  [32]=n_perms [33...]=perms flattened (n_perms * S entries)
 // out: [0]=distinct [1]=generated [2]=depth [3]=violated_mask [4]=overflow
+//      [8 + d - 1]=post-constraint frontier size after level d, for
+//      d <= MAX_LEVELS (the oracle's level_sizes)
+constexpr int64_t MAX_LEVELS = 256;
 int64_t raft_check(const int64_t *a, int64_t *out) {
   Cfg c{};
   c.S = (int)a[0];
@@ -961,7 +993,8 @@ int64_t raft_check(const int64_t *a, int64_t *out) {
 
   Stats st;
   VisitedSet visited;
-  visited.insert(fingerprint(c, init));
+  visited.offer(fingerprint(c, init), 0);
+  visited.settle(fingerprint(c, init));
   st.distinct = 1;
   st.generated = 1;
   st.violated |= check_invariants(c, init);
@@ -985,19 +1018,35 @@ int64_t raft_check(const int64_t *a, int64_t *out) {
           size_t base = cursor.fetch_add(grain);
           if (base >= frontier.size()) break;
           size_t end = std::min(frontier.size(), base + grain);
-          for (size_t q = base; q < end; ++q) successors(x, frontier[q]);
+          for (size_t q = base; q < end; ++q) {
+            sinks[t].rank = (uint64_t)q << 24;   // < 2^24 successors
+            successors(x, frontier[q]);
+          }
         }
       });
     }
     for (auto &t : threads) t.join();
-    std::vector<State> next;
+    // the winners, in rank order: the sequential BFS's first-seen order
+    struct Win { uint64_t rank; const Cand *cd; State *s; };
+    std::vector<Win> win;
     for (auto &w : sinks) {
       st.generated += w.generated;
-      st.distinct += w.distinct;
-      st.overflow += w.overflow;
-      st.violated |= w.violated;
-      next.insert(next.end(), w.next.begin(), w.next.end());
+      for (auto &cd : w.cand)
+        if (visited.won(cd.fp, cd.rank))
+          win.push_back({cd.rank, &cd,
+                         cd.keep >= 0 ? &w.keep[cd.keep] : nullptr});
     }
+    std::sort(win.begin(), win.end(),
+              [](const Win &a, const Win &b) { return a.rank < b.rank; });
+    std::vector<State> next;
+    for (auto &wn : win) {
+      visited.settle(wn.cd->fp);
+      st.distinct++;
+      st.overflow += wn.cd->overflow;
+      st.violated |= wn.cd->violated;
+      if (wn.s) next.push_back(*wn.s);
+    }
+    if (st.depth <= MAX_LEVELS) out[8 + st.depth - 1] = next.size();
     frontier.swap(next);
     if (a[30] && st.violated) break;
   }

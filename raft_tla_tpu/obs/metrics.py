@@ -31,15 +31,15 @@ CHECK_COUNTER_KEYS = (
     "distinct_states", "generated_states", "depth", "overflow_faults",
     "violations_global", "levels_fused", "burst_dispatches",
     "burst_bailouts", "pin_interior_states", "guard_matmul",
-    "dedup_kernel", "delta_matmul", "sym_canon")
+    "delta_matmul", "sym_canon")
 
-# the MXU-path mode flags (0/1): which expansion/dedup program this
+# the MXU-path mode flags (0/1): which expansion program this
 # run executed — BENCH rounds 9/11 read these next to the
-# guard_matmul / dedup_kernel / delta_apply span totals so the A/B
+# guard_matmul / delta_apply span totals so the A/B
 # attributes per phase AND records which mode produced each row.
 # Stamped LIVE by every engine's _stamp_mode (never serialized into
 # checkpoints — a resumed run reports the resuming engine's modes).
-MXU_COUNTER_KEYS = ("guard_matmul", "dedup_kernel", "delta_matmul",
+MXU_COUNTER_KEYS = ("guard_matmul", "delta_matmul",
                     # 1 = orbit-sort canonical fingerprints (round 15),
                     # 0 = min-over-perms; the resolved --sym-canon mode
                     "sym_canon")
@@ -151,8 +151,8 @@ def check_stats(counters: Mapping, seconds: float, n_violations: int,
         # level (burst_bailouts ~ depth with levels_fused 0)
         for k in BURST_COUNTER_KEYS:
             out[k] = int(counters[k])
-        # MXU-path mode flags (guard-matmul expansion / Pallas dedup
-        # kernel) — .get: pre-round-9 counter dicts lack them
+        # MXU-path mode flags (guard-matmul / delta-matmul expansion)
+        # — .get: pre-round-9 counter dicts lack them
         for k in MXU_COUNTER_KEYS:
             out[k] = int(counters.get(k, 0) or 0)
     if spec is not None:

@@ -11,7 +11,7 @@ under DIR:
   ``r<YYYYmmdd-HHMMSS>-<pid>-<6 hex>`` (``new_run_id``): lexically ≈
   chronological, collision-free across interleaved processes, and the
   SAME id is stamped into every ledger row and heartbeat of the run,
-  so a dropped tunnel no longer orphans telemetry — the record's
+  so a lost connection no longer orphans telemetry — the record's
   ``artifacts`` paths cross-link them.
 - **atomicity** — write-tmp-then-``os.replace``, the repo-wide publish
   pattern: a reader never sees a torn record, and a crash mid-write

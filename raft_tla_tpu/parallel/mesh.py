@@ -67,20 +67,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:                                    # jax >= 0.6
-    from jax import shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-except ImportError:                     # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 from ..config import ModelConfig
 from ..obs import NULL_OBS
@@ -103,6 +91,11 @@ _SHARDED_CKPT_FORMAT = 4
 _SHARDED_FMT = ("ckpt_format", _SHARDED_CKPT_FORMAT,
                 "the carry replaced pg_off with the gids table and "
                 "gained trip_base")
+
+def _shard_map(f, mesh, in_specs, out_specs):
+    return shard_map(f, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
+
 
 # warn-once latch for uneven user chunk overrides (per process, like
 # any stacklevel warning filter — the mesh size doesn't change mid-run)
@@ -148,7 +141,6 @@ class ShardedEngine(Engine):
                  burst: bool = True,
                  burst_levels: Optional[int] = None,
                  guard_matmul: bool = True,
-                 dedup_kernel: str = "auto",
                  delta_matmul: bool = True,
                  fam_density=None,
                  sym_canon: str = "auto"):
@@ -166,7 +158,6 @@ class ShardedEngine(Engine):
                          lcap=lcap, vcap=vcap, fcap=fcap, burst=burst,
                          burst_levels=burst_levels,
                          guard_matmul=guard_matmul,
-                         dedup_kernel=dedup_kernel,
                          delta_matmul=delta_matmul,
                          fam_density=fam_density,
                          sym_canon=sym_canon)
@@ -1045,7 +1036,7 @@ class ShardedEngine(Engine):
         burst_ok = True
         while n_front and depth < max_depth and \
                 res.distinct_states < max_states:
-            # chaos site: dispatch-time device/tunnel error at the
+            # chaos site: dispatch-time device/runtime error at the
             # level boundary (resil/chaos) — before any device work,
             # so the last checkpoint stays the exact resume point
             chaos_point("dispatch")
@@ -1401,9 +1392,7 @@ class ShardedEngine(Engine):
                         for _ in range(self.W))
             ncl = jnp.full((new_vb,), U32MAX)
             ranks = jnp.arange(old_vb, dtype=jnp.uint32)
-            # lax path unconditionally: a rehash probes a whole table
-            # shard at once, not the per-candidate hot loop
-            new, ncl, _f, _p, hv = self._probe_insert_lax(
+            new, ncl, _f, _p, hv = self._probe_insert(
                 new, ncl, t, ~allones, ranks)
             # replicated so every controller can read it (multi-host)
             hv_all = jax.lax.all_gather(hv, "d").any()

@@ -23,7 +23,7 @@ engine:
   store_states × checkpoint combination works here from day one);
 - the **hash-ownership exchange is a sharding-constraint-mediated
   collective inside ONE jit program**: a candidate's claim-scatter
-  into the slot-sharded table (engine/bfs._probe_insert_lax) IS the
+  into the slot-sharded table (engine/bfs._probe_insert) IS the
   routing step the shard_map engines spell as an explicit
   ``all_to_all`` — ``with_sharding_constraint`` pins the table's named
   sharding and GSPMD emits the cross-device (ICI within a host, DCN
@@ -181,18 +181,17 @@ class PjitShardedEngine(Engine):
 
     def __init__(self, cfg: ModelConfig, devices=None, **kw):
         devices = list(devices) if devices is not None else jax.devices()
+        # Auto axes: GSPMD propagates the carry shardings through the
+        # root placement's .at[].set (Explicit axes would reshard each
+        # operand and refuse the replicated 1-row updates)
         self.mesh = jax.make_mesh((len(devices),), ("d",),
+                                  axis_types=(jax.sharding.AxisType.Auto,),
                                   devices=devices)
         self.D = len(devices)
         from .mesh import _round_chunk_to_devices
         kw = dict(kw, chunk=_round_chunk_to_devices(
             kw.get("chunk", 512), self.D))
         super().__init__(cfg, **kw)
-        # the Pallas probe kernel is a single-device program; the lax
-        # claim walk is the pjit program (its table scatter is the
-        # ownership exchange) — keep the kernel off regardless of the
-        # dedup_kernel flag
-        self._dedup_pallas = False
         self._rep_sh = NamedSharding(self.mesh, P())
         self._table_sh = NamedSharding(self.mesh, P("d"))
         # rule-matched spec tree over the carry template (structure
@@ -260,13 +259,13 @@ class PjitShardedEngine(Engine):
         """The dedup claim walk with the table pinned to its slot
         sharding: the winners' key scatter is the hash-ownership
         exchange, mediated by this constraint as an in-program GSPMD
-        collective (module docstring) — no Pallas kernel, no
-        all_to_all, no host hop."""
+        collective (module docstring) — no all_to_all, no host
+        hop."""
         table = jax.lax.with_sharding_constraint(
             table, tuple(self._table_sh for _ in table))
         claims = jax.lax.with_sharding_constraint(claims,
                                                   self._table_sh)
-        return self._probe_insert_lax(table, claims, keys, live, ranks)
+        return super()._probe_insert(table, claims, keys, live, ranks)
 
     # -- checkpoint / resume ------------------------------------------
     #

@@ -450,7 +450,7 @@ class SpilledShardedEngine(ShardedEngine):
         burst_ok = True
         while any(frontier) and depth < max_depth and \
                 res.distinct_states < max_states:
-            # chaos site: dispatch-time device/tunnel error at the
+            # chaos site: dispatch-time device/runtime error at the
             # level boundary (resil/chaos) — before any device work,
             # so the last checkpoint stays the exact resume point
             chaos_point("dispatch")

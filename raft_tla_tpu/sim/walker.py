@@ -82,7 +82,6 @@ from ..config import ModelConfig
 from ..ops.kernels import select_enabled
 from ..spec import spec_of
 from ..engine.expand import Expander
-from ..engine.bfs import enable_persistent_compilation_cache
 from ..engine.fingerprint import (Fingerprinter, bloom_estimate,
                                   bloom_positions, resolve_sym_canon)
 
@@ -183,7 +182,6 @@ class SimEngine:
                  guard_matmul: bool = True,
                  delta_matmul: bool = True,
                  sym_canon: str = "auto"):
-        enable_persistent_compilation_cache()
         if policy not in ("punctuated", "tlc"):
             raise ValueError(f"unknown restart policy {policy!r}")
         self.cfg = cfg

@@ -7,9 +7,42 @@ them under its historical names for backward compatibility).
 
 from __future__ import annotations
 
+import os
 from typing import Dict, List
 
 import numpy as np
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; every entry point
+    (cli.main, bench.py, chip_smoke.py, __graft_entry__, the tools/
+    mains) calls this before its first jit; library code never does.  ``$JAX_COMPILATION_CACHE_DIR`` wins when set
+    (JAX reads it itself); otherwise the fixed ``<repo>/.jax_cache``.
+    Returns the directory in use."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    # persist every program: the root path compiles many small ones
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
+
+
+def ref_or_local(path: str) -> str:
+    """A reference model path (/root/reference/...), falling back to
+    the repo-local twin under configs/ when the reference tree is not
+    on this machine (tests/test_sim.py pins that the twin parses
+    identically).  The twins carry only the cfg + the bound-constant
+    stub the parser scans, not the full spec text."""
+    if os.path.exists(path):
+        return path
+    local = os.path.join(REPO_ROOT, "configs",
+                         os.path.relpath(path, "/root/reference"))
+    return local if os.path.exists(local) else path
 
 
 # the probe-walk contract every visited-table image shares (device

@@ -66,26 +66,11 @@ def test_floor_ratchets_on_new_best(tmp_path):
 
 import pytest
 
-_FLOOR_KEYS = sorted(k for k in json.load(
-    open(os.path.join(REPO, "BENCH_FLOOR.json"))) if k[0] != "_")
-
-
-def test_floor_covers_every_measured_config():
-    """VERDICT r4 #6: the configs rounds 3-5 fought for must each have
-    a regression floor — a 3x collapse on any of them must not ship
-    green via the headline row alone."""
-    want = {"tlc_membership_S3_T3_L3", "config1_budgeted",
-            "config2_budgeted", "config3_budgeted", "config4_budgeted",
-            "config5_budgeted", "spill_config2_depth19"}
-    assert want <= set(_FLOOR_KEYS), sorted(want - set(_FLOOR_KEYS))
-
-
-@pytest.mark.parametrize("key", _FLOOR_KEYS)
-def test_repo_floor_rows_are_valid(key):
-    e = json.load(open(os.path.join(REPO, "BENCH_FLOOR.json")))[key]
-    assert 0 < e["hard_frac"] < e["warn_frac"] < 1
-    assert e["best_states_per_sec"] > 0
-    assert e["platform_prefix"] and e["source"]
+# the run shapes tools/measure_baseline.py and tools/deep_run.py key
+# their floor rows by, beside bench.py's headline
+_FLOOR_KEYS = ("tlc_membership_S3_T3_L3", "config1_budgeted",
+               "config2_budgeted", "config3_budgeted", "config4_budgeted",
+               "config5_budgeted", "spill_config2_depth19")
 
 
 @pytest.mark.parametrize("key", _FLOOR_KEYS)

@@ -26,6 +26,17 @@ needs_reference = pytest.mark.skipif(
     reason="reference spec tree not present in this container")
 
 
+def test_config2_is_tlc_membership_with_election_safety_only():
+    """configs/config2 (BASELINE config #2, what bench.py and
+    chip_smoke.py check) is the tlc_membership twin with its invariant
+    block cut to ElectionSafety: nothing else may drift between them."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    base = load_model(os.path.join(repo, LOCAL_CFG))
+    cfg2 = load_model(os.path.join(repo, "configs", "config2", "raft.cfg"))
+    assert cfg2.invariants == ("ElectionSafety",)
+    assert cfg2 == base.with_(invariants=("ElectionSafety",))
+
+
 @needs_reference
 def test_parse_tlc_membership():
     cfg = load_model(TLC_CFG)

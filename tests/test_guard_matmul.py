@@ -1,11 +1,11 @@
 """MXU-native expansion (round 9): guard grid as int8 matmul, batched
-successor einsum, Pallas probe/claim dedup kernel.
+successor einsum; the claim-insert dedup against a sequential twin.
 
 The contract is bit-exactness BY CONSTRUCTION, pinned differentially:
 ``guard_matmul=True`` (default) must be an exact drop-in for the
 historical vmapped lane sweep in EVERY engine — counts, level sizes,
 global ids, archives, witness traces, violation states — and the
-Pallas dedup kernel must reproduce the lax probe/claim sequence's
+parallel claim-insert must reproduce a sequential probe/claim walk's
 outcomes (fresh set, slots, table contents) on forced-collision
 fixtures.  One fast representative per engine family runs in tier-1;
 the full-space duplicates are slow-marked (870s budget)."""
@@ -19,7 +19,6 @@ from raft_tla_tpu.config import Bounds, ModelConfig, NEXT_ASYNC, \
     NEXT_DYNAMIC
 from raft_tla_tpu.engine.bfs import Engine, U32MAX
 from raft_tla_tpu.engine.expand import Expander, parse_fam_density
-from raft_tla_tpu.engine.fingerprint import probe_claim_insert_pallas
 from raft_tla_tpu.engine.spill import SpillEngine
 
 # tiny configs (test_obs/test_burst shapes: small spaces, fast)
@@ -183,15 +182,45 @@ def test_sim_guard_matmul_bit_identical_trajectories():
 
 
 # ---------------------------------------------------------------------
-# Pallas probe/claim dedup kernel ≡ lax sequence (forced collisions)
+# claim-insert dedup ≡ sequential host twin (forced collisions)
 # ---------------------------------------------------------------------
 
 
-def test_pallas_dedup_kernel_forced_collision_fixture():
-    """The acceptance fixture: a small table, few distinct keys, many
-    duplicates and dead lanes, a pre-populated cohort — kernel
-    (interpret=True, the CPU fallback) and lax sequence must agree on
-    the table contents, the fresh set and every lane's final slot."""
+def _sequential_claim_insert(table, keys, live, home):
+    """Plain reference: lanes in index order, each quadratic-probes
+    (pos += ++t) until it meets its key (duplicate) or an empty slot
+    (claim)."""
+    table = [list(t) for t in table]
+    vcap = len(table[0])
+    fresh, pos = [], []
+    for m in range(len(live)):
+        key = [int(k[m]) for k in keys]
+        p, t = int(home[m]), 0
+        if not live[m]:
+            fresh.append(False)
+            pos.append(p)
+            continue
+        while True:
+            cur = [tw[p] for tw in table]
+            if cur == key:
+                fresh.append(False)
+                break
+            if all(c == 0xFFFFFFFF for c in cur):
+                for w, tw in enumerate(table):
+                    tw[p] = key[w]
+                fresh.append(True)
+                break
+            t += 1
+            p = (p + t) & (vcap - 1)
+        pos.append(p)
+    return table, fresh, pos
+
+
+def test_claim_insert_forced_collision_fixture():
+    """A small table, few distinct keys, many duplicates and dead
+    lanes, a pre-populated cohort: the parallel claim/scatter-min walk
+    must land on the sequential-by-rank fixpoint — same table
+    contents, fresh set and final slot for every live lane."""
     eng = Engine(MICRO, chunk=64, store_states=False)
     W = eng.W
     rng = np.random.RandomState(7)
@@ -206,19 +235,20 @@ def test_pallas_dedup_kernel_forced_collision_fixture():
     claims0 = jnp.full((VCAP,), U32MAX)
     # pre-populate (cross-call duplicate detection)
     pre = tuple(jnp.asarray(distinct[:4, w]) for w in range(W))
-    t1, c1, _f, _p, _h = eng._probe_insert_lax(
+    t1, c1, _f, _p, _h = eng._probe_insert(
         table0, claims0, pre, jnp.ones(4, bool),
         jnp.arange(4, dtype=jnp.uint32))
-    tA, _cA, fA, pA, hA = eng._probe_insert_lax(
+    tA, _cA, fA, pA, hA = eng._probe_insert(
         t1, c1, keys, live, jnp.arange(M, dtype=jnp.uint32))
-    tB, fB, pB, hB = probe_claim_insert_pallas(
-        t1, keys, live, max_rounds=4096, interpret=True)
+    home = np.asarray(eng._home(keys, VCAP))
+    tB, fB, pB = _sequential_claim_insert(
+        [np.asarray(t) for t in t1], keys_np.T, live_np, home)
     for w in range(W):
-        np.testing.assert_array_equal(np.asarray(tA[w]),
-                                      np.asarray(tB[w]))
-    np.testing.assert_array_equal(np.asarray(fA), np.asarray(fB))
-    np.testing.assert_array_equal(np.asarray(pA), np.asarray(pB))
-    assert bool(hA) == bool(hB) is False
+        np.testing.assert_array_equal(np.asarray(tA[w]), tB[w])
+    np.testing.assert_array_equal(np.asarray(fA), fB)
+    np.testing.assert_array_equal(np.asarray(pA)[live_np],
+                                  np.asarray(pB)[live_np])
+    assert not bool(hA)
     # the fixture actually forced duplicates AND dead lanes
     assert fA.sum() < live_np.sum()
 
@@ -297,33 +327,6 @@ def test_guard_matmul_violation_states_identical():
                     [(lbl, repr(sv)) for lbl, sv in
                      eng.trace(v.state_id)])
     assert outs[True] == outs[False]
-
-
-@pytest.mark.slow
-def test_engine_dedup_kernel_on_matches_off():
-    """Full-engine Pallas parity through the interpreter (the CPU
-    fallback): dedup_kernel='on' ≡ 'off', depth-capped — interpret
-    mode costs per-lane Python, so the space is kept tiny."""
-    r_on = Engine(MICRO, chunk=16, store_states=False,
-                  dedup_kernel="on").check(max_depth=3)
-    r_off = Engine(MICRO, chunk=16, store_states=False,
-                   dedup_kernel="off").check(max_depth=3)
-    assert _key(r_on) == _key(r_off)
-    assert r_on.dedup_kernel == 1 and r_off.dedup_kernel == 0
-
-
-@pytest.mark.slow
-def test_mesh_dedup_kernel_on_matches_off():
-    """Pallas kernel inside the shard_map step (the path a TPU mesh
-    runs under dedup_kernel='auto'): interpreter-pinned ≡ lax, so the
-    mesh default has a CPU-side signal before TPU hardware sees it."""
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    r_on = ShardedEngine(TINY, chunk=16, store_states=False,
-                         dedup_kernel="on").check(max_depth=3)
-    r_off = ShardedEngine(TINY, chunk=16, store_states=False,
-                          dedup_kernel="off").check(max_depth=3)
-    assert _key(r_on) == _key(r_off)
-    assert r_on.dedup_kernel == 1 and r_off.dedup_kernel == 0
 
 
 @pytest.mark.slow

@@ -3,7 +3,8 @@
 The native runtime (native/raft_checker.cc) is the framework's CPU
 engine and the machine-measured stand-in for the reference's
 "TLC -workers N" baseline (BASELINE.md) — it must agree with the oracle
-on distinct-state counts, depth and invariant verdicts, with and
+on distinct-state counts, per-level sizes, depth and invariant
+verdicts, with and
 without symmetry reduction, across the Next families.
 """
 
@@ -39,6 +40,8 @@ def compare(cfg, max_depth=10 ** 9, threads=4):
     assert got.distinct_states == want.distinct_states, \
         (got.distinct_states, want.distinct_states)
     assert got.depth == want.depth, (got.depth, want.depth)
+    assert got.level_sizes == want.level_sizes, \
+        (got.level_sizes, want.level_sizes)
     want_viol = {v.invariant for v in want.violations
                  if v.invariant in native.INVARIANT_ORDER}
     assert set(got.violations) == want_viol, (got.violations, want_viol)
@@ -66,3 +69,19 @@ def test_native_single_thread_deterministic():
     a = compare(MICRO, threads=1)
     b = compare(MICRO, threads=8)
     assert a.distinct_states == b.distinct_states
+
+
+def test_native_first_seen_is_thread_count_independent():
+    """VIEW drops the history counters, so which of several VIEW-equal
+    states joins the frontier decides the constraints' inputs later on.
+    With racing workers config #2 drifted from the oracle at level 14
+    (7567 frontier states instead of 7579); the smallest-rank winner
+    makes every thread count land on the sequential BFS."""
+    import os
+    from raft_tla_tpu.cfg.parser import load_model
+    cfg = load_model(
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "configs", "config2", "raft.cfg"),
+        bounds=Bounds.make(max_log_length=3, max_timeouts=2,
+                           max_client_requests=3))
+    compare(cfg, max_depth=14, threads=8)

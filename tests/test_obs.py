@@ -76,8 +76,8 @@ def test_check_stats_keys_byte_compatible():
         "distinct_states", "generated_states", "depth", "seconds",
         "states_per_sec", "dedup_hit_rate", "violations", "fp_bits",
         "expected_fp_collisions", "levels_fused", "burst_dispatches",
-        "burst_bailouts", "guard_matmul", "dedup_kernel",
-        "delta_matmul", "sym_canon")
+        "burst_bailouts", "guard_matmul", "delta_matmul",
+        "sym_canon")
     # oracle payload (no engine telemetry)
     out = check_stats(r.metrics.as_dict(), 1.5, 2)
     assert tuple(out.keys()) == (

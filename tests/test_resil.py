@@ -560,11 +560,11 @@ def test_obs_retry_ledger_heartbeat_and_watch(tmp_path):
     obs.dispatch(kind="level", depth=3,
                  metrics={"distinct_states": 42})
     obs.retry(attempt=2, max_attempts=4, wait_s=1.5,
-              error=RuntimeError("tunnel dropped"))
+              error=RuntimeError("device lost"))
     recs = [json.loads(ln) for ln in open(ledger_path)]
     rr = next(r for r in recs if r["kind"] == "retry")
     assert rr["attempt"] == 2 and rr["max_attempts"] == 4
-    assert "tunnel dropped" in rr["error"] and rr["spec"] == "raft"
+    assert "device lost" in rr["error"] and rr["spec"] == "raft"
     hb = json.load(open(hb_path))
     assert hb["status"] == "backoff" and \
         hb["retry"]["attempt"] == 2

@@ -15,6 +15,8 @@ import pickle
 
 import pytest
 
+import jax
+
 from raft_tla_tpu.config import Bounds, ModelConfig, NEXT_ASYNC
 from raft_tla_tpu.engine.bfs import Engine
 from raft_tla_tpu.serve import (ExecCache, Job, ResultCache,
@@ -485,6 +487,22 @@ def test_exec_cache_lru_bytes_eviction(tmp_path):
     assert unb.stats()["exec_cache_evictions"] == 0
 
 
+def test_exec_cache_refuses_to_store_under_cpu_jax_cache(tmp_path):
+    """jax 0.9's XLA:CPU re-serializes an executable that JAX's
+    persistent cache loaded into a blob that fails at run time, so
+    with that cache on the real serializer stores nothing: a named
+    store failure, never an entry a restart would crash on."""
+    import jax.numpy as jnp
+    assert jax.config.jax_enable_compilation_cache   # conftest: on
+    assert jax.config.jax_compilation_cache_dir
+    cache = ExecCache(str(tmp_path))
+    compiled = jax.jit(lambda x: x + 1).lower(jnp.zeros(4)).compile()
+    assert not cache.store("k", compiled, {"p": 1})
+    assert cache.stores == 0 and cache.store_failures == 1
+    assert "persistent compilation cache" in cache.store_fail_reasons[0]
+    assert cache.load("k", {"p": 1})[0] is None
+
+
 def test_exec_cache_max_bytes_cli_validation():
     """batch --executable-cache-max-bytes is a usage error (exit 2,
     named message) without --executable-cache or with a non-positive
@@ -497,7 +515,8 @@ def test_exec_cache_max_bytes_cli_validation():
                  "--executable-cache-max-bytes", "-5"]) == 2
 
 
-def test_exec_cache_warm_restart_zero_compiles_and_slo_obs(tmp_path):
+def test_exec_cache_warm_restart_zero_compiles_and_slo_obs(tmp_path,
+                                                          no_jax_cache):
     """End-to-end acceptance: a warm ``exec_cache`` restart (fresh
     BucketEngine, fresh run_jobs) performs ZERO .compile() calls —
     no bucket_compile span — and serves bit-identical results.  Uses

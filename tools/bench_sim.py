@@ -75,6 +75,8 @@ def measure(name, walkers, steps, warm=16):
 
 
 def main():
+    from raft_tla_tpu.utils import enable_compilation_cache
+    enable_compilation_cache()
     import jax
     rows = [measure("small", walkers=64, steps=256),
             measure("cfg5", walkers=64, steps=128)]

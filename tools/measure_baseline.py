@@ -188,6 +188,8 @@ def measure(n, reps=3):
 
 
 if __name__ == "__main__":
+    from raft_tla_tpu.utils import enable_compilation_cache
+    enable_compilation_cache()
     args = sys.argv[1:]
     reps = 3
     if "--reps" in args:

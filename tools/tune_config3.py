@@ -20,6 +20,8 @@ from raft_tla_tpu.engine.bfs import Engine
 
 
 def main():
+    from raft_tla_tpu.utils import enable_compilation_cache
+    enable_compilation_cache()
     variant = sys.argv[1] if len(sys.argv) > 1 else "base"
     budget = int(sys.argv[2]) if len(sys.argv) > 2 else 1_500_000
     cfg = build_cfg(3)
