@@ -53,7 +53,7 @@ from jax import lax
 
 from ..config import ModelConfig
 from ..obs import NULL_OBS
-from ..obs.metrics import CHECK_COUNTER_KEYS
+from ..obs.metrics import CHECK_COUNTER_KEYS, DEDUP_COUNTER_KEYS
 from ..ops.codec import C_OVERFLOW
 from ..spec import spec_of
 from ..utils import HOME_SALT
@@ -118,6 +118,12 @@ class CheckResult:
       of distinct interior states the engine invariant-checked but did
       NOT count — the upper bound on the distinct_states divergence
       from TLC for pinned cfgs.
+    - ``dedup_walk_iters`` / ``dedup_probe_steps`` / ``dedup_rounds`` /
+      ``dedup_claim_losses`` — the dedup claim walk's work on the
+      device (obs.metrics.DEDUP_COUNTER_KEYS), summed over every
+      finalize (overflow replays included) and every committed burst
+      level.  Integer work of a deterministic program: a check's counts
+      are the same on every run and every backend.
     """
 
     # the ONE canonical key tuple lives in obs.metrics — aliasing it
@@ -135,7 +141,9 @@ class CheckResult:
                  burst_dispatches: int = 0, burst_bailouts: int = 0,
                  pin_interior_states: int = 0, guard_matmul: int = 0,
                  delta_matmul: int = 0,
-                 sym_canon: int = 0):
+                 sym_canon: int = 0, dedup_walk_iters: int = 0,
+                 dedup_probe_steps: int = 0, dedup_rounds: int = 0,
+                 dedup_claim_losses: int = 0):
         from ..obs.metrics import MetricsRegistry
         init = locals()
         self.metrics = MetricsRegistry()
@@ -170,6 +178,18 @@ def _metric_view(nm: str) -> property:
 
 for _nm in CheckResult._COUNTERS:
     setattr(CheckResult, _nm, _metric_view(_nm))
+
+
+def _add_dedup(res: CheckResult, counts) -> None:
+    """Add one dedup count vector (DEDUP_COUNTER_KEYS order, as the
+    device packs it) to a result's counters."""
+    for nm, v in zip(DEDUP_COUNTER_KEYS, counts):
+        res.metrics.inc(nm, int(v))
+
+
+def _dedup_counts(res: CheckResult) -> Dict[str, int]:
+    """A result's dedup counters by name (DEDUP_COUNTER_KEYS)."""
+    return {k: res.metrics.get(k) for k in DEDUP_COUNTER_KEYS}
 
 
 def _ceil_log2(n: int) -> int:
@@ -232,6 +252,7 @@ def ckpt_write(path, carry, store_states, parents, lanes, states, res,
                 levels_fused=res.levels_fused,
                 burst_dispatches=res.burst_dispatches,
                 burst_bailouts=res.burst_bailouts,
+                **_dedup_counts(res),
                 n_levels=len(parents), store_states=store_states)
     data["meta"] = np.array(json.dumps({**base, **meta}))
     tmp = path + ".tmp.npz"           # .npz suffix: savez won't append
@@ -387,7 +408,8 @@ def ckpt_result(z, meta) -> "CheckResult":
         # counter here
         levels_fused=meta.get("levels_fused", 0),
         burst_dispatches=meta.get("burst_dispatches", 0),
-        burst_bailouts=meta.get("burst_bailouts", 0))
+        burst_bailouts=meta.get("burst_bailouts", 0),
+        **{k: meta.get(k, 0) for k in DEDUP_COUNTER_KEYS})
     for nm, sid in zip(z["viol_names"], z["viol_ids"]):
         res.violations.append(Violation(str(nm), int(sid)))
     return res
@@ -490,9 +512,9 @@ class Engine:
         # ~1-4x chunk where enabled can exceed 20x chunk on the
         # membership config, so the second compaction cuts the
         # append-side work ~8x (measured 17+21 ms -> 8+11 ms per chunk
-        # at FCAP=2^16 vs 2^13, tools/profile.py).  A chunk
-        # whose fresh count exceeds OCAP trips oovf and the level
-        # replays with OCAP grown (same discipline as FCAP/fam caps).
+        # at FCAP=2^16 vs 2^13).  A chunk whose fresh count exceeds
+        # OCAP trips oovf and the level replays with OCAP grown (same
+        # discipline as FCAP/fam caps).
         self.OCAP = self._round_cap(min(self.FCAP, int(ocap) if ocap
                                         else max(4 * self.chunk,
                                                  1 << 11)))
@@ -515,6 +537,9 @@ class Engine:
         self.FAM_CAPS = tuple(self.expander.default_fam_caps(
             self.chunk, self.fam_density))
         self._rehash_cache = {}
+        # the (LCAP, VCAP, FCAP, OCAP, FAM_CAPS) the per-level
+        # executables were last prewarmed at (check()'s prewarm)
+        self._warm_caps = None
         self._phase1 = jax.jit(self._phase1_impl)
         self._phase2 = jax.jit(self._phase2_impl)
         # runtime-bounds twin (traced only by the padded-ceiling
@@ -647,13 +672,18 @@ class Engine:
             h = fmix32(h ^ keys[w])
         return (h & jnp.uint32(vcap - 1)).astype(jnp.int32)
 
-    def _probe_insert(self, table, claims, keys, live, ranks):
+    def _probe_insert(self, table, claims, keys, live, ranks,
+                      counts=False):
         """Parallel claim-insert of `keys` (W × u32[M]; lanes with
         live=False are ignored) into the open-addressing `table`
         (W × u32[VCAP]; `claims` u32[VCAP] all-U32MAX between calls).
         Returns (table', claims', fresh, pos, hovf): fresh marks lanes
         whose key was NOT already present and won its slot; pos is each
-        lane's final table slot.
+        lane's final table slot.  ``counts=True`` appends int32[4], the
+        call's work in obs.metrics.DEDUP_COUNTER_KEYS order: inner walk
+        iterations summed over outer rounds (each one vector-wide gather
+        round), probe advances summed over lanes, outer rounds, and
+        lanes that claimed an empty slot and lost it to a lower rank.
 
         Two-phase structure, shaped by TPU op costs (scatters are an
         order of magnitude slower than gathers at these widths):
@@ -672,9 +702,11 @@ class Engine:
           the next outer iteration (equal keys walk identical probe
           paths, so a duplicate always finds its winner).
 
-        The outer loop runs until every lane resolves — typically 2-3
-        iterations (≈12 scatter ops total), versus one 4-scatter round
-        per probe *step* in the naive formulation.  `hovf` reports a
+        The outer loop runs until every lane resolves: `dedup_rounds`
+        per call read 2.67 on config #4's whole space (104 rounds over
+        39 chunk steps) and 2.83 on config #2 to depth 17 (331 over
+        117), each round 2 + W scatters, versus one such round per
+        probe *step* in the naive formulation.  `hovf` reports a
         blown round budget (table too full — caller grows, rehashes,
         replays the level).
 
@@ -698,11 +730,11 @@ class Engine:
             return iskey, isempty
 
         def outer_cond(st):
-            _t, _c, _p, _tt, active, _f, rounds = st
+            active, rounds = st[4], st[6]
             return active.any() & (rounds < self._MAX_PROBE_ROUNDS)
 
         def outer_body(st):
-            table, claims, pos, t, active, fresh, rounds = st
+            table, claims, pos, t, active, fresh, rounds, walks, lost = st
 
             # ---- walk: gathers only, no table writes ----
             def walk_cond(ws):
@@ -717,7 +749,7 @@ class Engine:
                 pos = jnp.where(adv, (pos + t) & (VCAP - 1), pos)
                 return pos, t, adv, steps + 1
 
-            pos, t, still_moving, _s = lax.while_loop(
+            pos, t, _moving, n_walk = lax.while_loop(
                 walk_cond, walk_body, (pos, t, active, jnp.int32(0)))
             iskey, isempty = classify(table, pos)
             active = active & ~iskey               # duplicate: lane dies
@@ -733,13 +765,21 @@ class Engine:
             claims = claims.at[cidx].set(U32MAX, mode="drop")
             fresh = fresh | won
             active = active & ~won
-            return table, claims, pos, t, active, fresh, rounds + 1
+            lost = lost + (claimers & ~won).sum(dtype=jnp.int32)
+            return (table, claims, pos, t, active, fresh, rounds + 1,
+                    walks + n_walk, lost)
 
+        zero = jnp.int32(0)
         state0 = (table, claims, pos0, jnp.zeros((M,), jnp.int32),
-                  live, jnp.zeros((M,), bool), jnp.int32(0))
-        table, claims, pos, _t, active, fresh, _r = lax.while_loop(
-            outer_cond, outer_body, state0)
-        return table, claims, fresh, pos, active.any()
+                  live, jnp.zeros((M,), bool), zero, zero, zero)
+        table, claims, pos, t, active, fresh, rounds, walks, lost = \
+            lax.while_loop(outer_cond, outer_body, state0)
+        out = (table, claims, fresh, pos, active.any())
+        if not counts:
+            return out
+        # t only advances on live lanes: its sum is the probe steps
+        return out + (jnp.stack([walks, t.sum(dtype=jnp.int32), rounds,
+                                 lost]),)
 
     def _host_probe_assign(self, keys: np.ndarray,
                            vcap: Optional[int] = None) -> np.ndarray:
@@ -922,8 +962,9 @@ class Engine:
         # journal stays the exact record of this level's table writes
         gate = ~(carry["ovf"] | fovf | carry["hovf"] | carry["oovf"])
         ranks = jnp.arange(FCAP, dtype=jnp.uint32)
-        table, claims, fresh, pos, hv = self._probe_insert(
-            carry["vis"], carry["claims"], keys, elive & gate, ranks)
+        table, claims, fresh, pos, hv, dd = self._probe_insert(
+            carry["vis"], carry["claims"], keys, elive & gate, ranks,
+            counts=True)
         hovf = carry["hovf"] | hv
         n_fresh = fresh.sum(dtype=jnp.int32)
         # two chunk-local overflows share the revert path: level buffer
@@ -947,8 +988,8 @@ class Engine:
         # Everything downstream (phase2, narrow, the level append) runs
         # at OCAP width — fresh rows are the dedup survivors, typically
         # ~8x fewer than enabled candidates on wide-grid configs
-        # (tools/profile.py measured the width halves the
-        # append+phase2 cost even at 8x).
+        # (measured: the width halves the append+phase2 cost even at
+        # 8x).
         slot = jnp.arange(FCAP, dtype=jnp.int32)
         lpos = jnp.where(fresh,
                          jnp.cumsum(fresh.astype(jnp.int32)) - 1, OCAP)
@@ -992,6 +1033,7 @@ class Engine:
                     n_gen=n_gen, ovf=ovf, fovf=fovf, hovf=hovf,
                     oovf=oovf, famx=famx,
                     ofx=jnp.maximum(carry["ofx"], n_fresh),
+                    dedup=carry["dedup"] + dd,
                     base=base + B)
 
     # ------------------------------------------------------------------
@@ -1012,9 +1054,11 @@ class Engine:
         """Level finalize.  Returns (carry', outputs) where
         outputs["scal"] packs every per-level scalar the host needs —
         [n_lvl, n_viol, faults, n_front, ovf, fovf, n_gen, n_expand,
-        hovf] — into ONE int32 array so the level costs a single
-        device→host round trip.  Invariants/constraints were already evaluated per
-        chunk (linv/lcon rows); finalize only aggregates, swaps the
+        hovf, oovf, ofx], the per-family enabled maxima, then the
+        level's four dedup counts — into ONE int32 array so the level
+        costs a single device→host round trip.  Invariants/constraints
+        were already evaluated per chunk (linv/lcon rows); finalize
+        only aggregates, swaps the
         level buffer into the frontier, and — when a chunk overflowed a
         buffer (ovf/fovf/hovf) — rolls the visited table back via the
         journal instead of committing, so the host can grow capacities
@@ -1064,7 +1108,7 @@ class Engine:
             carry["ovf"].astype(jnp.int32), carry["fovf"].astype(jnp.int32),
             carry["n_gen"], n_expand, carry["hovf"].astype(jnp.int32),
             carry["oovf"].astype(jnp.int32), carry["ofx"]]),
-            carry["famx"]])
+            carry["famx"], carry["dedup"]])
         new_carry = dict(carry, vis=vis, front=front, lvl=lvl,
                          fmask=fmask, n_front=n_front,
                          n_lvl=jnp.int32(0), n_gen=jnp.int32(0),
@@ -1072,6 +1116,7 @@ class Engine:
                          hovf=jnp.bool_(False), oovf=jnp.bool_(False),
                          famx=jnp.zeros_like(carry["famx"]),
                          ofx=jnp.int32(0),
+                         dedup=jnp.zeros_like(carry["dedup"]),
                          base=jnp.int32(0), pg_off=pg_off, g_off=g_next)
         return new_carry, dict(inv_ok=inv_ok, scal=scal)
 
@@ -1120,7 +1165,7 @@ class Engine:
 
     _BURST_LEVELS = 16
     _BURST_CHUNKS = 4           # ring width, in frontier chunks
-    _BS_N = 8                   # stats columns (see _burst_core)
+    _BS_N = 9                   # stats columns (see _burst_core)
 
     @property
     def _burst_chunks(self) -> int:
@@ -1142,7 +1187,8 @@ class Engine:
         the stats + per-level archives.
 
         out["stats"] is int32 [burst_levels + 1, _BS_N]: per-level rows
-        [n_lvl, n_viol, faults, n_expand, n_gen, 0, 0, 0] and a meta
+        [n_lvl, n_viol, faults, n_expand, n_gen] followed by the level's
+        four dedup counts (DEDUP_COUNTER_KEYS order), and a meta
         row at index burst_levels:
         [n_levels_done, bail, n_front_out, viol_any, states_done].
         out["par"]/out["lane"] are [L_MAX, KB] int32, out["st"] the
@@ -1164,6 +1210,7 @@ class Engine:
         st = dict(
             vis=vis, claims=claims, fr=fr, fm=fm, gd=gd, nf=nf,
             base=jnp.int32(0), nl=jnp.int32(0), gl=jnp.int32(0),
+            dd=jnp.zeros((len(DEDUP_COUNTER_KEYS),), jnp.int32),
             lv={k: jnp.zeros_like(v) for k, v in fr.items()},
             lvp=jnp.full((KB,), -1, jnp.int32),
             lvlane=jnp.full((KB,), -1, jnp.int32),
@@ -1200,8 +1247,10 @@ class Engine:
             keys = tuple(jnp.where(elive, fp[w], U32MAX)
                          for w in range(W))
             ranks = jnp.arange(FCAP, dtype=jnp.uint32)
-            vis, claims, fresh, pos, hv = self._probe_insert(
-                st["vis"], st["claims"], keys, elive & ~bail, ranks)
+            vis, claims, fresh, pos, hv, dd = self._probe_insert(
+                st["vis"], st["claims"], keys, elive & ~bail, ranks,
+                counts=True)
+            dd2 = st["dd"] + dd
             bail = bail | hv
             n_fresh = fresh.sum(dtype=jnp.int32)
             bail = bail | (nl + n_fresh > KB)
@@ -1272,8 +1321,8 @@ class Engine:
                       validrow).sum(dtype=jnp.int32)
             n_expand = (lco & validrow).sum(dtype=jnp.int32)
             li = st["li"]
-            row = jnp.stack([nl2, n_viol, faults, n_expand, gl2,
-                             jnp.int32(0), jnp.int32(0), jnp.int32(0)])
+            row = jnp.concatenate([
+                jnp.stack([nl2, n_viol, faults, n_expand, gl2]), dd2])
 
             new = dict(st)
             new["vis"], new["claims"] = vis, claims
@@ -1326,6 +1375,7 @@ class Engine:
             new["base"] = jnp.where(level_done, 0, new_base)
             new["nl"] = jnp.where(level_done, 0, nl2)
             new["gl"] = jnp.where(level_done, 0, gl2)
+            new["dd"] = jnp.where(level_done, 0, dd2)
             new["bail"] = bail
             new["viol"] = st["viol"] | (level_done & (n_viol > 0))
             return new
@@ -1522,6 +1572,8 @@ class Engine:
             n_gen=jnp.int32(0),
             famx=jnp.zeros((len(self.expander.families),), jnp.int32),
             ofx=jnp.int32(0),       # max fresh rows in any chunk
+            # the level's dedup work counts (DEDUP_COUNTER_KEYS)
+            dedup=jnp.zeros((len(DEDUP_COUNTER_KEYS),), jnp.int32),
             base=jnp.int32(0),      # chunk cursor within the frontier
             g_off=jnp.int32(0),     # global state-id offset (this level)
             pg_off=jnp.int32(0),    # global state-id offset (frontier)
@@ -1768,7 +1820,6 @@ class Engine:
         one heartbeat rewrite, so a killed run keeps its telemetry."""
         obs = self._obs = obs if obs is not None else NULL_OBS
         t0 = time.perf_counter()
-        lay = self.lay
         if resume_from is not None and resume_image is not None:
             raise ValueError(
                 "resume_from and resume_image are mutually exclusive")
@@ -1782,76 +1833,28 @@ class Engine:
             # pass spans), while uninstrumented unit-test checks skip
             # the two extra dummy dispatches — on XLA:CPU the
             # persistent compile cache cannot absorb them, and tier-1
-            # runs ~100 check() calls.  Called BEFORE the real carry
-            # materializes where possible: the dummy carry is donated
-            # away by the warm dispatches, so sequencing it first keeps
-            # peak device memory at ONE carry.
-            if obs.spans is not None:
+            # runs ~100 check() calls.  Once per capacity set: a later
+            # check at the same capacities finds the executables warm,
+            # so a traced check then dispatches what an untraced one
+            # does.  Called BEFORE the real carry materializes where
+            # possible: the dummy carry is donated away by the warm
+            # dispatches, so sequencing it first keeps peak device
+            # memory at ONE carry.
+            caps = (self.LCAP, self.VCAP, self.FCAP, self.OCAP,
+                    self.FAM_CAPS)
+            if obs.spans is not None and caps != self._warm_caps:
                 with obs.span("compile"):
                     self._prewarm_perlevel()
-
-        if resume_from is not None:
-            carry, res, meta = self._load_checkpoint(resume_from)
-            # resume: the checkpointed carry is already device-resident
-            # before the capacities are known, so this prewarm runs
-            # beside it — a transient second carry allocation (resumes
-            # are rare; a fresh start never pays it)
-            prewarm(obs)
-            n_states = meta["n_states"]
-            n_vis = meta["n_vis"]
-            depth = meta["depth"]
-            n_front = meta["n_front"]
-            resumed = True
-        elif resume_image is not None:
-            (carry, res, depth, n_states, n_vis,
-             n_front) = self._resume_portable(resume_image)
-            prewarm(obs)
-            resumed = True
-        else:
-            self._init_store()
-            roots, rk, pin_interiors = self._dedup_roots(seed_states)
-            n_roots = len(rk)
-
-            res = CheckResult(distinct_states=0,
-                              generated_states=n_roots, depth=0)
-            self._check_pin_interiors(pin_interiors, res)
-            while self.LCAP - self.OCAP < 2 * n_roots:
-                self.LCAP *= 2
-            while n_roots + self.LCAP - self.OCAP > \
-                    self._LOAD_MAX * self.VCAP:
-                self.VCAP *= 4
-            # capacities final; warm BEFORE the real carry allocates
-            prewarm(obs)
-            carry = self._fresh_carry(self.LCAP, self.VCAP)
-            # roots enter through the same admit path as every level:
-            # place them in the level buffer + visited table (host-side
-            # probe placement — the table is empty, so the sequential
-            # simulation is exact) and finalize.  Only the n_roots rows
-            # go to the device: the buffers stay device-resident and
-            # take the rows via .at[] updates (_place_roots), instead
-            # of a host-side concatenate that would upload the WHOLE
-            # padded LCAP buffer (~340 B/row x millions of rows).
-            roots_n = {k: jnp.asarray(np.moveaxis(v, 0, -1)) for k, v in
-                       self.ir.narrow(self.lay,
-                                      self.ir.widen(roots)).items()}
-            # invariants/constraints for the root cohort (levels get
-            # theirs inside the chunk step; roots bypass it)
-            inv_r, con_r = self._phase2(
-                {k: jnp.asarray(roots[k]) for k in roots})
-            carry = self._place_roots(
-                carry, roots_n, jnp.asarray(self._host_probe_assign(rk)),
-                jnp.asarray(rk), inv_r, con_r)
-            n_states = 0
-            n_vis = 0
-            depth = 0
-            resumed = False
-        self._stamp_mode(res)
-        t_dev = 0.0
+                self._warm_caps = caps
 
         def run_finalize(carry):
             carry, out = self._fin_jit(carry)
             # the ONE per-level device->host sync
-            return carry, out, [int(x) for x in np.asarray(out["scal"])]
+            scal = [int(x) for x in np.asarray(out["scal"])]
+            # every finalize's dedup counts, replays included (a
+            # replayed level's probe walk is device work too)
+            _add_dedup(res, scal[-len(DEDUP_COUNTER_KEYS):])
+            return carry, out, scal
 
         def grow_table_if_needed(carry, min_add=0):
             # pessimistic load bound: a level can add at most
@@ -1905,11 +1908,72 @@ class Engine:
             driver.guard_id_space(n_states)
             return n_front
 
-        if not resumed:
-            carry, out, scal = run_finalize(carry)
-            n_front = harvest(carry, out, scal)
+        # everything before the driver loop is one span: store init,
+        # root dedup, the prewarm, carry allocation, root placement and
+        # the root level's finalize + harvest
+        with obs.span("check_setup"):
+            if resume_from is not None:
+                carry, res, meta = self._load_checkpoint(resume_from)
+                # resume: the checkpointed carry is already device-resident
+                # before the capacities are known, so this prewarm runs
+                # beside it — a transient second carry allocation (resumes
+                # are rare; a fresh start never pays it)
+                prewarm(obs)
+                n_states = meta["n_states"]
+                n_vis = meta["n_vis"]
+                depth = meta["depth"]
+                n_front = meta["n_front"]
+                resumed = True
+            elif resume_image is not None:
+                (carry, res, depth, n_states, n_vis,
+                 n_front) = self._resume_portable(resume_image)
+                prewarm(obs)
+                resumed = True
+            else:
+                self._init_store()
+                roots, rk, pin_interiors = self._dedup_roots(seed_states)
+                n_roots = len(rk)
+
+                res = CheckResult(distinct_states=0,
+                                  generated_states=n_roots, depth=0)
+                self._check_pin_interiors(pin_interiors, res)
+                while self.LCAP - self.OCAP < 2 * n_roots:
+                    self.LCAP *= 2
+                while n_roots + self.LCAP - self.OCAP > \
+                        self._LOAD_MAX * self.VCAP:
+                    self.VCAP *= 4
+                # capacities final; warm BEFORE the real carry allocates
+                prewarm(obs)
+                carry = self._fresh_carry(self.LCAP, self.VCAP)
+                # roots enter through the same admit path as every level:
+                # place them in the level buffer + visited table (host-side
+                # probe placement — the table is empty, so the sequential
+                # simulation is exact) and finalize.  Only the n_roots rows
+                # go to the device: the buffers stay device-resident and
+                # take the rows via .at[] updates (_place_roots), instead
+                # of a host-side concatenate that would upload the WHOLE
+                # padded LCAP buffer (~340 B/row x millions of rows).
+                roots_n = {k: jnp.asarray(np.moveaxis(v, 0, -1)) for k, v in
+                           self.ir.narrow(self.lay,
+                                          self.ir.widen(roots)).items()}
+                # invariants/constraints for the root cohort (levels get
+                # theirs inside the chunk step; roots bypass it)
+                inv_r, con_r = self._phase2(
+                    {k: jnp.asarray(roots[k]) for k in roots})
+                carry = self._place_roots(
+                    carry, roots_n, jnp.asarray(self._host_probe_assign(rk)),
+                    jnp.asarray(rk), inv_r, con_r)
+                n_states = 0
+                n_vis = 0
+                depth = 0
+                resumed = False
+            self._stamp_mode(res)
+            if not resumed:
+                carry, out, scal = run_finalize(carry)
+                n_front = harvest(carry, out, scal)
         if stop_on_violation and res.violations:
             res.seconds = time.perf_counter() - t0
+            obs.counters(_dedup_counts(res))
             return res
 
         # burst_ok gates the speculative burst entry: a burst that
@@ -1986,7 +2050,8 @@ class Engine:
                             res, nlev, lambda li: stats[li, :5],
                             depth, n_states, archive=_arch,
                             violations=_viol, visited=_vis)
-                    t_dev += time.perf_counter() - t1
+                        # the committed levels' dedup counts (columns 5+)
+                        _add_dedup(res, stats[:nlev, 5:].sum(axis=0))
                     if checkpoint_path is not None and \
                             driver.ckpt_due_after_burst(
                                 depth, d0, checkpoint_every):
@@ -2097,7 +2162,6 @@ class Engine:
             # metric)
             depth = driver.gate_level_depth(res, depth, scal[0],
                                             scal[6], scal[7])
-            t_dev += time.perf_counter() - t1
             if checkpoint_path is not None and \
                     driver.ckpt_due_at_level(depth, checkpoint_every):
                 self._save_checkpoint(checkpoint_path, carry, res,
@@ -2113,7 +2177,7 @@ class Engine:
                       f"{time.perf_counter() - t1:.2f}s")
         res.depth = depth
         res.seconds = time.perf_counter() - t0
-        res.phase_seconds["device_levels"] = t_dev
+        obs.counters(_dedup_counts(res))
         return res
 
     def _check_pin_interiors(self, interiors, res: CheckResult):

@@ -43,9 +43,10 @@ from typing import Dict, Optional
 from .heartbeat import Heartbeat
 from .ledger import RunLedger, device_memory_stats, rss_bytes
 from .metrics import (BURST_COUNTER_KEYS, CHECK_COUNTER_KEYS,
-                      MXU_COUNTER_KEYS, SIM_COUNTER_KEYS,
-                      SIM_DISPATCH_KEYS, MetricsRegistry, check_stats,
-                      sim_counters, sim_stats)
+                      DEDUP_COUNTER_KEYS, MXU_COUNTER_KEYS,
+                      SIM_COUNTER_KEYS, SIM_DISPATCH_KEYS,
+                      MetricsRegistry, check_stats, sim_counters,
+                      sim_stats)
 from .registry import RunRegistry, new_run_id
 from .resources import ResourceSampler, backend_fingerprint
 from .spans import SpanRecorder
@@ -56,7 +57,7 @@ __all__ = [
     "check_stats", "sim_stats", "sim_counters", "rss_bytes",
     "device_memory_stats", "backend_fingerprint", "new_run_id",
     "CHECK_COUNTER_KEYS", "BURST_COUNTER_KEYS", "MXU_COUNTER_KEYS",
-    "SIM_COUNTER_KEYS", "SIM_DISPATCH_KEYS",
+    "DEDUP_COUNTER_KEYS", "SIM_COUNTER_KEYS", "SIM_DISPATCH_KEYS",
 ]
 
 _NULL_CTX = contextlib.nullcontext()
@@ -131,6 +132,12 @@ class Obs:
         if self.spans is None:
             return _NULL_CTX
         return self.spans.span(name)
+
+    def counters(self, values: Dict[str, int]):
+        """One sample of named counters on the span timeline
+        (``SpanRecorder.counters``); a no-op without a recorder."""
+        if self.spans is not None:
+            self.spans.counters(values)
 
     def dispatch(self, *, kind: str, depth: int, frontier: int = 0,
                  metrics: Optional[Dict] = None,

@@ -24,6 +24,16 @@ from __future__ import annotations
 
 from typing import Dict, Mapping, Optional
 
+# the dedup claim walk's device-side work counts (engine/bfs
+# _probe_insert), in the order the device packs them: inner walk
+# iterations (vector-wide gather rounds), probe advances summed over
+# lanes, outer claim/insert/reset scatter rounds, and claims lost to a
+# lower rank (retries).  Counted by the single-device engine's chunk
+# step and burst (and the pjit engine, which inherits them); the other
+# engines report 0.
+DEDUP_COUNTER_KEYS = ("dedup_walk_iters", "dedup_probe_steps",
+                      "dedup_rounds", "dedup_claim_losses")
+
 # the canonical counter set every exhaustive-check engine accumulates
 # (bfs / spill / mesh / spill_mesh all share CheckResult, so the set is
 # structurally identical across them — tests/test_obs.py pins it)
@@ -31,7 +41,7 @@ CHECK_COUNTER_KEYS = (
     "distinct_states", "generated_states", "depth", "overflow_faults",
     "violations_global", "levels_fused", "burst_dispatches",
     "burst_bailouts", "pin_interior_states", "guard_matmul",
-    "delta_matmul", "sym_canon")
+    "delta_matmul", "sym_canon") + DEDUP_COUNTER_KEYS
 
 # the MXU-path mode flags (0/1): which expansion program this
 # run executed — BENCH rounds 9/11 read these next to the
@@ -154,6 +164,9 @@ def check_stats(counters: Mapping, seconds: float, n_violations: int,
         # MXU-path mode flags (guard-matmul / delta-matmul expansion)
         # — .get: pre-round-9 counter dicts lack them
         for k in MXU_COUNTER_KEYS:
+            out[k] = int(counters.get(k, 0) or 0)
+        # dedup work counts (appended after the pinned prefix)
+        for k in DEDUP_COUNTER_KEYS:
             out[k] = int(counters.get(k, 0) or 0)
     if spec is not None:
         # the active SpecIR name + structure fingerprint (spec/

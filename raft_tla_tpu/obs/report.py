@@ -19,9 +19,7 @@ milliseconds.
   dicts, bench headline objects (``detail``) and BENCH A/B rows
   (``phase_seconds``/``phase_counts``) all reduce to the same
   ``{counters, level_sizes, spans, resources}`` view.
-- ``format_span_totals`` — the one span-rollup formatter
-  (``tools/profile.py`` prints through it instead of its private
-  aggregation).
+- ``format_span_totals`` — the one span-rollup formatter.
 """
 
 from __future__ import annotations
@@ -42,7 +40,13 @@ def format_span_totals(totals: Dict[str, Dict]) -> str:
     """``compile=6.10s/1  harvest=0.52s/12`` — the shared rendering of
     ``SpanRecorder.totals()``-shaped rollups."""
     return "  ".join(f"{nm}={t['seconds']:.2f}s/{t['count']}"
-                     for nm, t in sorted(totals.items()))
+                     for nm, t in sorted(totals.items()) if _is_span(t))
+
+
+def _is_span(t: Dict) -> bool:
+    """A span's rollup, not a counter's (``SpanRecorder.counters``
+    totals carry a ``sum``)."""
+    return "sum" not in t
 
 
 def extract(rec: Dict) -> Dict:
@@ -121,6 +125,8 @@ def _mode_drift(a: Dict, b: Dict) -> List[str]:
 def _span_deltas(a: Dict, b: Dict) -> Dict:
     out = {}
     for nm in sorted(set(a["spans"]) | set(b["spans"])):
+        if not all(_is_span(r["spans"].get(nm, {})) for r in (a, b)):
+            continue
         sa = float(a["spans"].get(nm, {}).get("seconds", 0.0))
         sb = float(b["spans"].get(nm, {}).get("seconds", 0.0))
         out[nm] = {"a_seconds": round(sa, 6), "b_seconds": round(sb, 6),

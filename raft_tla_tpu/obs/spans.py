@@ -1,15 +1,19 @@
 """Span/timeline recorder: nested named phases on monotonic clocks.
 
-Every engine driver brackets its phases — ``compile``,
-``burst_dispatch``, ``level_dispatch``, ``host_sweep``, ``harvest``,
-``archive_io``, ``checkpoint`` — with ``SpanRecorder.span(name)``.
-Round 9 adds the MXU-path micro-phase names ``guard_matmul`` /
-``guard_lanes``; round 11 adds
-``delta_apply`` / ``delta_kernels`` (the group scatter-as-matmul vs
-the per-family successor kernels): inside a fused engine step these
-exist as ``jax.named_scope`` annotations (visible in an XLA
-``--profile-dir`` trace), and bench.py times them as standalone host
-spans in the BENCH_r09/r11 A/Bs so the win attributes per phase.
+Every engine driver brackets its phases with
+``SpanRecorder.span(name)``: the single-device engine emits
+``check_setup`` (everything before its driver loop, with ``compile``,
+the per-level prewarm, inside it), ``burst_dispatch``,
+``level_dispatch``, ``harvest``, ``archive_io`` and ``checkpoint``;
+the spill and mesh engines add ``host_sweep``, ``h2d_stage`` and
+``sweep_overlap``, the serving layer its ``job_*``/``bucket_*`` and
+``batched_dispatch`` spans, the simulator ``sim_dispatch``.  Spans
+are host phases only: work inside one device program (the chunk
+step's expansion, dedup, predicates) has no span, and its share shows
+in a device trace or in the program's counters (``obs.metrics``).
+The single-device engine also records each check's dedup counters as
+one Chrome-trace counter event (``counters()``), so the timeline and
+``totals()`` carry them beside the spans.
 Clocks are ``time.perf_counter()`` (monotonic: NTP steps on long
 runs corrupted the old ``time.time()`` deltas), and completed
 spans are emitted as Chrome-trace "complete" events (``ph": "X"`` with
@@ -51,6 +55,8 @@ class SpanRecorder:
         self._pid = os.getpid()
         self._stack: List[Tuple[str, float]] = []
         self._totals: Dict[str, List[float]] = {}   # name -> [n, secs]
+        # counter name -> [samples, sum, min, max]
+        self._counters: Dict[str, List[int]] = {}
         self.events: List[dict] = []
         self._fh = None
         self._n_written = 0
@@ -82,16 +88,36 @@ class SpanRecorder:
                 ann.__exit__(None, None, None)
             self._emit(name, t0, t1)
 
+    def counters(self, values: Dict[str, int]):
+        """One sample of named counters (a check's dedup counts): a
+        Chrome-trace counter event ("ph": "C") on the timeline, and
+        per-name totals that ``totals()`` reports beside the spans."""
+        values = {nm: int(v) for nm, v in values.items()}
+        for nm, v in values.items():
+            tot = self._counters.setdefault(nm, [0, 0, v, v])
+            tot[0] += 1
+            tot[1] += v
+            tot[2] = min(tot[2], v)
+            tot[3] = max(tot[3], v)
+        self._write({
+            "name": "counters", "cat": "obs", "ph": "C",
+            "ts": round((time.perf_counter() - self._t0) * 1e6, 3),
+            "pid": self._pid, "tid": 0,
+            "args": values,
+        })
+
     def _emit(self, name: str, t0: float, t1: float):
         tot = self._totals.setdefault(name, [0, 0.0])
         tot[0] += 1
         tot[1] += t1 - t0
-        ev = {
+        self._write({
             "name": name, "cat": "obs", "ph": "X",
             "ts": round((t0 - self._t0) * 1e6, 3),
             "dur": round((t1 - t0) * 1e6, 3),
             "pid": self._pid, "tid": 0,
-        }
+        })
+
+    def _write(self, ev: dict):
         if self._fh is None:
             # in-memory mode only: when streaming, the file IS the
             # record — retaining a second copy would grow RAM without
@@ -112,9 +138,15 @@ class SpanRecorder:
         """Per-span-name inclusive totals:
         ``{name: {count, seconds}}`` — bench.py records these per phase
         so A/B deltas attribute to dispatch vs compute vs harvest
-        instead of one end-to-end number."""
-        return {nm: {"count": n, "seconds": round(s, 6)}
-                for nm, (n, s) in sorted(self._totals.items())}
+        instead of one end-to-end number.  Each counter recorded by
+        ``counters()`` adds ``{name: {count, seconds: 0.0, sum, min,
+        max}}`` over its samples."""
+        out = {nm: {"count": n, "seconds": round(s, 6)}
+               for nm, (n, s) in self._totals.items()}
+        for nm, (n, s, lo, hi) in self._counters.items():
+            out[nm] = {"count": n, "seconds": 0.0, "sum": s, "min": lo,
+                       "max": hi}
+        return dict(sorted(out.items()))
 
     # -- lifecycle -----------------------------------------------------
 

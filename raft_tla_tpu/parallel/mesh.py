@@ -134,6 +134,9 @@ class ShardedEngine(Engine):
     device); rounded up to a multiple of the mesh size
     (_round_chunk_to_devices — uneven overrides warn once)."""
 
+    # the mesh burst's stats rows carry no dedup counts
+    _BS_N = 8
+
     def __init__(self, cfg: ModelConfig, devices=None, chunk: int = 512,
                  store_states: bool = True,
                  lcap: int = 1 << 14, vcap: int = 1 << 17,

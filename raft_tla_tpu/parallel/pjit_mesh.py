@@ -255,7 +255,8 @@ class PjitShardedEngine(Engine):
         non-addressable shards)."""
         return np.asarray(self._gather_rep(x))
 
-    def _probe_insert(self, table, claims, keys, live, ranks):
+    def _probe_insert(self, table, claims, keys, live, ranks,
+                      counts=False):
         """The dedup claim walk with the table pinned to its slot
         sharding: the winners' key scatter is the hash-ownership
         exchange, mediated by this constraint as an in-program GSPMD
@@ -265,7 +266,8 @@ class PjitShardedEngine(Engine):
             table, tuple(self._table_sh for _ in table))
         claims = jax.lax.with_sharding_constraint(claims,
                                                   self._table_sh)
-        return super()._probe_insert(table, claims, keys, live, ranks)
+        return super()._probe_insert(table, claims, keys, live, ranks,
+                                     counts=counts)
 
     # -- checkpoint / resume ------------------------------------------
     #

@@ -17,7 +17,8 @@ import pytest
 
 from raft_tla_tpu.config import Bounds, ModelConfig, NEXT_ASYNC
 from raft_tla_tpu.obs import (CHECK_COUNTER_KEYS, BURST_COUNTER_KEYS,
-                              SIM_DISPATCH_KEYS, Heartbeat,
+                              DEDUP_COUNTER_KEYS, SIM_DISPATCH_KEYS,
+                              Heartbeat,
                               MetricsRegistry, Obs, RunLedger,
                               SpanRecorder, check_stats)
 from raft_tla_tpu.obs.heartbeat import read_heartbeat
@@ -77,7 +78,8 @@ def test_check_stats_keys_byte_compatible():
         "states_per_sec", "dedup_hit_rate", "violations", "fp_bits",
         "expected_fp_collisions", "levels_fused", "burst_dispatches",
         "burst_bailouts", "guard_matmul", "delta_matmul",
-        "sym_canon")
+        "sym_canon", "dedup_walk_iters", "dedup_probe_steps",
+        "dedup_rounds", "dedup_claim_losses")
     # oracle payload (no engine telemetry)
     out = check_stats(r.metrics.as_dict(), 1.5, 2)
     assert tuple(out.keys()) == (
@@ -323,6 +325,114 @@ def test_burst_bailout_reuses_warmed_per_level_executable():
             assert r.burst_bailouts >= 1
             assert tot["level_dispatch"]["count"] >= 1
             assert r.depth - r.levels_fused >= 1
+
+
+# ---------------------------------------------------------------------
+# dedup work counters and the check_setup span (engine/bfs): integer
+# counts of a deterministic program, so exact equalities hold on CPU
+# ---------------------------------------------------------------------
+
+
+def _dedup(r):
+    return {k: r.metrics.get(k) for k in DEDUP_COUNTER_KEYS}
+
+
+@pytest.fixture(scope="module")
+def dedup_runs():
+    """Two traced checks on one burst engine (span totals after each),
+    and one check each of two per-level engines whose tables differ
+    16x in size (2^17 and 2^21 slots)."""
+    from raft_tla_tpu.engine.bfs import Engine
+    rec = SpanRecorder()
+    obs = Obs(spans=rec)
+    eng = Engine(TINY, chunk=64, store_states=False)
+    first = eng.check(obs=obs)
+    tot1 = rec.totals()
+    second = eng.check(obs=obs)
+    out = dict(eng=eng, first=first, second=second, tot1=tot1,
+               tot2=rec.totals())
+    for nm, vcap in (("small", 1 << 17), ("big", 1 << 21)):
+        e = Engine(TINY, chunk=64, store_states=False, burst=False,
+                   vcap=vcap)
+        out[nm] = e.check()
+        assert e.VCAP == vcap, nm      # no growth blurs the sizes
+    return out
+
+
+def test_dedup_counters_repeat_exactly_on_one_engine(dedup_runs):
+    a, b = dedup_runs["first"], dedup_runs["second"]
+    assert _dedup(a) == _dedup(b)
+    assert a.distinct_states == b.distinct_states
+
+
+def test_dedup_probe_steps_fall_on_a_16x_larger_table(dedup_runs):
+    small, big = dedup_runs["small"], dedup_runs["big"]
+    assert small.distinct_states == big.distinct_states
+    # the same candidates meet a 16x emptier table: shorter chains
+    assert 0 < big.dedup_probe_steps < small.dedup_probe_steps
+
+
+@pytest.mark.parametrize("path", ["burst", "per_level"])
+def test_dedup_counters_count_on_both_driver_paths(dedup_runs, path):
+    r = dedup_runs["first" if path == "burst" else "small"]
+    assert (r.levels_fused > 0) is (path == "burst")
+    d = _dedup(r)
+    assert all(v > 0 for v in d.values()), d
+    # every outer round runs at least one walk iteration
+    assert d["dedup_walk_iters"] >= d["dedup_rounds"]
+    # the counters reach the --stats-json payload after the pinned keys
+    stats = check_stats(r.metrics.as_dict(), 1.0, 0, fp_bits=64)
+    assert list(stats)[-4:] == list(DEDUP_COUNTER_KEYS)
+    assert {k: stats[k] for k in DEDUP_COUNTER_KEYS} == d
+
+
+def test_check_setup_span_opens_once_per_check(dedup_runs):
+    assert dedup_runs["tot1"]["check_setup"]["count"] == 1
+    assert dedup_runs["tot2"]["check_setup"]["count"] == 2
+
+
+def test_each_check_leaves_one_dedup_counter_sample(dedup_runs):
+    """A traced check records its dedup counters once, on the span
+    recorder: the totals after two equal checks hold two equal
+    samples."""
+    d = _dedup(dedup_runs["first"])
+    for k, v in d.items():
+        assert dedup_runs["tot1"][k] == {"count": 1, "seconds": 0.0,
+                                         "sum": v, "min": v, "max": v}
+        assert dedup_runs["tot2"][k] == {"count": 2, "seconds": 0.0,
+                                         "sum": 2 * v, "min": v, "max": v}
+
+
+@pytest.mark.smoke
+def test_span_recorder_counter_samples(tmp_path):
+    from raft_tla_tpu.obs.report import format_span_totals
+    path = str(tmp_path / "tl.json")
+    rec = SpanRecorder(path)
+    with rec.span("a"):
+        rec.counters({"walk": 3, "steps": 7})
+    rec.counters({"walk": 5, "steps": 7})
+    rec.close()
+    events = json.load(open(path))
+    assert [(e["name"], e["ph"]) for e in events] == [
+        ("counters", "C"), ("a", "X"), ("counters", "C")]
+    assert events[2]["args"] == {"walk": 5, "steps": 7}
+    tot = rec.totals()
+    assert tot["walk"] == {"count": 2, "seconds": 0.0, "sum": 8,
+                           "min": 3, "max": 5}
+    assert tot["steps"]["min"] == tot["steps"]["max"] == 7
+    # span rollups render the spans alone
+    assert format_span_totals(tot) == f"a={tot['a']['seconds']:.2f}s/1"
+
+
+def test_second_traced_check_runs_no_prewarm(dedup_runs):
+    """The prewarm runs once per capacity set: the second traced check
+    on one engine opens no compile span, so it dispatches the same
+    programs an untraced check does."""
+    eng = dedup_runs["eng"]
+    assert dedup_runs["tot1"]["compile"]["count"] == 1
+    assert dedup_runs["tot2"]["compile"]["count"] == 1
+    assert eng._step_jit._cache_size() == 1
+    assert eng._fin_jit._cache_size() == 1
 
 
 def test_telemetry_parity_sim_engine(tmp_path):
