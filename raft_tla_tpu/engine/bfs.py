@@ -537,6 +537,14 @@ class Engine:
         self.FAM_CAPS = tuple(self.expander.default_fam_caps(
             self.chunk, self.fam_density))
         self._rehash_cache = {}
+        # jitted carry builders: the empty carry per (LCAP, VCAP, FCAP,
+        # OCAP), and the fresh-start set-up program per capacity set and
+        # root count (_fresh_carry, _setup_carry).  _carry_sh is
+        # the carry's sharding tree where an engine has one
+        # (parallel/pjit_mesh): both programs' outputs are born under it
+        self._fresh_jit_cache = {}
+        self._setup_jit_cache = {}
+        self._carry_sh = None
         # the (LCAP, VCAP, FCAP, OCAP, FAM_CAPS) the per-level
         # executables were last prewarmed at (check()'s prewarm)
         self._warm_caps = None
@@ -1545,10 +1553,29 @@ class Engine:
 
     # ------------------------------------------------------------------
 
+    def _carry_jit(self, fn):
+        """jax.jit for a program that returns a carry: born under the
+        carry's named shardings where the engine has them."""
+        if self._carry_sh is None:
+            return jax.jit(fn)
+        return jax.jit(fn, out_shardings=self._carry_sh)
+
     def _fresh_carry(self, lcap: int, vcap: int, fcap: Optional[int] = None,
                      ocap: Optional[int] = None):
-        fcap = fcap if fcap is not None else self.FCAP
-        ocap = ocap if ocap is not None else self.OCAP
+        """An empty carry at the given capacities, built by one jitted
+        program per capacity set (every buffer filled on the device,
+        none uploaded)."""
+        key = (lcap, vcap, fcap if fcap is not None else self.FCAP,
+               ocap if ocap is not None else self.OCAP)
+        fn = self._fresh_jit_cache.get(key)
+        if fn is None:
+            def fresh_carry():           # the program's name in traces
+                return self._fresh_carry_impl(*key)
+            fn = self._fresh_jit_cache[key] = self._carry_jit(fresh_carry)
+        return fn()
+
+    def _fresh_carry_impl(self, lcap: int, vcap: int, fcap: int,
+                          ocap: int):
         one = self.ir.narrow(self.lay, self.ir.encode(
             self.lay, *self.ir.init_state(self.cfg)))
         # frontier/level state buffers are BATCH-LAST ([..., lcap]) —
@@ -1590,8 +1617,10 @@ class Engine:
         """Write the n root rows into a fresh carry: level-buffer rows
         (narrow, batch-last), their host-placed visited-table slots
         and keys (u32 [n, W]), the insert journal and the invariant /
-        constraint bits.  Runs eagerly on whatever sharding the carry
-        has (parallel/pjit_mesh: slot- and row-sharded)."""
+        constraint bits.  Traced inside the set-up program
+        (_setup_impl), on whatever sharding the carry has
+        (parallel/pjit_mesh: slot- and row-sharded); the row writes
+        update the leading n rows of each output buffer in place."""
         n = slots.shape[0]
         carry = dict(carry)
         carry["lvl"] = {k: v.at[..., :n].set(roots_n[k])
@@ -1603,6 +1632,33 @@ class Engine:
         carry["linv"] = carry["linv"].at[:, :n].set(inv_r.T)
         carry["lcon"] = carry["lcon"].at[:n].set(con_r)
         return carry
+
+    def _setup_impl(self, lcap, vcap, fcap, ocap, roots, slots, rk):
+        """The fresh-start set-up program: allocate the carry, narrow
+        the widened root rows (int32 SoA [n, ...]) to storage dtypes in
+        batch-last layout, evaluate the root cohort's invariants and
+        constraints (levels get theirs inside the chunk step; roots
+        bypass it) and place the roots.  The fills and the root writes
+        land in the same output buffers: one carry."""
+        carry = self._fresh_carry_impl(lcap, vcap, fcap, ocap)
+        roots_n = {k: jnp.moveaxis(v, 0, -1)
+                   for k, v in self.ir.narrow(self.lay, roots).items()}
+        inv_r, con_r = self._phase2_impl(roots)
+        return self._place_roots(carry, roots_n, slots, rk, inv_r, con_r)
+
+    def _setup_carry(self, roots, rk):
+        """A fresh start's carry with the deduplicated roots placed, in
+        ONE device call, compiled once per capacity set and root count.
+        roots: widened SoA [n, ...] host rows; rk: their u32 [n, W]
+        keys.  The table is empty, so the host's sequential probe
+        placement (_host_probe_assign) is exact."""
+        key = (self.LCAP, self.VCAP, self.FCAP, self.OCAP, len(rk))
+        fn = self._setup_jit_cache.get(key)
+        if fn is None:
+            def setup_carry(*args):      # the program's name in traces
+                return self._setup_impl(*key[:4], *args)
+            fn = self._setup_jit_cache[key] = self._carry_jit(setup_carry)
+        return fn(roots, self._host_probe_assign(rk), rk)
 
     def _grow(self, carry, lcap: int, vcap: int):
         """Re-home a carry into bigger capacity buffers (the visited
@@ -1646,23 +1702,26 @@ class Engine:
         res.sym_canon = int(self.fpr.sym_canon == "sort")
         return res
 
-    def _prewarm_perlevel(self):
+    def _prewarm_perlevel(self, roots=None, rk=None):
         """Warm the per-level step/finalize executables with one dummy
         dispatch each BEFORE the driver loop (the BENCH_r08 recompile
         leak: with burst ON the first per-level dispatch otherwise
         happens only when a burst BAILS, so its cold compile landed
         mid-run inside a level_dispatch span — 11.6 s over 9 dispatches
-        vs 1.65 s over 30 in per-level mode).  The dummy carry is empty
-        (n_front = 0: every lane invalid, nothing inserted) and donated
-        away by the calls, so the cost is one transient carry
-        allocation + two no-op dispatches; post-bail dispatches then
-        reuse the warmed executable (tests/test_obs.py pins the
-        compile-span/cache counts).  Capacity growth retraces, as
-        ever."""
-        dummy = self._fresh_carry(self.LCAP, self.VCAP)
+        vs 1.65 s over 30 in per-level mode).  Given a fresh start's
+        roots, the set-up program builds the dummy, so it warms too;
+        otherwise the dummy is an empty carry.  Either way n_front = 0
+        (every lane invalid: the step inserts nothing) and the calls
+        donate the dummy away, so the cost is one transient carry + two
+        no-op dispatches; post-bail dispatches then reuse the warmed
+        executable (tests/test_obs.py pins the compile-span/cache
+        counts).  Capacity growth retraces, as ever."""
+        dummy = (self._setup_carry(roots, rk) if roots is not None
+                 else self._fresh_carry(self.LCAP, self.VCAP))
         dummy = self._step_jit(dummy, self.FAM_CAPS)
-        dummy, _out = self._fin_jit(dummy)
-        del dummy
+        # wait for the last warm dispatch, so the dummy is freed before
+        # the real carry's buffers are allocated, not beside them
+        jax.block_until_ready(self._fin_jit(dummy))
 
     def _dedup_roots(self, seed_states):
         """Shared root-admission front half (this engine, ShardedEngine
@@ -1685,8 +1744,7 @@ class Engine:
             if isinstance(s, dict) else
             {k: v[None] for k, v in self.ir.encode(self.lay, *s).items()}
             for s in init_list]))
-        rootsb = {k: jnp.asarray(v) for k, v in init_arrs.items()}
-        root_fp = np.asarray(self._rootfp_jit(rootsb)).astype(np.uint32)
+        root_fp = np.asarray(self._rootfp_jit(init_arrs)).astype(np.uint32)
         _uniq, first_idx = np.unique(fp_key(root_fp),
                                      return_index=True)
         first_idx.sort()
@@ -1824,7 +1882,7 @@ class Engine:
             raise ValueError(
                 "resume_from and resume_image are mutually exclusive")
 
-        def prewarm(obs):
+        def prewarm(obs, roots=None, rk=None):
             # per-level executables warm at run start, inside a compile
             # span — never mid-run inside a level_dispatch span (the
             # BENCH_r08 burst-bailout leak).  Gated on span
@@ -1839,12 +1897,13 @@ class Engine:
             # does.  Called BEFORE the real carry materializes where
             # possible: the dummy carry is donated away by the warm
             # dispatches, so sequencing it first keeps peak device
-            # memory at ONE carry.
+            # memory at ONE carry.  A fresh start's set-up program warms
+            # with them.
             caps = (self.LCAP, self.VCAP, self.FCAP, self.OCAP,
                     self.FAM_CAPS)
             if obs.spans is not None and caps != self._warm_caps:
                 with obs.span("compile"):
-                    self._prewarm_perlevel()
+                    self._prewarm_perlevel(roots, rk)
                 self._warm_caps = caps
 
         def run_finalize(carry):
@@ -1909,8 +1968,8 @@ class Engine:
             return n_front
 
         # everything before the driver loop is one span: store init,
-        # root dedup, the prewarm, carry allocation, root placement and
-        # the root level's finalize + harvest
+        # root dedup, the prewarm, the set-up program (or a resume's
+        # carry load) and the root level's finalize + harvest
         with obs.span("check_setup"):
             if resume_from is not None:
                 carry, res, meta = self._load_checkpoint(resume_from)
@@ -1943,26 +2002,15 @@ class Engine:
                         self._LOAD_MAX * self.VCAP:
                     self.VCAP *= 4
                 # capacities final; warm BEFORE the real carry allocates
-                prewarm(obs)
-                carry = self._fresh_carry(self.LCAP, self.VCAP)
+                prewarm(obs, roots, rk)
                 # roots enter through the same admit path as every level:
-                # place them in the level buffer + visited table (host-side
-                # probe placement — the table is empty, so the sequential
-                # simulation is exact) and finalize.  Only the n_roots rows
-                # go to the device: the buffers stay device-resident and
-                # take the rows via .at[] updates (_place_roots), instead
-                # of a host-side concatenate that would upload the WHOLE
-                # padded LCAP buffer (~340 B/row x millions of rows).
-                roots_n = {k: jnp.asarray(np.moveaxis(v, 0, -1)) for k, v in
-                           self.ir.narrow(self.lay,
-                                          self.ir.widen(roots)).items()}
-                # invariants/constraints for the root cohort (levels get
-                # theirs inside the chunk step; roots bypass it)
-                inv_r, con_r = self._phase2(
-                    {k: jnp.asarray(roots[k]) for k in roots})
-                carry = self._place_roots(
-                    carry, roots_n, jnp.asarray(self._host_probe_assign(rk)),
-                    jnp.asarray(rk), inv_r, con_r)
+                # placed in the level buffer + visited table, then
+                # finalized.  One jitted set-up program allocates the
+                # carry on the device, narrows the roots, evaluates their
+                # invariants/constraints and places them (_setup_carry);
+                # only the root rows, slots and keys cross to the device,
+                # as that call's arguments.
+                carry = self._setup_carry(roots, rk)
                 n_states = 0
                 n_vis = 0
                 depth = 0

@@ -63,7 +63,6 @@ host BEFORE constructing the engine, then build with
 from __future__ import annotations
 
 import re
-from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -182,8 +181,8 @@ class PjitShardedEngine(Engine):
     def __init__(self, cfg: ModelConfig, devices=None, **kw):
         devices = list(devices) if devices is not None else jax.devices()
         # Auto axes: GSPMD propagates the carry shardings through the
-        # root placement's .at[].set (Explicit axes would reshard each
-        # operand and refuse the replicated 1-row updates)
+        # set-up program's root writes (Explicit axes would reshard
+        # each operand and refuse the replicated few-row updates)
         self.mesh = jax.make_mesh((len(devices),), ("d",),
                                   axis_types=(jax.sharding.AxisType.Auto,),
                                   devices=devices)
@@ -196,9 +195,13 @@ class PjitShardedEngine(Engine):
         self._table_sh = NamedSharding(self.mesh, P("d"))
         # rule-matched spec tree over the carry template (structure
         # only; shardings are shape-free, so one tree serves every
-        # capacity growth)
+        # capacity growth).  Setting _carry_sh makes the base engine's
+        # jitted carry builders (_fresh_carry, _setup_carry) bear every
+        # buffer under its named sharding: no host-side materialization
+        # of the multi-GB state, the pod-scale point
         template = jax.eval_shape(
-            lambda: Engine._fresh_carry(self, self.LCAP, self.VCAP))
+            lambda: self._fresh_carry_impl(self.LCAP, self.VCAP,
+                                           self.FCAP, self.OCAP))
         self._carry_specs = match_partition_rules(CARRY_RULES, template)
         self._carry_sh = jax.tree_util.tree_map(
             lambda spec: NamedSharding(self.mesh, spec),
@@ -227,26 +230,7 @@ class PjitShardedEngine(Engine):
         self._shard_carry = jax.jit(lambda c: c,
                                     out_shardings=self._carry_sh)
         self._gather_rep = jax.jit(lambda x: x, out_shardings=rep)
-        self._fresh_jit_cache = {}
         self._seed_table_cache = {}
-
-    # -- sharded state construction -----------------------------------
-
-    def _fresh_carry(self, lcap: int, vcap: int,
-                     fcap: Optional[int] = None,
-                     ocap: Optional[int] = None):
-        """The base builder, jitted with the carry's out_shardings so
-        every buffer is BORN under its named sharding (no host-side
-        materialization of the multi-GB state — the pod-scale point)."""
-        key = (lcap, vcap, fcap, ocap)
-        fn = self._fresh_jit_cache.get(key)
-        if fn is None:
-            fn = jax.jit(
-                lambda: Engine._fresh_carry(self, lcap, vcap, fcap,
-                                            ocap),
-                out_shardings=self._carry_sh)
-            self._fresh_jit_cache[key] = fn
-        return fn()
 
     def _fetch(self, x) -> np.ndarray:
         """Harvest-path reads gather to a replicated array first, so

@@ -327,6 +327,43 @@ def test_burst_bailout_reuses_warmed_per_level_executable():
             assert r.depth - r.levels_fused >= 1
 
 
+def test_setup_program_compiles_in_compile_span():
+    """The fresh-start set-up program (Engine._setup_carry) compiles
+    inside the traced check's ONE compile span, beside the step and
+    finalize; a second check at the same capacities adds no cache
+    entry.  The program is keyed on the exact root count: a 3-seed
+    check adds one entry, which a second 3-seed check reuses."""
+    from raft_tla_tpu.engine.bfs import Engine
+    from raft_tla_tpu.models.explore import explore
+    rec = SpanRecorder()
+    obs = Obs(spans=rec)
+    eng = Engine(TINY, chunk=64, store_states=False)
+    warmed = []
+    prewarm = eng._prewarm_perlevel
+
+    def spy(*a):
+        prewarm(*a)
+        warmed.append(set(eng._setup_jit_cache))
+
+    eng._prewarm_perlevel = spy
+    eng.check(obs=obs)
+    caps = (eng.LCAP, eng.VCAP, eng.FCAP, eng.OCAP)
+    assert warmed == [{caps + (1,)}]
+    assert rec.totals()["compile"]["count"] == 1
+    eng.check(obs=obs)
+    assert rec.totals()["compile"]["count"] == 1
+    assert set(eng._setup_jit_cache) == {caps + (1,)}
+    seeds = list(explore(TINY, max_depth=2, keep_states=True)
+                 .states.values())
+    for _ in range(2):
+        eng.check(obs=obs, seed_states=seeds[:3], max_depth=2)
+    assert set(eng._setup_jit_cache) == {caps + (1,), caps + (3,)}
+    assert all(fn._cache_size() == 1
+               for fn in eng._setup_jit_cache.values())
+    assert eng._step_jit._cache_size() == 1
+    assert eng._fin_jit._cache_size() == 1
+
+
 # ---------------------------------------------------------------------
 # dedup work counters and the check_setup span (engine/bfs): integer
 # counts of a deterministic program, so exact equalities hold on CPU

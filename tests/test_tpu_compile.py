@@ -125,6 +125,23 @@ def test_carry_growth_compiles_for_v5e(engine, one_chip):
         mem.temp_size_in_bytes < 16e9
 
 
+def test_setup_program_compiles_for_v5e_in_one_carry(engine, one_chip):
+    # a fresh start's one set-up program at config #2 capacities: the
+    # buffer fills and the root writes share the output buffers
+    roots, rk, _ = engine._dedup_roots(None)
+    n = len(rk)
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    compiled = jax.jit(
+        lambda *a: engine._setup_impl(engine.LCAP, engine.VCAP,
+                                      engine.FCAP, engine.OCAP, *a)).lower(
+        {k: arg(v.shape, v.dtype) for k, v in roots.items()},
+        arg((n,), jnp.int32), arg((n, engine.W), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes > 4 << 30
+    assert mem.temp_size_in_bytes * 100 < mem.output_size_in_bytes
+
+
 def test_pjit_carry_placement_compiles_on_4_chip_mesh(topo):
     from raft_tla_tpu.engine.bfs import Engine
     from raft_tla_tpu.parallel.pjit_mesh import PjitShardedEngine
