@@ -20,7 +20,8 @@ from typing import List
 HERE = os.path.dirname(os.path.abspath(__file__))
 SRC = os.path.join(os.path.dirname(HERE), "reference", "plain_bfs.cc")
 BOUNDS = ("max_log_length", "max_restarts", "max_timeouts", "max_terms",
-          "max_client_requests")
+          "max_client_requests", "max_membership_changes",
+          "max_tried_membership_changes")
 
 
 @dataclass
@@ -59,6 +60,7 @@ def arguments(model: dict, max_depth: int, fp_bits: int = 0) -> List[str]:
         "init_servers": ints(model["init_servers"]),
         "values": ints(model["values"]),
         "next": model["next"],
+        "num_rounds": model["num_rounds"],
         "symmetry": int(model["symmetry"]),
         "max_inflight_messages": model["max_inflight_messages"],
         "constraints": ",".join(model["constraints"]),

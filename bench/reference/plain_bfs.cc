@@ -10,7 +10,10 @@
 //     (std::unordered_set of byte strings), so no two distinct states
 //     can ever merge;
 //   * SYMMETRY is the lexicographically least byte string over every
-//     relabeling of Server that maps InitServer onto itself;
+//     relabeling of Server that maps InitServer onto itself; a relabeling
+//     renames servers everywhere they appear: votedFor, vote sets, the
+//     server sets of config entries, message ends and CheckOldConfig's
+//     mserver;
 //   * VIEW vars (raft.cfg) leaves `history` out of a state's identity;
 //     the first state of a VIEW class met in BFS order is the one kept
 //     and expanded (frontier order, then the order of Next's disjuncts);
@@ -19,11 +22,14 @@
 //   * the message bag is a function message -> count kept sorted by the
 //     message's bytes; Receive, Duplicate and Drop walk it in that order.
 //
-// Only what the two benchmark configurations reach is written: the
-// families NextAsync, NextAsyncCrash and Next (no membership actions, so
-// no config entries, catch-up or CheckOldConfig messages; GetConfig is
-// InitServer), four invariants and the constraints they list.  Anything
-// else is refused (exit 2) rather than guessed.
+// The families NextAsync, NextAsyncCrash, Next and NextDynamic (Next with
+// AddNewServer and DeleteServer, SURVEY §2.6): typed log entries,
+// GetConfig from the log, catch-up and CheckOldConfig messages.  The
+// safety invariants of raft.cfg, the two `_false` forms, the authored
+// OneAtATimeMembershipChangeOK, and the constraints of §2.8.  Anything
+// else (scenario properties, the prefix pins, unknown names) is refused
+// (exit 2) rather than guessed, and so is a state that outgrows the
+// fixed capacities below.
 //
 // The control: fp_bits=N (1..63) replaces the exact set by a set of the
 // canonical strings' 64-bit hashes cut to N bits, a lossy dedup key that
@@ -45,37 +51,48 @@ namespace {
 
 constexpr int SMAX = 5;    // servers
 constexpr int LMAX = 8;    // entries in one log or one message
-constexpr int KMAX = 40;   // distinct messages in the bag
+constexpr int KMAX = 40;   // distinct messages in the bag: at four servers a
+                           // state holds at most MaxInFlightMessages + 1 = 33
 constexpr uint8_t NIL = 255;
+constexpr uint8_t ABSENT = 255;   // a field the message record lacks
 
 enum Role : uint8_t { FOLLOWER = 0, CANDIDATE = 1, LEADER = 2 };
-enum MType : uint8_t { RVREQ = 1, RVRESP = 2, AEREQ = 3, AERESP = 4 };
+enum MType : uint8_t {
+  RVREQ = 1, RVRESP = 2, AEREQ = 3, AERESP = 4,
+  CATREQ = 5, CATRESP = 6, CHECKOLD = 7
+};
+enum EType : uint8_t { VALUE_ENTRY = 0, CONFIG_ENTRY = 1 };
 
 [[noreturn]] void die(const char *what) {
   std::fprintf(stderr, "plain_bfs: %s\n", what);
   std::exit(2);
 }
 
-// A log entry [term, type |-> ValueEntry, value].
+// A log entry (SURVEY §2.1): a ValueEntry [term, value] or a ConfigEntry
+// [term, server set]; `value` holds the value or the set's bits.
 struct Entry {
-  uint8_t term, value;
+  uint8_t term, value, type;
   bool operator==(const Entry &o) const {
-    return term == o.term && value == o.value;
+    return term == o.term && value == o.value && type == o.type;
   }
   bool operator!=(const Entry &o) const { return !(*this == o); }
 };
 
 // A message record, laid out as bytes so that equality and order are
 // those of the whole record.  Fields by type:
-//   RVREQ  a=mlastLogTerm b=mlastLogIndex
-//   RVRESP a=mvoteGranted, ents=mlog
-//   AEREQ  a=mprevLogIndex b=mprevLogTerm c=mcommitIndex, ents=mentries
-//   AERESP a=msuccess b=mmatchIndex
+//   RVREQ    a=mlastLogTerm b=mlastLogIndex
+//   RVRESP   a=mvoteGranted, ents=mlog
+//   AEREQ    a=mprevLogIndex b=mprevLogTerm c=mcommitIndex, ents=mentries
+//   AERESP   a=msuccess b=mmatchIndex
+//   CATREQ   a=mlogLen b=mcommitIndex (ABSENT in a follow-up round)
+//            c=mrounds, ents=mentries
+//   CATRESP  a=msuccess b=mmatchIndex c=mroundsLeft
+//   CHECKOLD a=madd b=mserver (src = dst: sent to itself)
 struct Msg {
   uint8_t type, term, src, dst, a, b, c, n;
   Entry ents[LMAX];
 };
-static_assert(sizeof(Msg) == 8 + 2 * LMAX, "Msg has no padding");
+static_assert(sizeof(Msg) == 8 + 3 * LMAX, "Msg has no padding");
 
 inline int msg_cmp(const Msg &x, const Msg &y) {
   return std::memcmp(&x, &y, sizeof(Msg));
@@ -91,18 +108,22 @@ struct State {
   uint8_t nmsg;
   Msg msg[KMAX];          // sorted by bytes, each with count >= 1
   uint8_t cnt[KMAX];
-  // history: outside the VIEW, read by the constraints
+  // history: outside the VIEW, read by the constraints and by
+  // LeaderVotesQuorum and CandidateTermNotInLog
   uint8_t restarted[SMAX], timeouts[SMAX];
   uint16_t hadNumLeaders, hadNumClientRequests;
+  uint16_t hadNumTriedMembershipChanges, hadNumMembershipChanges;
 };
 
 struct Cfg {
   int S = 0, nvals = 0, vals[8] = {0};
   uint8_t init_mask = 0;
-  int family = -1;              // 0 NextAsync, 1 NextAsyncCrash, 2 Next
+  int family = -1;    // 0 NextAsync, 1 NextAsyncCrash, 2 Next, 3 NextDynamic
   bool symmetry = false;
   int max_log = 0, max_restarts = 0, max_timeouts = 0, max_terms = 0;
   int max_client_requests = 0, max_inflight = 0;
+  int max_membership_changes = 0, max_tried_membership_changes = 0;
+  int num_rounds = 1;
   std::vector<std::string> constraints, invariants;
   int max_depth = 0, fp_bits = 0;
   std::vector<std::vector<uint8_t>> perms;   // sigma: old -> new
@@ -159,13 +180,37 @@ int last_term(const State &s, int i) {
   return s.len[i] ? s.log[i][s.len[i] - 1].term : 0;
 }
 
-bool is_quorum(uint8_t set, uint8_t config) {       // Quorum(config)
+bool is_quorum(uint8_t set, uint8_t config) {       // set \in Quorum(config)
   if (set & ~config) return false;
   return 2 * __builtin_popcount(set) > __builtin_popcount(config);
 }
 
-// GetConfig(i): no config entry is ever appended here, so InitServer.
-uint8_t get_config(const Cfg &c, const State &, int) { return c.init_mask; }
+// GetMaxConfigIndex(i) (H7, :346-351): the index of the last ConfigEntry
+// in log[i], 0 if there is none.
+int max_config_index(const State &s, int i) {
+  for (int p = s.len[i]; p > 0; --p)
+    if (s.log[i][p - 1].type == CONFIG_ENTRY) return p;
+  return 0;
+}
+
+// GetConfig(i) (H8, :354-360): the server set of that entry, committed
+// or not; InitServer when the log holds none.
+uint8_t get_config(const Cfg &c, const State &s, int i) {
+  int p = max_config_index(s, i);
+  return p ? s.log[i][p - 1].value : c.init_mask;
+}
+
+void append(State &t, int i, const Entry &e) {
+  if (t.len[i] >= LMAX) die("log longer than LMAX");
+  t.log[i][t.len[i]++] = e;
+}
+
+// SubSeq(log[i], from, commitIndex[i]) as a catch-up message's mentries.
+void catchup_entries(const State &s, int i, int from, Msg &m) {
+  if (s.commitIndex[i] > s.len[i]) die("a commitIndex past the log");
+  for (int k = from; k <= s.commitIndex[i]; ++k)
+    m.ents[m.n++] = s.log[i][k - 1];
+}
 
 // ------------------------------------------------------------ actions
 
@@ -244,9 +289,8 @@ void become_leader(const Cfg &c, const State &s, int i, Out &o) {
 
 void client_request(const Cfg &, const State &s, int i, int v, Out &o) {
   if (s.state[i] != LEADER) return;
-  if (s.len[i] >= LMAX) die("log longer than LMAX");
   State t = s;
-  t.log[i][t.len[i]++] = Entry{s.currentTerm[i], (uint8_t)v};
+  append(t, i, Entry{s.currentTerm[i], (uint8_t)v, VALUE_ENTRY});
   t.hadNumClientRequests++;
   o.emit(t);
 }
@@ -267,13 +311,47 @@ void advance_commit_index(const Cfg &c, const State &s, int i, Out &o) {
   o.emit(t);
 }
 
+// A8 AddNewServer(i, j) (:542-555): the leader resets j's term and vote
+// (it writes another server's variables, a modelling shortcut) and sends
+// the first CatchupRequest.  SendDirect counts the try (:249-254).
+void add_new_server(const Cfg &c, const State &s, int i, int j, Out &o) {
+  if (s.state[i] != LEADER) return;
+  if (get_config(c, s, i) >> j & 1) return;
+  State t = s;
+  t.currentTerm[j] = 1;
+  t.votedFor[j] = NIL;
+  Msg m = make_msg(CATREQ, s.currentTerm[i], i, j);
+  m.a = s.matchIndex[i][j];
+  m.b = s.commitIndex[i];
+  m.c = (uint8_t)c.num_rounds;
+  catchup_entries(s, i, s.nextIndex[i][j], m);
+  with_message(t, m);
+  t.hadNumTriedMembershipChanges++;
+  o.emit(t);
+}
+
+// A9 DeleteServer(i, j) (:558-569): a CheckOldConfig(madd = FALSE) the
+// leader sends to itself; SendDirect counts the try (:249-254).
+void delete_server(const Cfg &c, const State &s, int i, int j, Out &o) {
+  if (s.state[i] != LEADER || i == j) return;
+  if (s.state[j] != FOLLOWER && s.state[j] != CANDIDATE) return;
+  if (!(get_config(c, s, i) >> j & 1)) return;
+  State t = s;
+  Msg m = make_msg(CHECKOLD, s.currentTerm[i], i, i);
+  m.a = 0;
+  m.b = (uint8_t)j;
+  with_message(t, m);
+  t.hadNumTriedMembershipChanges++;
+  o.emit(t);
+}
+
 // Reply(response, request): discard the request, send the response.
 void reply(State &t, const Msg &resp, const Msg &req) {
   without_message(t, req);
   with_message(t, resp);
 }
 
-void receive(const Cfg &, const State &s, int k, Out &o) {
+void receive(const Cfg &c, const State &s, int k, Out &o) {
   const Msg m = s.msg[k];
   int i = m.dst, j = m.src;
   int ct = s.currentTerm[i];
@@ -343,12 +421,11 @@ void receive(const Cfg &, const State &s, int k, Out &o) {
         } else if (s.len[i] >= index) {
           State t = s;             // conflict: drop the last entry
           t.len[i]--;
-          t.log[i][t.len[i]] = Entry{0, 0};
+          t.log[i][t.len[i]] = Entry{0, 0, 0};
           o.emit(t);
         } else if (s.len[i] == prev) {
           State t = s;             // no conflict: append the entry
-          if (t.len[i] >= LMAX) die("log longer than LMAX");
-          t.log[i][t.len[i]++] = m.ents[0];
+          append(t, i, m.ents[0]);
           o.emit(t);
         }
       }
@@ -367,6 +444,88 @@ void receive(const Cfg &, const State &s, int k, Out &o) {
         }
       }
       without_message(t, m);
+      o.emit(t);
+      break;
+    }
+    case CATREQ: {                 // R7 HandleCatchupRequest (:718-745)
+      State t = s;
+      if (m.term < ct) {           // stale: refuse
+        Msg r = make_msg(CATRESP, ct, i, j);
+        r.a = 0;
+        r.b = 0;
+        r.c = 0;
+        reply(t, r, m);
+        o.emit(t);
+        break;
+      }
+      // adopt the term; log[i] := SubSeq(log[i], 1, Min({mlogLen,
+      // Len(log[i])})) \o mentries, the spec's splice (not the older
+      // one its comment keeps); state and votedFor stay
+      t.currentTerm[i] = m.term;
+      int keep = std::min<int>(m.a, s.len[i]);
+      if (keep + m.n > LMAX) die("log longer than LMAX");
+      for (int p = 0; p < m.n; ++p) t.log[i][keep + p] = m.ents[p];
+      for (int p = keep + m.n; p < LMAX; ++p) t.log[i][p] = Entry{0, 0, 0};
+      t.len[i] = (uint8_t)(keep + m.n);
+      Msg r = make_msg(CATRESP, m.term, i, j);
+      r.a = 1;
+      r.b = s.len[i];              // Len(log[i]) before the splice (:740)
+      r.c = (uint8_t)(m.c - 1);
+      reply(t, r, m);
+      o.emit(t);
+      break;
+    }
+    case CATRESP: {                // R8 HandleCatchupResponse (:748-792)
+      bool progress = m.b == s.commitIndex[i] || m.b != s.matchIndex[i][j];
+      bool accept = m.a && progress && s.state[i] == LEADER &&
+                    m.term == ct && !(get_config(c, s, i) >> j & 1);
+      State t = s;
+      if (!accept) {               // the five discard disjuncts
+        without_message(t, m);
+        o.emit(t);
+        break;
+      }
+      int next = s.nextIndex[i][j];
+      t.nextIndex[i][j] = (uint8_t)(m.b + 1);
+      t.matchIndex[i][j] = m.b;
+      Msg r;
+      if (m.c != 0) {              // another round (:761-771): from the
+        r = make_msg(CATREQ, ct, i, j);   // old nextIndex, no mcommitIndex
+        r.a = (uint8_t)(next - 1);
+        r.b = ABSENT;
+        r.c = m.c;
+        catchup_entries(s, i, next, r);
+      } else {                     // caught up (:772-782)
+        r = make_msg(CHECKOLD, ct, i, i);
+        r.a = 1;
+        r.b = (uint8_t)j;
+      }
+      reply(t, r, m);
+      o.emit(t);
+      break;
+    }
+    case CHECKOLD: {               // R9 HandleCheckOldConfig (:795-822)
+      // discard (:796): a current leader may take this branch too
+      if (s.state[i] != LEADER || m.term == ct) {
+        State t = s;
+        without_message(t, m);
+        o.emit(t);
+      }
+      if (s.state[i] != LEADER || m.term != ct) break;
+      State t = s;
+      if (max_config_index(s, i) <= s.commitIndex[i]) {
+        // the previous config is committed: one change at a time (:800)
+        uint8_t config = get_config(c, s, i);
+        uint8_t next = m.a ? (uint8_t)(config | 1u << m.b)
+                           : (uint8_t)(config & ~(1u << m.b));
+        if (next != config) {      // DiscardDirectWithMembershipChange
+          append(t, i, Entry{s.currentTerm[i], next, CONFIG_ENTRY});
+          t.hadNumMembershipChanges++;
+        }
+        without_message(t, m);
+      }
+      // else the same message again to itself (:813-821): Reply(m, m)
+      // leaves the bag as it was
       o.emit(t);
       break;
     }
@@ -407,6 +566,12 @@ void successors(const Cfg &c, const State &s, Out &o) {
     for (int k = 0; k < s.nmsg; ++k) duplicate_message(s, k, o);
     for (int k = 0; k < s.nmsg; ++k) drop_message(s, k, o);
   }
+  if (c.family >= 3) {             // NextDynamic (:940-943)
+    for (int i = 0; i < c.S; ++i)
+      for (int j = 0; j < c.S; ++j) add_new_server(c, s, i, j, o);
+    for (int i = 0; i < c.S; ++i)
+      for (int j = 0; j < c.S; ++j) delete_server(c, s, i, j, o);
+  }
 }
 
 // -------------------------------------------------------- constraints
@@ -442,6 +607,12 @@ bool in_model(const Cfg &c, const State &s) {
   if (has(k, "BoundedClientRequests") &&
       s.hadNumClientRequests > c.max_client_requests)
     return false;
+  if (has(k, "BoundedTriedMembershipChanges") &&
+      s.hadNumTriedMembershipChanges > c.max_tried_membership_changes)
+    return false;
+  if (has(k, "BoundedMembershipChanges") &&
+      s.hadNumMembershipChanges > c.max_membership_changes)
+    return false;
   if (has(k, "ElectionsUncontested") && candidates > 1) return false;
   if (has(k, "CleanStartUntilFirstRequest") && s.hadNumLeaders < 1 &&
       s.hadNumClientRequests < 1 &&
@@ -458,10 +629,15 @@ bool in_model(const Cfg &c, const State &s) {
 
 // --------------------------------------------------------- invariants
 
-// IsPrefix(Committed(i), log[j]); Committed(i) is SubSeq(log[i], 1,
-// commitIndex[i]), read as the first min(commitIndex, Len) entries.
+// Committed(i) == SubSeq(log[i], 1, commitIndex[i]) (:969), read as the
+// first min(commitIndex, Len) entries: its length.
+int committed_len(const State &s, int i) {
+  return std::min(s.commitIndex[i], s.len[i]);
+}
+
+// IsPrefix(Committed(i), log[j]).
 bool committed_is_prefix(const State &s, int i, int j) {
-  int n = std::min(s.commitIndex[i], s.len[i]);
+  int n = committed_len(s, i);
   if (n > s.len[j]) return false;
   for (int p = 0; p < n; ++p)
     if (s.log[i][p] != s.log[j][p]) return false;
@@ -512,12 +688,105 @@ bool holds(const Cfg &c, const State &s, const std::string &inv) {
           if (!committed_is_prefix(s, j, i)) return false;
     return true;
   }
+  // LeaderVotesQuorum (:988-993): a leader's voters, those at a higher
+  // term or that voted for it in its term, are a quorum of its config;
+  // stated while no membership change has happened.
+  if (inv == "LeaderVotesQuorum") {
+    if (s.hadNumMembershipChanges != 0) return true;
+    for (int i = 0; i < S; ++i) {
+      if (s.state[i] != LEADER) continue;
+      uint8_t voters = 0;
+      for (int j = 0; j < S; ++j)
+        if (s.currentTerm[j] > s.currentTerm[i] ||
+            (s.currentTerm[j] == s.currentTerm[i] && s.votedFor[j] == i))
+          voters |= 1u << j;
+      if (!is_quorum(voters, get_config(c, s, i))) return false;
+    }
+    return true;
+  }
+  // CandidateTermNotInLog (:997-1004): a candidate that could still win
+  // (a quorum of its config at its term voted for it or for no one) has
+  // its term in no log; the same guard.
+  if (inv == "CandidateTermNotInLog") {
+    if (s.hadNumMembershipChanges != 0) return true;
+    for (int i = 0; i < S; ++i) {
+      if (s.state[i] != CANDIDATE) continue;
+      uint8_t voters = 0;
+      for (int j = 0; j < S; ++j)
+        if (s.currentTerm[j] == s.currentTerm[i] &&
+            (s.votedFor[j] == i || s.votedFor[j] == NIL))
+          voters |= 1u << j;
+      if (!is_quorum(voters, get_config(c, s, i))) continue;
+      for (int j = 0; j < S; ++j)
+        for (int p = 0; p < s.len[j]; ++p)
+          if (s.log[j][p].term == s.currentTerm[i]) return false;
+    }
+    return true;
+  }
+  // VotesGrantedInv (:1048-1052): votedFor[i] = j => IsPrefix(
+  // Committed(i), log[j]).
+  if (inv == "VotesGrantedInv") {
+    for (int i = 0; i < S; ++i)
+      if (s.votedFor[i] != NIL && !committed_is_prefix(s, i, s.votedFor[i]))
+        return false;
+    return true;
+  }
+  // QuorumLogInv (:1056-1060): every quorum of GetConfig(i) holds a
+  // server whose log begins with Committed(i); so the config's servers
+  // whose logs do not are no quorum.
+  if (inv == "QuorumLogInv") {
+    for (int i = 0; i < S; ++i) {
+      uint8_t config = get_config(c, s, i), lacking = 0;
+      for (int j = 0; j < S; ++j)
+        if ((config >> j & 1) && !committed_is_prefix(s, i, j))
+          lacking |= 1u << j;
+      if (is_quorum(lacking, config)) return false;
+    }
+    return true;
+  }
+  // MoreUpToDateCorrect (:1066-1071): a log at least as up to date as
+  // log[j] begins with Committed(j).
+  if (inv == "MoreUpToDateCorrect") {
+    for (int i = 0; i < S; ++i)
+      for (int j = 0; j < S; ++j) {
+        int ti = last_term(s, i), tj = last_term(s, j);
+        bool more = ti > tj || (ti == tj && s.len[i] >= s.len[j]);
+        if (more && !committed_is_prefix(s, j, i)) return false;
+      }
+    return true;
+  }
+  // LeaderCompleteness (:1089-1099): a committed entry is at the same
+  // index in the log of every leader whose term is above the entry's.
+  if (inv == "LeaderCompleteness") {
+    for (int i = 0; i < S; ++i)
+      for (int p = 0; p < committed_len(s, i); ++p) {
+        const Entry &e = s.log[i][p];
+        for (int l = 0; l < S; ++l)
+          if (s.state[l] == LEADER && s.currentTerm[l] > e.term &&
+              (s.len[l] <= p || s.log[l][p] != e))
+            return false;
+      }
+    return true;
+  }
+  // OneAtATimeMembershipChangeOK, the authored invariant (SURVEY's
+  // preamble): at most one ConfigEntry in each log beyond commitIndex.
+  if (inv == "OneAtATimeMembershipChangeOK") {
+    for (int i = 0; i < S; ++i) {
+      int pending = 0;
+      for (int p = s.commitIndex[i]; p < s.len[i]; ++p)
+        pending += s.log[i][p].type == CONFIG_ENTRY;
+      if (pending > 1) return false;
+    }
+    return true;
+  }
   die("an invariant this reference does not know");
 }
 
 // ---------------------------------------------------- canonical VIEW
 
-// The VIEW of `s` with every server renamed by sigma, as bytes.
+// The VIEW of `s` with every server renamed by sigma, as bytes.  An
+// entry is two bytes: its term with the type in the top bit, then its
+// value or its renamed server set.
 void view_bytes(const Cfg &c, const State &s, const uint8_t *sigma,
                 std::string &out) {
   int S = c.S;
@@ -529,6 +798,14 @@ void view_bytes(const Cfg &c, const State &s, const uint8_t *sigma,
       if (set >> i & 1) r |= 1u << sigma[i];
     return r;
   };
+  auto ren_entry = [&](Entry e) {
+    if (e.type == CONFIG_ENTRY) e.value = ren(e.value);
+    return e;
+  };
+  auto put_entry = [&](const Entry &e) {
+    out.push_back((char)(e.term | e.type << 7));
+    out.push_back((char)e.value);
+  };
   out.clear();
   for (int k = 0; k < S; ++k) {       // the server now named k
     int i = inv[k];
@@ -537,10 +814,7 @@ void view_bytes(const Cfg &c, const State &s, const uint8_t *sigma,
     out.push_back((char)(s.votedFor[i] == NIL ? NIL : sigma[s.votedFor[i]]));
     out.push_back((char)s.commitIndex[i]);
     out.push_back((char)s.len[i]);
-    for (int p = 0; p < s.len[i]; ++p) {
-      out.push_back((char)s.log[i][p].term);
-      out.push_back((char)s.log[i][p].value);
-    }
+    for (int p = 0; p < s.len[i]; ++p) put_entry(ren_entry(s.log[i][p]));
     out.push_back((char)ren(s.votesResponded[i]));
     out.push_back((char)ren(s.votesGranted[i]));
     for (int l = 0; l < S; ++l) out.push_back((char)s.nextIndex[i][inv[l]]);
@@ -550,9 +824,12 @@ void view_bytes(const Cfg &c, const State &s, const uint8_t *sigma,
   Msg ms[KMAX];
   int order[KMAX];
   for (int q = 0; q < s.nmsg; ++q) {
-    ms[q] = s.msg[q];
-    ms[q].src = sigma[ms[q].src];
-    ms[q].dst = sigma[ms[q].dst];
+    Msg &m = ms[q];
+    m = s.msg[q];
+    m.src = sigma[m.src];
+    m.dst = sigma[m.dst];
+    if (m.type == CHECKOLD) m.b = sigma[m.b];
+    for (int p = 0; p < m.n; ++p) m.ents[p] = ren_entry(m.ents[p]);
     order[q] = q;
   }
   std::sort(order, order + s.nmsg, [&](int x, int y) {
@@ -561,7 +838,8 @@ void view_bytes(const Cfg &c, const State &s, const uint8_t *sigma,
   out.push_back((char)s.nmsg);
   for (int q = 0; q < s.nmsg; ++q) {
     const Msg &m = ms[order[q]];
-    out.append(reinterpret_cast<const char *>(&m), 8 + 2 * m.n);
+    out.append(reinterpret_cast<const char *>(&m), 8);
+    for (int p = 0; p < m.n; ++p) put_entry(m.ents[p]);
     out.push_back((char)s.cnt[order[q]]);
   }
 }
@@ -609,6 +887,12 @@ std::vector<std::string> words(const std::string &v) {
   return r;
 }
 
+bool known(const std::vector<const char *> &names, const std::string &k) {
+  return std::find_if(names.begin(), names.end(), [&](const char *n) {
+           return k == n;
+         }) != names.end();
+}
+
 Cfg parse(int argc, char **argv) {
   Cfg c;
   std::vector<int> init;
@@ -617,7 +901,8 @@ Cfg parse(int argc, char **argv) {
     size_t eq = kv.find('=');
     if (eq == std::string::npos) die("arguments are key=value");
     std::string k = kv.substr(0, eq), v = kv.substr(eq + 1);
-    if (k == "servers") c.S = std::atoi(v.c_str());
+    int n = std::atoi(v.c_str());
+    if (k == "servers") c.S = n;
     else if (k == "init_servers") init = ints(v);
     else if (k == "values") {
       auto vs = ints(v);
@@ -628,40 +913,50 @@ Cfg parse(int argc, char **argv) {
       if (v == "NextAsync") c.family = 0;
       else if (v == "NextAsyncCrash") c.family = 1;
       else if (v == "Next") c.family = 2;
+      else if (v == "NextDynamic") c.family = 3;
       else die("a Next family this reference does not know");
     } else if (k == "symmetry") c.symmetry = v == "1";
-    else if (k == "max_log_length") c.max_log = std::atoi(v.c_str());
-    else if (k == "max_restarts") c.max_restarts = std::atoi(v.c_str());
-    else if (k == "max_timeouts") c.max_timeouts = std::atoi(v.c_str());
-    else if (k == "max_terms") c.max_terms = std::atoi(v.c_str());
-    else if (k == "max_client_requests")
-      c.max_client_requests = std::atoi(v.c_str());
-    else if (k == "max_inflight_messages")
-      c.max_inflight = std::atoi(v.c_str());
+    else if (k == "max_log_length") c.max_log = n;
+    else if (k == "max_restarts") c.max_restarts = n;
+    else if (k == "max_timeouts") c.max_timeouts = n;
+    else if (k == "max_terms") c.max_terms = n;
+    else if (k == "max_client_requests") c.max_client_requests = n;
+    else if (k == "max_membership_changes") c.max_membership_changes = n;
+    else if (k == "max_tried_membership_changes")
+      c.max_tried_membership_changes = n;
+    else if (k == "num_rounds") c.num_rounds = n;
+    else if (k == "max_inflight_messages") c.max_inflight = n;
     else if (k == "constraints") c.constraints = words(v);
     else if (k == "invariants") c.invariants = words(v);
-    else if (k == "max_depth") c.max_depth = std::atoi(v.c_str());
-    else if (k == "fp_bits") c.fp_bits = std::atoi(v.c_str());
+    else if (k == "max_depth") c.max_depth = n;
+    else if (k == "fp_bits") c.fp_bits = n;
     else die("an unknown argument");
   }
   if (c.S < 1 || c.S > SMAX) die("servers out of range");
   if (c.family < 0) die("no next");
   if (c.max_log + 1 > LMAX) die("max_log_length too large for LMAX");
+  if (c.num_rounds < 1 || c.num_rounds > 255) die("num_rounds out of range");
   if (c.fp_bits < 0 || c.fp_bits > 63) die("fp_bits out of range");
-  static const char *known[] = {
+  static const std::vector<const char *> constraints = {
       "BoundedInFlightMessages", "BoundedRequestVote", "BoundedLogSize",
       "BoundedRestarts", "BoundedTimeouts", "BoundedTerms",
-      "BoundedClientRequests", "ElectionsUncontested",
+      "BoundedClientRequests", "BoundedTriedMembershipChanges",
+      "BoundedMembershipChanges", "ElectionsUncontested",
       "CleanStartUntilFirstRequest", "CleanStartUntilTwoLeaders",
-      "CleanFirstLeaderElection",
-      // no membership action is enabled, so these two always hold
-      "BoundedTriedMembershipChanges", "BoundedMembershipChanges"};
+      "CleanFirstLeaderElection"};
+  static const std::vector<const char *> invariants = {
+      "LeaderVotesQuorum", "CandidateTermNotInLog", "ElectionSafety",
+      "LogMatching", "VotesGrantedInv", "VotesGrantedInv_false",
+      "QuorumLogInv", "MoreUpToDateCorrect", "LeaderCompleteness",
+      "LeaderCompleteness_false", "OneAtATimeMembershipChangeOK"};
   for (auto &k : c.constraints)
-    if (std::find_if(std::begin(known), std::end(known),
-                     [&](const char *n) { return k == n; }) ==
-        std::end(known))
-      die("a constraint this reference does not know");
-  for (int i : init) c.init_mask |= 1u << i;
+    if (!known(constraints, k)) die("a constraint this reference does not know");
+  for (auto &k : c.invariants)
+    if (!known(invariants, k)) die("an invariant this reference does not know");
+  for (int i : init) {
+    if (i < 0 || i >= c.S) die("an init server out of range");
+    c.init_mask |= 1u << i;
+  }
   // relabelings of Server that map InitServer onto itself
   std::vector<uint8_t> sigma(c.S);
   for (int i = 0; i < c.S; ++i) sigma[i] = (uint8_t)i;

@@ -6,10 +6,13 @@
   contract's limits;
 - the harness on a CPU exits non-zero and prints no result;
 - two back-to-back checks on one engine answer identically, and equal
-  the reference, at micro bounds of both configurations;
+  the reference, at micro bounds of both configurations and of config
+  #3 (the membership fixture in configs/, which has no cell yet);
 - the plain reference agrees with the program's native checker, a
-  second witness, at micro bounds of both configurations, and refuses
-  what it was not written for;
+  second witness, at micro bounds of all three and at config #3's own
+  bounds to depth 15; the membership actions add states; the two
+  configurations' answers are pinned; the reference refuses what it
+  was not written for;
 - a corrupted level size fails the comparison, the control (a narrow
   dedup key) fails it at the cells' own sizes, and each fault planted
   under the timed path makes a whole run report ``correct`` false;
@@ -37,6 +40,9 @@ from harness.system import System  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 CACHE = os.path.join(ROOT, ".bench_cache", "reference")
+# BASELINE.json config #3: Server 4 beyond InitServer 3, NextDynamic
+CONFIG3 = "raft-tlc-s4-membership"
+MICRO = {"max_log_length": 1, "max_timeouts": 1, "max_client_requests": 1}
 
 
 @pytest.fixture(scope="module")
@@ -49,21 +55,27 @@ def ref_exe():
     return reference.build(CACHE)
 
 
+def _conf_dir(name):
+    if name == CONFIG3:
+        return os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "configs")
+    return os.path.join(BENCH, "configs")
+
+
 def _conf(name):
-    with open(os.path.join(BENCH, "configs", name + ".json")) as fh:
+    with open(os.path.join(_conf_dir(name), name + ".json")) as fh:
         return json.load(fh)
 
 
 def micro(name):
     """The configuration at micro bounds (a CPU-sized space)."""
     c = copy.deepcopy(_conf(name))
-    c["cfg"] = os.path.join(BENCH, "configs", c["cfg"])
-    if name == "raft-tlc-s3-l3":
-        c["bound_flags"] = {"max_log_length": 1, "max_timeouts": 1,
-                            "max_client_requests": 1}
-        c["model"]["bounds"].update(max_log_length=1, max_timeouts=1,
-                                    max_client_requests=1, max_terms=2)
-        c["max_depth"] = 9
+    c["cfg"] = os.path.join(_conf_dir(name), c["cfg"])
+    if name in ("raft-tlc-s3-l3", CONFIG3):
+        c["bound_flags"] = dict(MICRO)
+        c["model"]["bounds"].update(MICRO, max_terms=2)
+        # config #3's membership actions first fire at depth 10
+        c["max_depth"] = 9 if name == "raft-tlc-s3-l3" else 12
         c["engine"] = {"chunk": 256, "store_states": False}
     else:
         c["max_depth"] = 5
@@ -130,7 +142,7 @@ def test_harness_without_a_chip_exits_nonzero():
 # -- the engine against the reference -----------------------------------
 
 @pytest.mark.parametrize("name", ["raft-tlc-s3-l3",
-                                  "raft-apalache-s2-k10"])
+                                  "raft-apalache-s2-k10", CONFIG3])
 def test_back_to_back_checks_answer_identically(name, ref_exe):
     c = micro(name)
     system = System(c, os.path.dirname(c["cfg"]))
@@ -142,15 +154,8 @@ def test_back_to_back_checks_answer_identically(name, ref_exe):
     assert compare.ok(compare.compare([a, b], ref))
 
 
-@pytest.mark.parametrize("name", ["raft-tlc-s3-l3",
-                                  "raft-apalache-s2-k10"])
-def test_reference_agrees_with_the_programs_native_checker(name, ref_exe):
-    """A second witness: the program's native C++ checker (a 64-bit
-    fingerprint set, multithreaded) reads what the plain reference
-    reads, at micro bounds."""
+def _agrees_with_native(c, ref):
     from raft_tla_tpu import native
-    c = micro(name)
-    ref = reference.check(ref_exe, c["model"], c["max_depth"])
     nat = native.check(_program_cfg(c), threads=2,
                        max_depth=c["max_depth"])
     assert (ref.distinct, ref.generated, ref.depth, ref.level_sizes) == (
@@ -161,13 +166,78 @@ def test_reference_agrees_with_the_programs_native_checker(name, ref_exe):
         set(nat.violations))
 
 
+@pytest.mark.parametrize("name", ["raft-tlc-s3-l3",
+                                  "raft-apalache-s2-k10", CONFIG3])
+def test_reference_agrees_with_the_programs_native_checker(name, ref_exe):
+    """A second witness: the program's native C++ checker (a 64-bit
+    fingerprint set, multithreaded) reads what the plain reference
+    reads, at micro bounds."""
+    c = micro(name)
+    _agrees_with_native(c, reference.check(ref_exe, c["model"],
+                                           c["max_depth"]))
+
+
+def test_reference_agrees_with_native_at_config3_bounds(ref_exe):
+    """Config #3 at its own bounds (MaxLogLength 2, MaxTimeouts 1,
+    MaxClientRequests 2) to depth 15, the deepest at which the two
+    agree: from depth 16 the program keeps another member of a VIEW
+    class whose members' histories differ, as the order of its bag's
+    slots falls (PERF.md, section 7)."""
+    c = copy.deepcopy(_conf(CONFIG3))
+    c["cfg"] = os.path.join(_conf_dir(CONFIG3), c["cfg"])
+    c["max_depth"] = 15
+    ref = reference.check(ref_exe, c["model"], 15)
+    assert (ref.distinct, ref.generated, ref.violated) == (180685, 544816,
+                                                           [])
+    assert ref.level_sizes == [1, 2, 4, 10, 20, 35, 56, 91, 141, 213, 382,
+                               1117, 4566, 19757, 80652]
+    _agrees_with_native(c, ref)
+
+
+def test_membership_actions_add_states(ref_exe):
+    """AddNewServer, DeleteServer and their messages do real work: the
+    fixture under NextDynamic reaches more states than under Next."""
+    c = micro(CONFIG3)
+    dyn = reference.check(ref_exe, c["model"], c["max_depth"])
+    stat = reference.check(ref_exe, dict(c["model"], next="Next"),
+                           c["max_depth"])
+    assert dyn.distinct > stat.distinct
+    assert dyn.generated > stat.generated
+
+
+@pytest.mark.parametrize("name,answer", [
+    ("raft-apalache-s2-k10",
+     (35279, 89337, [2, 6, 18, 56, 150, 370, 878, 1982, 4258, 8782], [])),
+    ("raft-tlc-s3-l3",
+     (738319, 1818497, [1, 2, 4, 7, 12, 19, 28, 40, 57, 85, 167, 507, 1942,
+                        7579, 27966, 96189, 309574], [])),
+])
+def test_reference_keeps_the_cells_answers(name, answer, ref_exe):
+    """The two cells' configurations at their own sizes: the reference's
+    answers, pinned as they were before it learnt the membership spec."""
+    c = _conf(name)
+    ref = reference.check(ref_exe, c["model"], c["max_depth"])
+    assert (ref.distinct, ref.generated, ref.level_sizes,
+            ref.violated) == answer
+
+
 def test_reference_refuses_what_it_was_not_written_for(ref_exe):
-    c = micro("raft-apalache-s2-k10")
-    for key, value in (("next", "NextDynamic"),
-                       ("invariants", ["QuorumLogInv"])):
+    """Scenario properties, the prefix pins, unknown families and
+    names: exit 2, not a guess."""
+    c = micro(CONFIG3)
+    pins = ["CommitWhenConcurrentLeaders_constraint",
+            "CommitWhenConcurrentLeaders_unique",
+            "MajorityOfClusterRestarts_constraint"]
+    for key, value in (("next", "NextUnreliable"),
+                       ("invariants", ["MembershipChangeCommits"]),
+                       ("invariants", ["FirstCommit"]),
+                       ("invariants", ["OneLeader"]),
+                       *(("constraints", c["model"]["constraints"] + [p])
+                         for p in pins)):
         model = dict(c["model"], **{key: value})
-        with pytest.raises(subprocess.CalledProcessError):
+        with pytest.raises(subprocess.CalledProcessError) as e:
             reference.check(ref_exe, model, 3)
+        assert e.value.returncode == 2
 
 
 def _program_cfg(c):
