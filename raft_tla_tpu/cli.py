@@ -432,7 +432,7 @@ def cmd_check(args):
             return 2
         # pre-sized capacities: no growth replay, no recompile
         burst_kw.update({k: getattr(args, k) for k in
-                         ("lcap", "vcap", "ocap")
+                         ("lcap", "vcap", "ocap", "fcap")
                          if getattr(args, k) is not None})
 
         def make_engine():
@@ -559,7 +559,8 @@ def cmd_check(args):
     else:
         out = check_stats(r.metrics.as_dict(), secs, len(viol),
                           fp_bits=128 if args.fp128 else 64,
-                          spec=ir.name, ir_fp=ir.fingerprint())
+                          spec=ir.name, ir_fp=ir.fingerprint(),
+                          lanes_enabled=getattr(r, "lanes_enabled", None))
     print(json.dumps(out))
     if args.stats_json:
         # oracle runs write the same stats file (minus the
@@ -1208,7 +1209,8 @@ def main(argv=None):
                          "are bit-identical to the default engine")
     for flag, what in (("--lcap", "level-buffer rows"),
                        ("--vcap", "visited-table slots"),
-                       ("--ocap", "post-dedup fresh rows per chunk")):
+                       ("--ocap", "post-dedup fresh rows per chunk"),
+                       ("--fcap", "enabled candidate lanes per chunk")):
         pc.add_argument(flag, type=int, default=None, metavar="N",
                         help=f"initial {what}: pre-size it for a known "
                              "space, so the run never pays a growth "

@@ -124,6 +124,11 @@ class CheckResult:
       finalize (overflow replays included) and every committed burst
       level.  Integer work of a deterministic program: a check's counts
       are the same on every run and every backend.
+
+    ``lanes_enabled`` (a dict beside the registry: its keys are the
+    spec's action families) — each family's enabled lanes over the
+    check's committed levels, a replayed level counted once.  Without
+    action constraints its sum is ``generated_states`` less the roots.
     """
 
     # the ONE canonical key tuple lives in obs.metrics — aliasing it
@@ -151,6 +156,7 @@ class CheckResult:
             self.metrics.register(nm, int(init[nm]))
         self.violations: List[Violation] = list(violations or [])
         self.level_sizes: List[int] = list(level_sizes or [])
+        self.lanes_enabled: Dict[str, int] = {}
         self.seconds = float(seconds)
         self.phase_seconds: Dict[str, float] = dict(phase_seconds or {})
 
@@ -192,6 +198,26 @@ def _dedup_counts(res: CheckResult) -> Dict[str, int]:
     return {k: res.metrics.get(k) for k in DEDUP_COUNTER_KEYS}
 
 
+def _add_lanes(res: CheckResult, names, counts) -> None:
+    """Add one committed level's (or burst's) enabled lanes per family."""
+    for nm, v in zip(names, counts):
+        res.lanes_enabled[nm] = res.lanes_enabled.get(nm, 0) + int(v)
+
+
+def _check_counters(res: CheckResult, delta_names) -> Dict[str, int]:
+    """The counter sample a check records on its span recorder: the
+    dedup counts, ``lanes_enabled.<Family>``, and ``lanes_kernel_path``,
+    the enabled lanes of the families whose successors the expander
+    built with their kernels rather than the delta matmul
+    (``delta_names``)."""
+    return {**_dedup_counts(res),
+            **{f"lanes_enabled.{nm}": v
+               for nm, v in res.lanes_enabled.items()},
+            "lanes_kernel_path": sum(
+                v for nm, v in res.lanes_enabled.items()
+                if nm not in delta_names)}
+
+
 def _ceil_log2(n: int) -> int:
     return max(1, int(np.ceil(np.log2(max(n, 2)))))
 
@@ -213,6 +239,22 @@ def _leaf_name(key_path) -> str:
 # bit-identical counts.  Engine-specific capacity fields ride in the
 # meta dict the callers supply.
 # ---------------------------------------------------------------------------
+
+# Raft states written since the bag was kept in message order
+# (ops/layout.py); a checkpoint without it holds bags in arrival order,
+# which would walk differently from a fresh run and change the answer.
+BAG_ORDER = 1
+
+
+def ckpt_check_bag_order(path, meta) -> None:
+    """Refuse a Raft checkpoint written before BAG_ORDER."""
+    if meta.get("spec", "raft") == "raft" and \
+            meta.get("bag_order") != BAG_ORDER:
+        raise CheckpointError(
+            f"{path}: checkpoint written before message bags were kept "
+            "in message order; its states would be walked in another "
+            "order than a fresh run's — re-run without --resume")
+
 
 _CKPT_BASE_KEYS = ("cfg", "chunk", "store_states", "n_levels",
                    "distinct", "generated", "depth", "level_sizes",
@@ -253,7 +295,9 @@ def ckpt_write(path, carry, store_states, parents, lanes, states, res,
                 burst_dispatches=res.burst_dispatches,
                 burst_bailouts=res.burst_bailouts,
                 **_dedup_counts(res),
-                n_levels=len(parents), store_states=store_states)
+                lanes_enabled=res.lanes_enabled,
+                n_levels=len(parents), store_states=store_states,
+                bag_order=BAG_ORDER)
     data["meta"] = np.array(json.dumps({**base, **meta}))
     tmp = path + ".tmp.npz"           # .npz suffix: savez won't append
     np.savez(tmp, **data)
@@ -345,6 +389,7 @@ def ckpt_read(path, cfg_repr, chunk, extra_keys, sharded, spill=False,
                 f"{path}: checkpoint written by an older engine "
                 f"version (meta lacks {key!r}) — re-run without "
                 "--resume")
+    ckpt_check_bag_order(path, meta)
     if meta["cfg"] != cfg_repr:
         raise CheckpointError(
             "checkpoint was written for a different model config:\n"
@@ -410,6 +455,7 @@ def ckpt_result(z, meta) -> "CheckResult":
         burst_dispatches=meta.get("burst_dispatches", 0),
         burst_bailouts=meta.get("burst_bailouts", 0),
         **{k: meta.get(k, 0) for k in DEDUP_COUNTER_KEYS})
+    res.lanes_enabled = dict(meta.get("lanes_enabled", {}))
     for nm, sid in zip(z["viol_names"], z["viol_ids"]):
         res.violations.append(Violation(str(nm), int(sid)))
     return res
@@ -961,6 +1007,7 @@ class Engine:
         cand_c, elive, fp, take, famx_c, n_e = self._expand_fp_chunk(
             sv, valid, fam_caps, FCAP)
         famx = jnp.maximum(carry["famx"], famx_c)
+        lanes = carry["lanes"] + famx_c
         fovf = carry["fovf"] | (n_e > FCAP) | \
             jnp.any(famx > jnp.asarray(fam_caps, jnp.int32))
         n_gen = carry["n_gen"] + elive.sum(dtype=jnp.int32)
@@ -1039,7 +1086,7 @@ class Engine:
                     n_lvl=jnp.minimum(carry["n_lvl"] + n_fresh,
                                       LCAP - OCAP),
                     n_gen=n_gen, ovf=ovf, fovf=fovf, hovf=hovf,
-                    oovf=oovf, famx=famx,
+                    oovf=oovf, famx=famx, lanes=lanes,
                     ofx=jnp.maximum(carry["ofx"], n_fresh),
                     dedup=carry["dedup"] + dd,
                     base=base + B)
@@ -1062,8 +1109,9 @@ class Engine:
         """Level finalize.  Returns (carry', outputs) where
         outputs["scal"] packs every per-level scalar the host needs —
         [n_lvl, n_viol, faults, n_front, ovf, fovf, n_gen, n_expand,
-        hovf, oovf, ofx], the per-family enabled maxima, then the
-        level's four dedup counts — into ONE int32 array so the level
+        hovf, oovf, ofx], the per-family enabled maxima, the per-family
+        enabled-lane sums, then the level's four dedup counts — into ONE
+        int32 array so the level
         costs a single device→host round trip.  Invariants/constraints
         were already evaluated per chunk (linv/lcon rows); finalize
         only aggregates, swaps the
@@ -1116,13 +1164,14 @@ class Engine:
             carry["ovf"].astype(jnp.int32), carry["fovf"].astype(jnp.int32),
             carry["n_gen"], n_expand, carry["hovf"].astype(jnp.int32),
             carry["oovf"].astype(jnp.int32), carry["ofx"]]),
-            carry["famx"], carry["dedup"]])
+            carry["famx"], carry["lanes"], carry["dedup"]])
         new_carry = dict(carry, vis=vis, front=front, lvl=lvl,
                          fmask=fmask, n_front=n_front,
                          n_lvl=jnp.int32(0), n_gen=jnp.int32(0),
                          ovf=jnp.bool_(False), fovf=jnp.bool_(False),
                          hovf=jnp.bool_(False), oovf=jnp.bool_(False),
                          famx=jnp.zeros_like(carry["famx"]),
+                         lanes=jnp.zeros_like(carry["lanes"]),
                          ofx=jnp.int32(0),
                          dedup=jnp.zeros_like(carry["dedup"]),
                          base=jnp.int32(0), pg_off=pg_off, g_off=g_next)
@@ -1173,7 +1222,12 @@ class Engine:
 
     _BURST_LEVELS = 16
     _BURST_CHUNKS = 4           # ring width, in frontier chunks
-    _BS_N = 9                   # stats columns (see _burst_core)
+
+    @property
+    def _BS_N(self) -> int:
+        """Stats columns (see _burst_core): five per-level counts, the
+        dedup counts, the enabled lanes per family."""
+        return 5 + len(DEDUP_COUNTER_KEYS) + len(self.expander.families)
 
     @property
     def _burst_chunks(self) -> int:
@@ -1196,8 +1250,8 @@ class Engine:
 
         out["stats"] is int32 [burst_levels + 1, _BS_N]: per-level rows
         [n_lvl, n_viol, faults, n_expand, n_gen] followed by the level's
-        four dedup counts (DEDUP_COUNTER_KEYS order), and a meta
-        row at index burst_levels:
+        four dedup counts (DEDUP_COUNTER_KEYS order) and its enabled
+        lanes per family, and a meta row at index burst_levels:
         [n_levels_done, bail, n_front_out, viol_any, states_done].
         out["par"]/out["lane"] are [L_MAX, KB] int32, out["st"] the
         narrow state rows [..., L_MAX, KB], out["inv"] bool
@@ -1219,6 +1273,7 @@ class Engine:
             vis=vis, claims=claims, fr=fr, fm=fm, gd=gd, nf=nf,
             base=jnp.int32(0), nl=jnp.int32(0), gl=jnp.int32(0),
             dd=jnp.zeros((len(DEDUP_COUNTER_KEYS),), jnp.int32),
+            fl=jnp.zeros((len(self.expander.families),), jnp.int32),
             lv={k: jnp.zeros_like(v) for k, v in fr.items()},
             lvp=jnp.full((KB,), -1, jnp.int32),
             lvlane=jnp.full((KB,), -1, jnp.int32),
@@ -1280,6 +1335,7 @@ class Engine:
             n_fresh = jnp.where(bail, 0, n_fresh)
             n_genl = jnp.where(bail, 0, elive.sum(dtype=jnp.int32))
             gl2 = st["gl"] + n_genl
+            fl2 = st["fl"] + jnp.where(bail, 0, famx_c)
             nl2 = nl + n_fresh
 
             # second compaction (the chunk step's OCAP discipline,
@@ -1330,7 +1386,7 @@ class Engine:
             n_expand = (lco & validrow).sum(dtype=jnp.int32)
             li = st["li"]
             row = jnp.concatenate([
-                jnp.stack([nl2, n_viol, faults, n_expand, gl2]), dd2])
+                jnp.stack([nl2, n_viol, faults, n_expand, gl2]), dd2, fl2])
 
             new = dict(st)
             new["vis"], new["claims"] = vis, claims
@@ -1384,6 +1440,7 @@ class Engine:
             new["nl"] = jnp.where(level_done, 0, nl2)
             new["gl"] = jnp.where(level_done, 0, gl2)
             new["dd"] = jnp.where(level_done, 0, dd2)
+            new["fl"] = jnp.where(level_done, 0, fl2)
             new["bail"] = bail
             new["viol"] = st["viol"] | (level_done & (n_viol > 0))
             return new
@@ -1598,6 +1655,8 @@ class Engine:
             n_lvl=jnp.int32(0),
             n_gen=jnp.int32(0),
             famx=jnp.zeros((len(self.expander.families),), jnp.int32),
+            # the level's enabled lanes per family (lanes_enabled)
+            lanes=jnp.zeros((len(self.expander.families),), jnp.int32),
             ofx=jnp.int32(0),       # max fresh rows in any chunk
             # the level's dedup work counts (DEDUP_COUNTER_KEYS)
             dedup=jnp.zeros((len(DEDUP_COUNTER_KEYS),), jnp.int32),
@@ -1881,6 +1940,7 @@ class Engine:
         if resume_from is not None and resume_image is not None:
             raise ValueError(
                 "resume_from and resume_image are mutually exclusive")
+        fam_names = [f.name for f in self.expander.families]
 
         def prewarm(obs, roots=None, rk=None):
             # per-level executables warm at run start, inside a compile
@@ -1933,6 +1993,8 @@ class Engine:
             nonlocal n_states, n_vis
             n_lvl, n_viol, faults, n_front = scal[:4]
             n_genl = scal[6]
+            nf = len(fam_names)
+            _add_lanes(res, fam_names, scal[11 + nf:11 + 2 * nf])
             res.distinct_states += n_lvl
             res.overflow_faults += faults
             res.generated_states += n_genl
@@ -1995,6 +2057,7 @@ class Engine:
 
                 res = CheckResult(distinct_states=0,
                                   generated_states=n_roots, depth=0)
+                res.lanes_enabled = dict.fromkeys(fam_names, 0)
                 self._check_pin_interiors(pin_interiors, res)
                 while self.LCAP - self.OCAP < 2 * n_roots:
                     self.LCAP *= 2
@@ -2021,7 +2084,8 @@ class Engine:
                 n_front = harvest(carry, out, scal)
         if stop_on_violation and res.violations:
             res.seconds = time.perf_counter() - t0
-            obs.counters(_dedup_counts(res))
+            obs.counters(_check_counters(
+                res, self.expander.delta_family_names))
             return res
 
         # burst_ok gates the speculative burst entry: a burst that
@@ -2098,8 +2162,12 @@ class Engine:
                             res, nlev, lambda li: stats[li, :5],
                             depth, n_states, archive=_arch,
                             violations=_viol, visited=_vis)
-                        # the committed levels' dedup counts (columns 5+)
-                        _add_dedup(res, stats[:nlev, 5:].sum(axis=0))
+                        # the committed levels' dedup counts and
+                        # enabled lanes (columns 5+)
+                        nd = 5 + len(DEDUP_COUNTER_KEYS)
+                        _add_dedup(res, stats[:nlev, 5:nd].sum(axis=0))
+                        _add_lanes(res, fam_names,
+                                   stats[:nlev, nd:].sum(axis=0))
                     if checkpoint_path is not None and \
                             driver.ckpt_due_after_burst(
                                 depth, d0, checkpoint_every):
@@ -2225,7 +2293,8 @@ class Engine:
                       f"{time.perf_counter() - t1:.2f}s")
         res.depth = depth
         res.seconds = time.perf_counter() - t0
-        obs.counters(_dedup_counts(res))
+        obs.counters(_check_counters(res,
+                                     self.expander.delta_family_names))
         return res
 
     def _check_pin_interiors(self, interiors, res: CheckResult):

@@ -15,8 +15,8 @@ InitServer ⊊ Server (models/explore.py symmetry_perms is the oracle twin).
 
 H hashes positional fields with per-position salts and the message bag
 **commutatively** (Σ over slots of count · mix(slot)), so bag slot order
-— or a message split across slots — never affects identity and no
-canonical bag sort exists anywhere in the engine (ops/layout.py).
+— or a message split across slots — never affects identity (the bag's
+message order, ops/layout.py, is the order its lanes walk it in).
 
 Hot-path formulation (the engine fingerprints every fresh candidate, so
 this dominated profiles): because the positional hash is a commutative
@@ -214,7 +214,7 @@ class RaftFingerprinter:
         mtype = get_field(w0, hs["mtype"]).astype(jnp.int32)
         src = get_field(w0, hs["msrc"]).astype(jnp.int32)
         dst = get_field(w0, hs["mdst"]).astype(jnp.int32)
-        braw = get_field(w0, hs["b"]).astype(jnp.int32)   # stored +1
+        braw = get_field(w0, hs["b"]).astype(jnp.int32)
         clear = U32(0xFFFFFFFF) ^ U32(
             put_field(0xFFFFFFFF, hs["msrc"]) |
             put_field(0xFFFFFFFF, hs["mdst"]) |
@@ -225,7 +225,7 @@ class RaftFingerprinter:
         ebits, epw = lay.entry_bits, lay.entries_per_word
         emask = (1 << ebits) - 1
         ent = jnp.stack([
-            ((bag[:, 1 + k // epw] >> (ebits * (k % epw))) & emask)
+            ((bag[:, 1 + k // epw] >> lay.entry_shift(k)) & emask)
             .astype(jnp.int32)
             for k in range(lay.Lmax)], axis=1) if lay.msg_words > 1 \
             else jnp.zeros((K, 0) + w0.shape[1:], jnp.int32)  # [K,Lmax,...]
@@ -287,7 +287,7 @@ class RaftFingerprinter:
         srcp = sub(jnp.clip(prep["src"], 0, S - 1))
         dstp = sub(jnp.clip(prep["dst"], 0, S - 1))
         bp = jnp.where(prep["is_coc"],
-                       sub(jnp.clip(prep["braw"] - 1, 0, S - 1)) + 1,
+                       sub(jnp.clip(prep["braw"], 0, S - 1)),
                        prep["braw"])
         w0p = (prep["w0_base"] |
                put_field(srcp.astype(U32), hs["msrc"]) |
@@ -304,7 +304,7 @@ class RaftFingerprinter:
             acc = jnp.zeros_like(prep["w0"])
             for k in range((w - 1) * epw, min(w * epw, lay.Lmax)):
                 acc = acc | (entp[:, k].astype(U32)
-                             << (ebits * (k % epw)))
+                             << lay.entry_shift(k))
             words.append(jnp.where(prep["empty"], prep["bag"][:, w],
                                    acc))
 
@@ -534,9 +534,11 @@ class RaftFingerprinter:
     # (BASELINE.md config #3) — by ~6-10x.
     #
     # Per-family touch supersets are derived from ops/kernels.py (each
-    # kernel's masked writes); the bag side is a generic <=2-changed-
-    # slot diff (every action sends and/or consumes at most one
-    # message each — SURVEY §2.4/§2.5).
+    # kernel's masked writes); the bag side is a generic diff of at
+    # most two entering and two leaving (message, count) slots (every
+    # action sends and/or consumes at most one message each — SURVEY
+    # §2.4/§2.5 — and the bag's message order moves a kept slot by at
+    # most one).
     # ==================================================================
 
     # families whose kernels touch the message bag (ops/kernels.py)
@@ -594,7 +596,7 @@ class RaftFingerprinter:
         ebits, epw = lay.entry_bits, lay.entries_per_word
         emask = (1 << ebits) - 1
         ent = jnp.stack([
-            ((bag[:, 1 + k // epw] >> (ebits * (k % epw))) & emask)
+            ((bag[:, 1 + k // epw] >> lay.entry_shift(k)) & emask)
             .astype(jnp.int32)
             for k in range(lay.Lmax)], axis=1) if lay.msg_words > 1 \
             else jnp.zeros((K, 0) + w0.shape[1:], jnp.int32)
@@ -628,7 +630,7 @@ class RaftFingerprinter:
             srcp = sigma[jnp.clip(src, 0, S - 1)]
             dstp = sigma[jnp.clip(dst, 0, S - 1)]
             bp = jnp.where(is_coc,
-                           sigma[jnp.clip(braw - 1, 0, S - 1)] + 1, braw)
+                           sigma[jnp.clip(braw, 0, S - 1)], braw)
             w0p = (w0_base |
                    put_field(srcp.astype(U32), hs["msrc"]) |
                    put_field(dstp.astype(U32), hs["mdst"]) |
@@ -642,7 +644,7 @@ class RaftFingerprinter:
                 acc = jnp.zeros_like(w0)
                 for k in range((w - 1) * epw, min(w * epw, lay.Lmax)):
                     acc = acc | (entp[:, k].astype(U32)
-                                 << (ebits * (k % epw)))
+                                 << lay.entry_shift(k))
                 words.append(jnp.where(empty, bag[:, w], acc))
             posterm, bagterm, hsum = [], [], []
             for t in range(self.n_streams):
@@ -687,7 +689,7 @@ class RaftFingerprinter:
         srcp = sig[:, jnp.clip(src, 0, S - 1)]            # [P, cap]
         dstp = sig[:, jnp.clip(dst, 0, S - 1)]
         bp = jnp.where(is_coc[None],
-                       sig[:, jnp.clip(braw - 1, 0, S - 1)] + 1,
+                       sig[:, jnp.clip(braw, 0, S - 1)],
                        braw[None])
         w0p = (w0_base[None] |
                put_field(srcp.astype(U32), hs["msrc"]) |
@@ -696,7 +698,7 @@ class RaftFingerprinter:
         w0p = jnp.where(empty[None], w0[None], w0p)       # [P, cap]
         wordsp = [w0p]
         if lay.msg_words > 1:
-            ent = [((words[1 + k // epw] >> (ebits * (k % epw))) & emask)
+            ent = [((words[1 + k // epw] >> lay.entry_shift(k)) & emask)
                    .astype(jnp.int32) for k in range(lay.Lmax)]
             for w in range(1, lay.msg_words):
                 acc = jnp.zeros_like(w0p)
@@ -708,7 +710,7 @@ class RaftFingerprinter:
                                    (e & ~jnp.int32(vmask))[None] |
                                    self._perm_mask_P(e & vmask, sig),
                                    e[None])
-                    acc = acc | (ep.astype(U32) << (ebits * (k % epw)))
+                    acc = acc | (ep.astype(U32) << lay.entry_shift(k))
                 wordsp.append(jnp.where(empty[None], words[w][None],
                                         acc))
         out = []
@@ -837,21 +839,41 @@ class RaftFingerprinter:
             delta = delta + (fmix32(newv[:, None] ^ saltv) - old)
 
         if name in self._BAG_FAMILIES:
-            bagp = parT["bag"]                            # [K, MW, cap]
-            bagc = candT["bag"]
-            diff = jnp.any(bagp != bagc, axis=1) | \
-                (parT["cnt"] != candT["cnt"])             # [K, cap]
-            k0 = jnp.argmax(diff, axis=0)
-            d0 = diff[k0, r]
-            diff2 = diff & (jnp.arange(K)[:, None] != k0[None])
-            k1 = jnp.argmax(diff2, axis=0)
-            d1 = diff2[k1, r]
+            # one send and one consume at most, each shifting the slots
+            # above it by one (ops/kernels.py bag_put / bag_del_slot):
+            # a (message, count) pair both bags hold sits at most one
+            # slot apart.  The child's unmatched slots enter, the
+            # parent's leave — at most two of each.
+            bagp, cntp = parT["bag"], parT["cnt"]         # [K, MW, cap]
+            bagc, cntc = candT["bag"], candT["cnt"]
+
+            def eq(d):              # child slot k holds parent slot k+d
+                bp = jnp.roll(bagp, -d, axis=0)
+                cp = jnp.roll(cntp, -d, axis=0)
+                return jnp.all(bagc == bp, axis=1) & (cntc == cp)
+
+            e0, e_up, e_dn = eq(0), eq(-1), eq(1)         # [K, cap]
+            k_ar = jnp.arange(K)[:, None]
+            e_up = e_up & (k_ar > 0)
+            e_dn = e_dn & (k_ar < K - 1)
+            enter = (cntc > 0) & ~(e0 | e_up | e_dn)
+            # parent slot k matched by child k, k+1 (e_up) or k-1 (e_dn)
+            leave = (cntp > 0) & ~(e0 | jnp.roll(e_up, -1, axis=0) |
+                                   jnp.roll(e_dn, 1, axis=0))
+
+            def two(m):
+                k0 = jnp.argmax(m, axis=0)
+                m2 = m & (k_ar != k0[None])
+                k1 = jnp.argmax(m2, axis=0)
+                return ((k0, m[k0, r]), (k1, m2[k1, r]))
+
             bag_t = jnp.moveaxis(bagc, 1, 0)              # [MW, K, cap]
-            for km, dm in ((k0, d0), (k1, d1)):
+            for km, dm in two(enter):
+                new = self._slot_terms(bag_t[:, km, r], cntc[km, r], sig)
+                delta = delta + jnp.where(dm[None, None], new, 0)
+            for km, dm in two(leave):
                 old = tables["bagterm"][:, :, km, b_idx]
-                new = self._slot_terms(bag_t[:, km, r],
-                                       candT["cnt"][km, r], sig)
-                delta = delta + jnp.where(dm[None, None], new - old, 0)
+                delta = delta - jnp.where(dm[None, None], old, 0)
 
         return tables["h"][:, :, b_idx] + delta
 
@@ -985,7 +1007,7 @@ def raft_server_signature(fpr, svT: Dict, prep: Dict) -> jnp.ndarray:
         msk = fld[None] == ark                           # [S, K, B]
         c = fmix32(c + jnp.sum(U(msk) * w[None], axis=1))
     wb = term * U(prep["is_coc"]) * fmix32(slot ^ U32(0x3C6EF372))
-    mskb = (prep["braw"] - 1)[None] == ark
+    mskb = prep["braw"][None] == ark
     c = fmix32(c + jnp.sum(U(mskb) * wb[None], axis=1))
     # per-block salt: σ fixes the InitServer blocks, so equal-looking
     # servers in different blocks must never tie

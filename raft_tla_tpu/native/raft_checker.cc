@@ -13,7 +13,8 @@
 //     min-over-permutations of a 64-bit field-stream hash; history
 //     counters ride along but are excluded from identity.
 //   * the message bag hashes commutatively (sum over slots of
-//     count * mix(msg)), so bag representation order never matters.
+//     count * mix(msg)), so slot order never affects identity; it is
+//     the order the bag is walked in (bag ops below).
 //   * CONSTRAINT = don't-expand (state still checked); first-seen
 //     survivor per level in frontier order.
 //   * UpdateTerm / ReturnToFollowerState / Conflict / NoConflict do not
@@ -155,22 +156,55 @@ inline bool in_quorum(uint32_t votes, uint32_t config) {
 }
 
 // ---------------------------------------------------------------------
-// Bag ops (TypedBags (+)/(-), raft.tla:226-231)
+// Bag ops (TypedBags (+)/(-), raft.tla:226-231).  The occupied slots
+// are a prefix of the bag in one total order of messages, the plain
+// reference's (type, term, src, dst, a, b, c, entry count, then each
+// entry as term, payload, type; an absent field last): the receive,
+// duplicate and drop loops walk the slots in that order, so BFS keeps
+// the reference's member of a VIEW class (ops/layout.py).
 // ---------------------------------------------------------------------
 
-inline void bag_put(const Cfg &c, State &s, const Msg &m) {
-  int empty = -1;
-  for (int k = 0; k < c.K; ++k) {
-    if (s.cnt[k] && s.bag[k] == m) { s.cnt[k]++; return; }
-    if (!s.cnt[k] && empty < 0) empty = k;
+inline int msg_cmp(const Cfg &c, const Msg &x, const Msg &y) {
+  const int hx[8] = {x.type, (uint16_t)x.term, x.src, x.dst,
+                     (uint16_t)x.a, (uint16_t)x.b, (uint16_t)x.c, x.entlen};
+  const int hy[8] = {y.type, (uint16_t)y.term, y.src, y.dst,
+                     (uint16_t)y.a, (uint16_t)y.b, (uint16_t)y.c, y.entlen};
+  for (int f = 0; f < 8; ++f)
+    if (hx[f] != hy[f]) return hx[f] < hy[f] ? -1 : 1;
+  for (int k = 0; k < x.entlen; ++k) {
+    // term, payload, type: the reference's entry bytes
+    auto key = [&](uint16_t e) {
+      return (entry_term(c, e) << (1 + c.value_bits)) |
+             (entry_payload(c, e) << 1) | entry_type(c, e);
+    };
+    int kx = key(x.ent[k]), ky = key(y.ent[k]);
+    if (kx != ky) return kx < ky ? -1 : 1;
   }
-  if (empty < 0) { s.overflow = 1; return; }
-  s.bag[empty] = m;
-  s.cnt[empty] = 1;
+  return 0;
+}
+
+inline void bag_put(const Cfg &c, State &s, const Msg &m) {
+  int k = 0;
+  int cmp = 1;
+  while (k < c.K && s.cnt[k] && (cmp = msg_cmp(c, s.bag[k], m)) < 0) ++k;
+  if (k < c.K && s.cnt[k] && cmp == 0) { s.cnt[k]++; return; }
+  if (s.cnt[c.K - 1]) { s.overflow = 1; return; }
+  for (int q = c.K - 1; q > k; --q) {
+    s.bag[q] = s.bag[q - 1];
+    s.cnt[q] = s.cnt[q - 1];
+  }
+  s.bag[k] = m;
+  s.cnt[k] = 1;
 }
 
 inline void bag_del(State &s, int k) {
-  if (--s.cnt[k] == 0) s.bag[k] = Msg{};
+  if (--s.cnt[k]) return;
+  for (; k + 1 < KMAX && s.cnt[k + 1]; ++k) {
+    s.bag[k] = s.bag[k + 1];
+    s.cnt[k] = s.cnt[k + 1];
+  }
+  s.bag[k] = Msg{};
+  s.cnt[k] = 0;
 }
 
 // ---------------------------------------------------------------------

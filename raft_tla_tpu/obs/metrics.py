@@ -124,7 +124,9 @@ class MetricsRegistry:
 def check_stats(counters: Mapping, seconds: float, n_violations: int,
                 fp_bits: Optional[int] = None,
                 spec: Optional[str] = None,
-                ir_fp: Optional[str] = None) -> Dict[str, object]:
+                ir_fp: Optional[str] = None,
+                lanes_enabled: Optional[Mapping] = None
+                ) -> Dict[str, object]:
     """The ``check`` stats payload (stdout line and ``--stats-json``),
     assembled from a counter mapping (``CheckResult.metrics.as_dict()``
     for the engines; a hand-built dict for the oracle, which has no
@@ -135,6 +137,8 @@ def check_stats(counters: Mapping, seconds: float, n_violations: int,
     (tests/test_obs.py pins both): the fingerprint/burst telemetry
     keys appear only when ``fp_bits`` is given (the oracle has no
     notion of them), ``pin_interior_states`` only when nonzero.
+    ``lanes_enabled`` (the engine's enabled lanes per action family)
+    comes last, when a nonempty mapping is given.
     """
     distinct = int(counters["distinct_states"])
     gen = int(counters["generated_states"])
@@ -176,6 +180,8 @@ def check_stats(counters: Mapping, seconds: float, n_violations: int,
         out["spec"] = spec
         if ir_fp is not None:
             out["ir_fingerprint"] = ir_fp
+    if lanes_enabled:
+        out["lanes_enabled"] = {k: int(v) for k, v in lanes_enabled.items()}
     return out
 
 

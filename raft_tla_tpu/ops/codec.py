@@ -135,17 +135,24 @@ def widen(arrs):
 # ---------------------------------------------------------------------------
 
 def pack_msg(lay: Layout, m: tuple) -> np.ndarray:
-    """Oracle message tuple -> u32[msg_words].  Generic fields a/b/c are
-    stored +1 so an absent field (-1; the follow-up CatchupRequest's missing
-    mcommitIndex, raft.tla:762-771) packs as 0 and field-set identity is
-    preserved."""
+    """Oracle message tuple -> u32[msg_words].  An absent generic field
+    (-1; the follow-up CatchupRequest's missing mcommitIndex,
+    raft.tla:762-771) packs as the field's all-ones value, above every
+    value the field holds, so field-set identity is preserved and the
+    message sorts as the plain reference sorts it (ops/layout.py)."""
     hs = lay.header_shifts
     f = MSG_FIELDS[m[0]]
     ent = m[f["ent"]] if f["ent"] is not None else ()
 
     def gf(key):
         idx = f[key]
-        return (m[idx] if idx is not None else -1) + 1
+        v = m[idx] if idx is not None else -1
+        absent = (1 << hs[key][1]) - 1
+        if v >= absent:
+            raise OverflowError(
+                f"message {key}={v} exceeds {hs[key][1]}-bit packing; "
+                f"state is un-representable under the configured bounds")
+        return absent if v == -1 else v
 
     w0 = (put_field_checked(m[0], hs["mtype"], "mtype") |
           put_field_checked(m[1], hs["mterm"], "mterm") |
@@ -160,8 +167,7 @@ def pack_msg(lay: Layout, m: tuple) -> np.ndarray:
     epw = lay.entries_per_word
     for k, e in enumerate(ent):
         packed = pack_entry(lay, e[0], e[1], e[2])
-        words[1 + k // epw] |= np.uint32(packed << (lay.entry_bits *
-                                                    (k % epw)))
+        words[1 + k // epw] |= np.uint32(packed << lay.entry_shift(k))
     return words
 
 
@@ -173,15 +179,18 @@ def unpack_msg(lay: Layout, words) -> tuple:
     term = get_field(w0, hs["mterm"])
     src = get_field(w0, hs["msrc"])
     dst = get_field(w0, hs["mdst"])
-    a = get_field(w0, hs["a"]) - 1
-    b = get_field(w0, hs["b"]) - 1
-    c = get_field(w0, hs["c"]) - 1
+
+    def opt(key):
+        v = get_field(w0, hs[key])
+        return -1 if v == (1 << hs[key][1]) - 1 else v
+
+    a, b, c = opt("a"), opt("b"), opt("c")
     nent = get_field(w0, hs["entlen"])
     epw = lay.entries_per_word
     mask = (1 << lay.entry_bits) - 1
     ent = tuple(
-        unpack_entry(lay, (int(words[1 + k // epw]) >>
-                           (lay.entry_bits * (k % epw))) & mask)
+        unpack_entry(lay, (int(words[1 + k // epw]) >> lay.entry_shift(k))
+                     & mask)
         for k in range(nent))
     if mtype == MT_RVREQ:
         return (mtype, term, a, b, src, dst)
@@ -272,8 +281,12 @@ def encode(lay: Layout, sv: State, h: Hist) -> Dict[str, np.ndarray]:
     bag = np.zeros((K, MW), dtype=np.uint32)
     cnt = np.zeros(K, dtype=np.int32)
     assert len(sv.msgs) <= K, "bag overflow: un-representable state"
-    for slot, (m, c) in enumerate(sv.msgs):
-        bag[slot] = pack_msg(lay, m)
+    # the occupied slots are a prefix in message order (ops/layout.py)
+    packed = sorted(
+        ((pack_msg(lay, m), c) for m, c in sv.msgs),
+        key=lambda pc: tuple(int(x) for x in lay.msg_key(pc[0])))
+    for slot, (words, c) in enumerate(packed):
+        bag[slot] = words
         cnt[slot] = c
     out["bag"] = bag
     out["cnt"] = cnt

@@ -144,16 +144,16 @@ class RaftKernels:
 
     def pack_msg(self, mtype, mterm, msrc, mdst, a=-1, b=-1, c=-1,
                  ent=None, entlen=0):
-        """Build u32[msg_words].  a/b/c use the +1 absent-field offset
-        (codec.pack_msg is the host twin)."""
+        """Build u32[msg_words].  An absent a/b/c (-1) packs as the
+        field's all-ones value (codec.pack_msg is the host twin)."""
         lay = self.lay
         hs = lay.header_shifts
         w0 = (put_field(jnp.int32(mtype), hs["mtype"]) |
               put_field(mterm, hs["mterm"]) |
               put_field(msrc, hs["msrc"]) | put_field(mdst, hs["mdst"]) |
-              put_field(jnp.asarray(a, jnp.int32) + 1, hs["a"]) |
-              put_field(jnp.asarray(b, jnp.int32) + 1, hs["b"]) |
-              put_field(jnp.asarray(c, jnp.int32) + 1, hs["c"]) |
+              put_field(jnp.asarray(a, jnp.int32), hs["a"]) |
+              put_field(jnp.asarray(b, jnp.int32), hs["b"]) |
+              put_field(jnp.asarray(c, jnp.int32), hs["c"]) |
               put_field(jnp.asarray(entlen, jnp.int32), hs["entlen"]))
         words = [w0.astype(jnp.uint32)]
         epw = lay.entries_per_word
@@ -163,7 +163,7 @@ class RaftKernels:
                 e = ent[k] if ent is not None else jnp.int32(0)
                 live = jnp.asarray(k < entlen, jnp.uint32)
                 acc = acc | (live * e.astype(jnp.uint32)
-                             << (lay.entry_bits * (k % epw)))
+                             << lay.entry_shift(k))
             words.append(acc)
         return jnp.stack(words)
 
@@ -175,46 +175,65 @@ class RaftKernels:
         f = {name: get_field(w0, hs[name]).astype(jnp.int32)
              for name in ("mtype", "mterm", "msrc", "mdst", "entlen")}
         for name in ("a", "b", "c"):
-            f[name] = get_field(w0, hs[name]).astype(jnp.int32) - 1
-        epw = lay.entries_per_word
+            v = get_field(w0, hs[name]).astype(jnp.int32)
+            f[name] = jnp.where(v == (1 << hs[name][1]) - 1, -1, v)
         mask = (1 << lay.entry_bits) - 1
-        ent = [((words[1 + k // epw] >> (lay.entry_bits * (k % epw)))
+        ent = [((words[1 + k // lay.entries_per_word] >> lay.entry_shift(k))
                 & mask).astype(jnp.int32) for k in range(self.Lmax)]
         f["ent"] = jnp.stack(ent) if ent else jnp.zeros(0, jnp.int32)
         return f
 
     # ------------------------------------------------------------------
-    # Bag ops (TypedBags (+)/(-), raft.tla:226-231; commutative-hash
-    # identity means slot order is free — see ops/layout.py docstring)
+    # Bag ops (TypedBags (+)/(-), raft.tla:226-231).  The occupied slots
+    # are a prefix of the bag in message order (ops/layout.py docstring):
+    # the lanes that walk the bag walk it as the reference does, so BFS
+    # keeps the reference's member of a VIEW class.
     # ------------------------------------------------------------------
 
     def bag_put(self, sv: State, words) -> State:
-        """WithMessage: +1 count, merging into an existing slot for the
-        same message, else the first empty slot; overflow faults."""
+        """WithMessage: +1 count, merging into the slot of the same
+        message, else inserting at its rank and shifting the slots above
+        it up one; a full bag faults."""
         bag, cnt = sv["bag"], sv["cnt"]
-        same = jnp.all(bag == words[None, :], axis=1) & (cnt > 0)
+        lay = self.lay
+        occ = cnt > 0
+        kb, kw = lay.msg_key(bag), lay.msg_key(words)
+        # one pass over the slots: equal to the new message, and below it
+        same = occ
+        below = jnp.zeros_like(occ)
+        for w in range(lay.msg_words):
+            below = below | (same & (kb[w] < kw[w]))
+            same = same & (kb[w] == kw[w])
         exists = jnp.any(same)
-        empty = cnt == 0
-        first_empty = jnp.argmax(empty)            # 0 if none; guarded below
-        target = jnp.where(exists, jnp.argmax(same), first_empty)
-        overflow = (~exists) & (~jnp.any(empty))
+        rank = jnp.sum(below, dtype=jnp.int32)
+        overflow = (~exists) & occ[-1]
+        pos = jnp.arange(self.K, dtype=jnp.int32)
+        up_bag = jnp.concatenate([jnp.zeros_like(bag[:1]), bag[:-1]])
+        up_cnt = jnp.concatenate([jnp.zeros_like(cnt[:1]), cnt[:-1]])
+        ins_bag = jnp.where((pos < rank)[:, None], bag,
+                            jnp.where((pos == rank)[:, None],
+                                      words[None, :], up_bag))
+        ins_cnt = jnp.where(pos < rank, cnt,
+                            jnp.where(pos == rank, 1, up_cnt))
         sv2 = dict(sv)
-        sv2["bag"] = jnp.where(overflow, bag,
-                               bag.at[target].set(words))
+        sv2["bag"] = jnp.where(exists | overflow, bag, ins_bag)
         sv2["cnt"] = jnp.where(overflow, cnt,
-                               cnt.at[target].add(1))
+                               jnp.where(exists, cnt + same, ins_cnt))
         sv2["ctr"] = sv["ctr"].at[C_OVERFLOW].add(overflow.astype(jnp.int32))
         return sv2
 
     def bag_del_slot(self, sv: State, slot) -> State:
-        """WithoutMessage on a known slot: -1 count, zero the slot at 0
-        (TypedBags (-) removes zero-count elements, TypedBags.tla:59-69)."""
-        cnt2 = sv["cnt"].at[slot].add(-1)
-        gone = cnt2[slot] == 0
+        """WithoutMessage on a known slot: -1 count; at 0 the message
+        leaves the bag (TypedBags (-) removes zero-count elements,
+        TypedBags.tla:59-69) and the slots above it shift down one."""
+        bag, cnt = sv["bag"], sv["cnt"]
+        gone = cnt[slot] == 1
+        shift = gone & (jnp.arange(self.K, dtype=jnp.int32) >= slot)
+        dn_bag = jnp.concatenate([bag[1:], jnp.zeros_like(bag[:1])])
+        dn_cnt = jnp.concatenate([cnt[1:], jnp.zeros_like(cnt[:1])])
         sv2 = dict(sv)
-        sv2["cnt"] = cnt2
-        sv2["bag"] = jnp.where(gone,
-                               sv["bag"].at[slot].set(0), sv["bag"])
+        sv2["cnt"] = jnp.where(shift, dn_cnt, cnt.at[slot].add(-1))
+        sv2["bag"] = jnp.where(shift[:, None], dn_bag, bag)
         return sv2
 
     # ------------------------------------------------------------------
@@ -464,11 +483,7 @@ class RaftKernels:
 
     def drop_message(self, sv: State, k) -> Tuple[jnp.ndarray, State]:
         """Oracle drop_message(); raft.tla:900-904."""
-        ok = sv["cnt"][k] == 1
-        sv2 = dict(sv)
-        sv2["cnt"] = sv["cnt"].at[k].set(0)
-        sv2["bag"] = sv["bag"].at[k].set(0)
-        return ok, sv2
+        return sv["cnt"][k] == 1, self.bag_del_slot(sv, k)
 
     # ------------------------------------------------------------------
     # Receive lanes (oracle receive(); raft.tla:842-863).  Three lanes per

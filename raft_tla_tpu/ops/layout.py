@@ -12,16 +12,28 @@ with bit-packing used exactly where it is load-bearing:
   * **messages** pack to ``msg_words`` uint32 words per bag slot: a
     header word (type/term/src/dst/3 generic fields/entry-count) plus
     entry words.  Field-set identity (the follow-up CatchupRequest's
-    *absent* mcommitIndex, raft.tla:762-771) is preserved by storing
-    every generic field with a +1 offset so "absent" = -1 = stored 0.
+    *absent* mcommitIndex, raft.tla:762-771) is preserved by storing an
+    absent generic field (-1) as the field's all-ones value.
 
 State *identity* (VIEW semantics, raft.cfg:30) is established by a
 64/128-bit fingerprint, not by canonical bytes:  the message bag is
 hashed **commutatively** (sum over slots of ``count * mix(words)``), so
-slot order — and even a message split across two slots — never affects
-identity, and no canonical bag sort is required anywhere (the TypedBags
-(+)/(-) semantics of raft.tla:226-231 are then free).  Symmetry
-(raft.cfg:29) is the min of the fingerprint over server relabelings.
+slot order never affects identity (the TypedBags (+)/(-) semantics of
+raft.tla:226-231).  Symmetry (raft.cfg:29) is the min of the
+fingerprint over server relabelings.
+
+Slot order is NOT free, though: it is the order in which Receive,
+UpdateTerm, CocDiscard, Duplicate and Drop walk the bag, and so which
+member of a VIEW class BFS meets first when the members' histories
+(outside the VIEW) differ.  The occupied slots are therefore kept as a
+prefix of the bag in one total order of messages, the plain reference's
+(bench/reference/plain_bfs.cc: type, term, src, dst, a, b, c, entry
+count, then each entry as term, payload, type; an absent field last).
+The packing is laid out so that this order is the lexicographic order
+of ``msg_key(words)``: the header's fields sit most significant first,
+an absent field is all-ones, message entries sit earliest-first in each
+word, and ``msg_key`` only moves each entry's type bit below its
+payload.
 
 All widths derive from ModelConfig bounds; tests assert round-trip
 identity against the oracle representation.
@@ -130,8 +142,9 @@ class Layout:
 
     @cached_property
     def field_bits(self):
-        # generic message fields a/b/c, stored with +1 offset (absent=-1→0):
-        # values span log indices (≤ Lcap+1), terms, server ids, rounds
+        # generic message fields a/b/c (absent=-1 → all-ones, above every
+        # value): values span log indices (≤ Lcap+1), terms, server ids,
+        # rounds
         fmax = max(self.Lcap + 1, self.cfg.bounds.max_terms + 1, self.S,
                    self.cfg.num_rounds)
         return bits_for(fmax + 1)
@@ -141,24 +154,26 @@ class Layout:
         return bits_for(self.Lmax)
 
     # ---- message word packing ------------------------------------------
-    # word0 (header): mtype | mterm | msrc | mdst | a | b | c | entlen
-    # word1..      : packed entries, entries_per_word per word
+    # word0 (header), most significant field first so that unsigned
+    # word order is message order:
+    #   mtype | mterm | msrc | mdst | a | b | c | entlen
+    # word1..: packed entries, entries_per_word per word, earliest entry
+    # most significant
     @cached_property
     def header_shifts(self):
-        shifts = {}
-        cur = 0
-        for name, width in (("mtype", 3), ("mterm", self.term_bits),
-                            ("msrc", self.server_bits),
-                            ("mdst", self.server_bits),
-                            ("a", self.field_bits), ("b", self.field_bits),
-                            ("c", self.field_bits),
-                            ("entlen", self.entlen_bits)):
-            shifts[name] = (cur, width)
-            cur += width
+        fields = (("mtype", 3), ("mterm", self.term_bits),
+                  ("msrc", self.server_bits), ("mdst", self.server_bits),
+                  ("a", self.field_bits), ("b", self.field_bits),
+                  ("c", self.field_bits), ("entlen", self.entlen_bits))
+        cur = sum(width for _, width in fields)
         if cur > 32:
             raise ValueError(
                 f"message header needs {cur} bits > 32; bounds too large "
                 f"for the single-header-word packing (split packing TBD)")
+        shifts = {}
+        for name, width in fields:
+            cur -= width
+            shifts[name] = (cur, width)
         return shifts
 
     @cached_property
@@ -169,6 +184,40 @@ class Layout:
     def msg_words(self):
         return 1 + (self.Lmax + self.entries_per_word - 1) \
             // self.entries_per_word
+
+    def entry_shift(self, k: int) -> int:
+        """Bit offset of message entry ``k`` in its word (word
+        ``1 + k // entries_per_word``): earliest entry most significant."""
+        epw = self.entries_per_word
+        return self.entry_bits * (epw - 1 - k % epw)
+
+    @cached_property
+    def _key_masks(self):
+        """Per entry word: the term bits, the payload bits and the unit
+        bit of every entry slot (msg_key)."""
+        vb, eb = self.value_bits, self.entry_bits
+        term = pay = unit = 0
+        for k in range(self.entries_per_word):
+            s = eb * k
+            term |= ((1 << eb) - (1 << (vb + 1))) << s
+            pay |= ((1 << vb) - 1) << s
+            unit |= 1 << s
+        return term, pay, unit
+
+    def msg_key(self, words):
+        """Packed message words [..., msg_words] (u32, numpy or jnp) ->
+        sort-key words whose lexicographic unsigned order is the plain
+        reference's message order.  The header word is its own key; in
+        each entry word every entry ``term | type | payload`` becomes
+        ``term | payload | type`` (three masks and two shifts for the
+        whole word)."""
+        term, pay, unit = (np.uint32(m) for m in self._key_masks)
+        vb = self.value_bits
+        out = [words[..., 0]]
+        for w in range(1, self.msg_words):
+            x = words[..., w]
+            out.append((x & term) | ((x & pay) << 1) | ((x >> vb) & unit))
+        return out
 
     # ---- fingerprint salts ---------------------------------------------
     @cached_property

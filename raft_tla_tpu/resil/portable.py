@@ -174,13 +174,15 @@ def load_portable_image(path: str) -> PortableImage:
     files."""
     import json
 
-    from ..engine.bfs import CheckpointError, ckpt_result
+    from ..engine.bfs import (CheckpointError, ckpt_check_bag_order,
+                              ckpt_result)
     from .ckpt_chain import load_engine_npz
     try:
         z, used = open_validated(path, load_engine_npz)
     except IntegrityError as e:
         raise CheckpointError(str(e)) from e
     meta = json.loads(str(z["meta"]))
+    ckpt_check_bag_order(used, meta)
     spill = bool(meta.get("spill"))
     sharded = bool(meta.get("sharded"))
     try:

@@ -174,14 +174,6 @@ def build_families(lay) -> List["Family"]:
     def d_duplicate(off, lay, k):
         return [(off["cnt"] + k, off["_const"], 1)]
 
-    def d_drop(off, lay, k):
-        X = off["_src_x"]
-        tr = [(off["cnt"] + k, X + off["cnt"] + k, -1)]
-        for w in range(lay.msg_words):
-            bw = off["bag"] + k * lay.msg_words + w
-            tr.append((bw, X + bw, -1))
-        return tr
-
     fams.append(Family(
         "RequestVote", kern.request_vote, ij,
         lambda i, j: f"RequestVote({i},{j})",
@@ -240,11 +232,12 @@ def build_families(lay) -> List["Family"]:
             k_, lambda k: f"Duplicate[slot{k}]",
             guard=lambda off, lay, k: ([(off["cnt1"] + k, 1)], 1),
             delta=d_duplicate))
+        # no delta declaration: a drop closes its slot's gap, shifting
+        # the slots above it (ops/kernels.py bag_del_slot)
         fams.append(Family(
             "Drop", lambda sv, der, k: kern.drop_message(sv, k),
             k_, lambda k: f"Drop[slot{k}]",
-            guard=lambda off, lay, k: ([(off["cnt1"] + k, 1)], 1),
-            delta=d_drop))
+            guard=lambda off, lay, k: ([(off["cnt1"] + k, 1)], 1)))
     if cfg.next_family == NEXT_DYNAMIC:
         fams.append(Family(
             "AddNewServer", kern.add_new_server, ij,

@@ -104,3 +104,38 @@ def test_cli_checkpoint_resume(tmp_path):
     want = json.loads(full.stdout.splitlines()[0])
     assert got["distinct_states"] == want["distinct_states"]
     assert got["depth"] == want["depth"]
+
+
+def test_resume_keeps_lane_counts_and_refuses_arrival_order_bags(
+        tmp_path):
+    """A resumed check reports the enabled lanes an uninterrupted one
+    does; a checkpoint written before bags were kept in message order
+    (its meta lacks ``bag_order``) is refused, natively and portably."""
+    import numpy as np
+    from raft_tla_tpu.engine.bfs import CheckpointError
+    from raft_tla_tpu.resil.ckpt_chain import publish
+    from raft_tla_tpu.resil.portable import load_portable_image
+    full = Engine(MICRO, chunk=64, store_states=False).check(max_depth=8)
+    ckpt = str(tmp_path / "run.ckpt")
+    Engine(MICRO, chunk=64, store_states=False).check(
+        max_depth=5, checkpoint_path=ckpt)
+    resumed = Engine(MICRO, chunk=64, store_states=False).check(
+        max_depth=8, resume_from=ckpt)
+    assert resumed.level_sizes == full.level_sizes
+    assert resumed.lanes_enabled == full.lanes_enabled
+    assert sum(full.lanes_enabled.values()) == full.generated_states - 1
+
+    with np.load(ckpt) as z:
+        data = dict(z)
+    meta = json.loads(str(data["meta"]))
+    assert meta.pop("bag_order") == 1
+    data["meta"] = np.array(json.dumps(meta))
+    tmp = str(tmp_path / "old.tmp.npz")
+    np.savez(tmp, **data)
+    old = str(tmp_path / "old.ckpt")
+    publish(tmp, old)
+    with pytest.raises(CheckpointError, match="message order"):
+        Engine(MICRO, chunk=64, store_states=False).check(
+            max_depth=8, resume_from=old)
+    with pytest.raises(CheckpointError, match="message order"):
+        load_portable_image(old)

@@ -104,25 +104,26 @@ def _materialize_pair(cfg, svT):
 @pytest.mark.smoke
 def test_delta_matmul_equals_kernel_path_on_reachable_states():
     """The group scatter-as-matmul reproduces every enabled successor
-    bit-exactly on reachable NextDynamic states — all seven affine
+    bit-exactly on reachable NextDynamic states — all six affine
     raft families (Timeout's clamped term, BecomeLeader's feat maxes,
-    ClientRequest's log append, Duplicate/Drop, and round 17's
+    ClientRequest's log append, Duplicate, and round 17's
     UpdateTerm dst-one-hot sets + Restart minus its min-gap min)
     interleaved with the kernel-path families in oracle enumeration
-    order."""
+    order.  Drop has no declaration: it closes its slot's gap in the
+    bag's message order (ops/kernels.py bag_del_slot)."""
     svT = _reachable_svT(DYN, n=120)
     ex_on, c_on, c_off, f_on, f_off, n_e = _materialize_pair(DYN, svT)
     assert set(ex_on.delta_family_names) == {
         "BecomeLeader", "ClientRequest", "Timeout", "Duplicate",
-        "Drop", "UpdateTerm", "Restart"}
-    # declaration coverage: 7 of the NextDynamic registry's families
+        "UpdateTerm", "Restart"}
+    # declaration coverage: 6 of the NextDynamic registry's families
     # ride the delta path now — a silently dropped declaration (or a
     # regression back to the kernel path) fails here by count, not
     # just by name
     fam_names = [f.name for f in ex_on.families]
     declared = [f.name for f in ex_on.families
                 if f.delta is not None]
-    assert len(declared) == 7 and len(fam_names) > len(declared)
+    assert len(declared) == 6 and len(fam_names) > len(declared)
     np.testing.assert_array_equal(np.asarray(f_on), np.asarray(f_off))
     for k in c_on:
         np.testing.assert_array_equal(
@@ -480,9 +481,8 @@ def test_delta_chunk_skip_all_disabled_families():
     np.testing.assert_array_equal(np.asarray(f_skip),
                                   np.asarray(f_off))
     # the init chunk really drives the skip branch: Timeout fires,
-    # the message-dependent affine families (Duplicate/Drop) cannot
-    assert famx["Timeout"] > 0 and famx["Duplicate"] == 0 \
-        and famx["Drop"] == 0, famx
+    # the message-dependent affine family (Duplicate) cannot
+    assert famx["Timeout"] > 0 and famx["Duplicate"] == 0, famx
     assert n_e > 0
     for k in c_skip:
         np.testing.assert_array_equal(

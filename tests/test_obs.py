@@ -440,6 +440,57 @@ def test_each_check_leaves_one_dedup_counter_sample(dedup_runs):
                                          "sum": 2 * v, "min": v, "max": v}
 
 
+@pytest.fixture(scope="module")
+def replayed_run():
+    """A per-level check from capacities too small for TINY: levels
+    overflow, grow and replay."""
+    from raft_tla_tpu.engine.bfs import Engine
+    e = Engine(TINY, chunk=64, store_states=False, burst=False,
+               lcap=256, fcap=64, ocap=64)
+    r = e.check()
+    assert (e.LCAP, e.FCAP) != (256, 64)     # something grew and replayed
+    return r
+
+
+@pytest.mark.parametrize("path", ["burst", "per_level", "replayed"])
+def test_lane_counters_sum_to_the_generated_successors(
+        dedup_runs, replayed_run, path):
+    """Each family's enabled lanes over the committed levels: their sum
+    is every generated successor (the roots aside), whichever level
+    path ran the levels and however often a level replayed."""
+    r = {"burst": dedup_runs["first"], "per_level": dedup_runs["small"],
+         "replayed": replayed_run}[path]
+    assert (r.levels_fused > 0) is (path == "burst")
+    lanes = r.lanes_enabled
+    assert set(lanes) == {"RequestVote", "BecomeLeader", "ClientRequest",
+                          "AdvanceCommitIndex", "AppendEntries",
+                          "UpdateTerm", "CocDiscard", "Receive", "Timeout"}
+    assert sum(lanes.values()) == r.generated_states - 1
+    assert lanes == dedup_runs["first"].lanes_enabled
+    # and reach the --stats-json payload, last
+    stats = check_stats(r.metrics.as_dict(), 1.0, 0, fp_bits=64,
+                        lanes_enabled=lanes)
+    assert list(stats)[-1] == "lanes_enabled"
+    assert stats["lanes_enabled"] == lanes
+
+
+def test_each_check_leaves_one_lane_counter_sample(dedup_runs):
+    """A traced check records ``lanes_enabled.<Family>`` and
+    ``lanes_kernel_path`` once: two equal checks leave two equal
+    samples."""
+    r, eng = dedup_runs["first"], dedup_runs["eng"]
+    delta = set(eng.expander.delta_family_names)
+    want = {f"lanes_enabled.{nm}": v for nm, v in r.lanes_enabled.items()}
+    want["lanes_kernel_path"] = sum(
+        v for nm, v in r.lanes_enabled.items() if nm not in delta)
+    assert 0 < want["lanes_kernel_path"] < r.generated_states
+    for k, v in want.items():
+        assert dedup_runs["tot1"][k] == {"count": 1, "seconds": 0.0,
+                                         "sum": v, "min": v, "max": v}, k
+        assert dedup_runs["tot2"][k] == {"count": 2, "seconds": 0.0,
+                                         "sum": 2 * v, "min": v, "max": v}
+
+
 @pytest.mark.smoke
 def test_span_recorder_counter_samples(tmp_path):
     from raft_tla_tpu.obs.report import format_span_totals
