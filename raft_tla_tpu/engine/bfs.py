@@ -62,7 +62,7 @@ from ..utils import cat_arrays as _cat
 from ..utils import fmix32_int as _fmix32_int
 from ..utils import fp_key
 from ..utils import take_arrays as _take
-from . import driver
+from . import driver, pack
 from .expand import Expander
 from .fingerprint import Fingerprinter, fmix32
 
@@ -129,6 +129,13 @@ class CheckResult:
     spec's action families) — each family's enabled lanes over the
     check's committed levels, a replayed level counted once.  Without
     action constraints its sum is ``generated_states`` less the roots.
+
+    ``harvest_transfers`` (beside the registry: a count of this check
+    alone, never checkpointed) — the device-to-host reads of level rows
+    the check's harvests made: one packed read (engine/pack) per
+    harvested level or burst that needed rows (one per block of a level
+    wider than ``Engine._PACK_BYTES``), none where states are not
+    stored and no invariant broke.
     """
 
     # the ONE canonical key tuple lives in obs.metrics — aliasing it
@@ -157,6 +164,7 @@ class CheckResult:
         self.violations: List[Violation] = list(violations or [])
         self.level_sizes: List[int] = list(level_sizes or [])
         self.lanes_enabled: Dict[str, int] = {}
+        self.harvest_transfers = 0
         self.seconds = float(seconds)
         self.phase_seconds: Dict[str, float] = dict(phase_seconds or {})
 
@@ -206,16 +214,17 @@ def _add_lanes(res: CheckResult, names, counts) -> None:
 
 def _check_counters(res: CheckResult, delta_names) -> Dict[str, int]:
     """The counter sample a check records on its span recorder: the
-    dedup counts, ``lanes_enabled.<Family>``, and ``lanes_kernel_path``,
+    dedup counts, ``lanes_enabled.<Family>``, ``lanes_kernel_path``,
     the enabled lanes of the families whose successors the expander
     built with their kernels rather than the delta matmul
-    (``delta_names``)."""
+    (``delta_names``), and ``harvest_transfers``."""
     return {**_dedup_counts(res),
             **{f"lanes_enabled.{nm}": v
                for nm, v in res.lanes_enabled.items()},
             "lanes_kernel_path": sum(
                 v for nm, v in res.lanes_enabled.items()
-                if nm not in delta_names)}
+                if nm not in delta_names),
+            "harvest_transfers": res.harvest_transfers}
 
 
 def _ceil_log2(n: int) -> int:
@@ -643,6 +652,56 @@ class Engine:
         gather to a replicated (every-controller-addressable) array
         first.  The base engines' arrays are process-local already."""
         return np.asarray(x)
+
+    # the most bytes one harvest read packs.  A pack's output and its
+    # program's code (resident while its bucket is cached, and growing
+    # with its rows) are held on the chip where the per-leaf reads held
+    # one leaf; at config #4's level 10 one 6.4 MB read raised the
+    # check's device peak 1.8% (TPU v5e), so a wider level reads in
+    # blocks of this size.
+    _PACK_BYTES = 2 << 20
+
+    def _fetch_rows(self, res, leaves, n_rows: int,
+                    levels: Optional[int] = None) -> List[np.ndarray]:
+        """The leaves' first ``n_rows`` rows (last axis; with ``levels``
+        the first ``levels`` ring levels too) on the host, batch-last:
+        ONE packed transfer (engine/pack) whose rows round up to a
+        bucket (``pack.row_bucket``), or for a level of more than
+        ``_PACK_BYTES`` one per block of that size."""
+        cap = leaves[0].shape[-1]
+        block = self._pack_block(leaves)
+        if levels is not None or n_rows <= block:
+            return self._read_pack(
+                res, leaves, 0, pack.row_bucket(n_rows, self.chunk, cap),
+                levels)
+        parts = []
+        for s in range(0, n_rows, block):
+            at = min(s, cap - block)          # the block stays in bounds
+            got = self._read_pack(res, leaves, at, block)
+            parts.append([g[..., s - at:min(n_rows, s + block) - at]
+                          for g in got])
+        return [np.concatenate(p, axis=-1) for p in zip(*parts)]
+
+    def _pack_block(self, leaves) -> int:
+        """Rows of these batch-last leaves one read packs at most: whole
+        chunks within ``_PACK_BYTES``, at least one, at most them all."""
+        row_bytes = sum(x.dtype.itemsize * int(np.prod(x.shape[:-1]))
+                        for x in leaves)
+        return min(leaves[0].shape[-1],
+                   max(1, self._PACK_BYTES // row_bytes // self.chunk)
+                   * self.chunk)
+
+    def _read_pack(self, res, leaves, start: int, rows: int,
+                   levels: Optional[int] = None) -> List[np.ndarray]:
+        """One packed transfer, read through ``_fetch`` and counted in
+        ``res.harvest_transfers``."""
+        buf = pack.pack(list(leaves), np.int32(start), rows=rows,
+                        levels=levels)
+        buf.copy_to_host_async()
+        host = self._fetch(buf)
+        del buf                   # the device copy goes with its bytes
+        res.harvest_transfers += 1
+        return pack.unpack(host, pack.layout(leaves, rows, levels), rows)
 
     # ------------------------------------------------------------------
     # phase 1: expand + action constraints + fingerprint (also used by
@@ -1999,22 +2058,29 @@ class Engine:
             res.overflow_faults += faults
             res.generated_states += n_genl
             res.violations_global += n_viol
-            if self.store_states:
+            if self.store_states or n_viol:
                 # after finalize the level's rows live in front (the
                 # buffers swap); they are only overwritten by the
-                # next-next level's chunk steps.  Archives are stored
-                # batch-major numpy (host layout) — decode/trace/_take
-                # row-index them.
+                # next-next level's chunk steps.  One packed read
+                # brings parents, lanes, state rows and (on a
+                # violation) the invariant bits.
+                front = carry["front"]
+                par, lane, *got = self._fetch_rows(
+                    res, [carry["lpar"], carry["llane"], *front.values(),
+                          *([out["inv_ok"]] if n_viol else [])], n_lvl)
+                inv_ok = got.pop()[:, :n_lvl] if n_viol else None
+                # batch-last state rows, cut to the level
+                rows = {k: v[..., :n_lvl] for k, v in zip(front, got)}
+            if self.store_states:
+                # archives are stored batch-major numpy (host layout) —
+                # decode/trace/_take row-index them; copies of the
+                # level's rows alone, so the archive keeps no padding
                 self._archive_level(
-                    self._fetch(carry["lpar"][:n_lvl]),
-                    self._fetch(carry["llane"][:n_lvl]),
-                    {k: np.moveaxis(self._fetch(v[..., :n_lvl]), -1, 0)
-                     for k, v in carry["front"].items()})
+                    par[:n_lvl].copy(), lane[:n_lvl].copy(),
+                    {k: np.moveaxis(v.copy(), -1, 0)
+                     for k, v in rows.items()})
             if n_viol:
-                inv_ok = self._fetch(out["inv_ok"])[:, :n_lvl]
-                rows = {k: np.moveaxis(self._fetch(v[..., :n_lvl]),
-                                       -1, 0)
-                        for k, v in carry["front"].items()}
+                rows = {k: np.moveaxis(v, -1, 0) for k, v in rows.items()}
                 for j, nm in enumerate(self.inv_names):
                     for s in np.nonzero(~inv_ok[j])[0]:
                         vsv, vh = self.ir.decode(self.lay,
@@ -2127,19 +2193,31 @@ class Engine:
                 bailed = bool(stats[-1, 1])
                 res.burst_dispatches += 1
                 res.burst_bailouts += int(bailed)
+                viol_any = bool(stats[-1, 3])
+                # the ring archives the harvest reads; the rest of the
+                # burst's output is dropped now, not at the next burst
+                ring = ([bout["par"], bout["lane"], *bout["st"].values(),
+                         *([bout["inv"]] if viol_any else [])]
+                        if nlev and (self.store_states or viol_any)
+                        else None)
+                st_keys = list(bout["st"])
+                del bout
                 if nlev:
                     burst_ok = not bailed
                     d0 = depth
                     n_front = int(stats[-1, 2])
-                    viol_any = bool(stats[-1, 3])
                     with obs.span("harvest"):
                         par_h = lane_h = st_h = inv_h = None
-                        if self.store_states or viol_any:
-                            par_h = self._fetch(bout["par"])
-                            lane_h = self._fetch(bout["lane"])
-                            st_h = {k: self._fetch(v)
-                                    for k, v in bout["st"].items()}
-                            inv_h = self._fetch(bout["inv"])
+                        if ring is not None:
+                            # one packed read of the committed levels,
+                            # cut to the widest level's rows
+                            par_h, lane_h, *got = self._fetch_rows(
+                                res, ring, int(stats[:nlev, 0].max()),
+                                levels=nlev)
+                            ring = None
+                            if viol_any:
+                                inv_h = got.pop()
+                            st_h = dict(zip(st_keys, got))
 
                         def _arch(li, n_lvl):
                             if self.store_states:

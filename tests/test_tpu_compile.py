@@ -26,6 +26,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from conftest import jax_cache_off
@@ -174,3 +175,35 @@ def test_pjit_carry_placement_compiles_on_4_chip_mesh(topo):
     vis_sh = placed.output_shardings["vis"][0]
     assert isinstance(vis_sh, NamedSharding) and eng.D == 4
     assert vis_sh.spec == eng._table_sh.spec
+
+
+def test_harvest_pack_compiles_for_v5e_in_bounded_memory(engine, one_chip):
+    # a stored-states harvest of config #2's widest level reads blocks
+    # of at most _PACK_BYTES, and a full burst ring packs whole: each
+    # read's output, the chip's temporaries and the program's code stay
+    # a small transient beside the multi-GB carry, whatever the level
+    from raft_tla_tpu.engine import pack
+    carry = _on(one_chip, jax.eval_shape(
+        lambda: engine._fresh_carry(engine.LCAP, VCAP)))
+    level = [carry["lpar"], carry["llane"], *carry["front"].values(),
+             carry["linv"]]
+    block = engine._pack_block(level)
+    assert engine.chunk <= block < engine.LCAP - engine.OCAP
+    KB, L = engine._burst_width(), engine.burst_levels
+    ring = lambda lead, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        lead + (L, KB), dt, sharding=one_chip)
+    for leaves, rows, levels, most in (
+            (level, block, None, engine._PACK_BYTES),
+            ([ring((), jnp.int32), ring((), jnp.int32),
+              *(ring(v.shape[:-1], v.dtype)
+                for v in carry["front"].values()),
+              ring((len(engine.inv_names),), jnp.bool_)], KB, L, 64 << 20)):
+        compiled = pack.pack.lower(leaves, np.int32(0), rows=rows,
+                                   levels=levels).compile()
+        mem = compiled.memory_analysis()
+        assert mem.output_size_in_bytes == sum(
+            int(np.prod(sh)) * dt.itemsize
+            for _, dt, sh in pack.layout(leaves, rows, levels))
+        assert mem.output_size_in_bytes <= most
+        assert mem.temp_size_in_bytes <= mem.output_size_in_bytes
+        assert mem.generated_code_size_in_bytes < 4 << 20
