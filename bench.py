@@ -638,123 +638,6 @@ def _ceiling_ab(out_path):
     return out
 
 
-def _pjit_ab(out_path):
-    """Pod-scale round A/B pair (BENCH_r13, round 14) under one
-    correctness gate:
-
-    (a) **sweep overlap** — SpillEngine ``--host-table`` with the
-    double-buffered pre-sweep H2D staging ON (default) vs OFF: level
-    k's partition-image uploads are issued at level start
-    (``h2d_stage`` spans nested inside ``level_dispatch`` = the
-    visible overlap; ``sweep_overlap`` marks each serialized upload a
-    sweep skipped because its image already rode the link), so the
-    upload cost leaves the sweep's critical path.  Counts must be
-    bit-identical ON vs OFF and the ON run must record at least one
-    prestage hit, or the file is FAILED.
-
-    (b) **pjit vs mesh** — the whole-state NamedSharding engine
-    (parallel/pjit_mesh: dedup exchange as in-program GSPMD
-    collectives) vs the shard_map mesh engine (explicit all_to_all)
-    on the same micro space, span totals attached.  Counts must be
-    identical across both AND equal to (a)'s — one shared gate.
-
-    CPU fallback labeling as in BENCH_r05+: on this container the
-    device_put staging is a host memcpy and the collectives are
-    XLA:CPU's, so the seconds are honest-fallback; the span/counter
-    structure (overlap visible, hits > 0, identical counts) is the
-    platform-independent content."""
-    import jax
-
-    from raft_tla_tpu.config import Bounds, ModelConfig, NEXT_ASYNC
-    from raft_tla_tpu.engine.spill import SpillEngine
-    from raft_tla_tpu.obs import Obs, SpanRecorder
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    from raft_tla_tpu.parallel.pjit_mesh import PjitShardedEngine
-
-    micro = ModelConfig(
-        n_servers=2, init_servers=(0, 1), values=(1,),
-        next_family=NEXT_ASYNC, symmetry=True, max_inflight_override=4,
-        bounds=Bounds.make(max_log_length=1, max_timeouts=1,
-                           max_client_requests=1))
-    rows, keys = {}, {}
-
-    def timed(label, eng, extra=None):
-        eng.check(max_depth=2)                  # warm the jit caches
-        rec = SpanRecorder()
-        t0 = time.perf_counter()
-        r = eng.check(obs=Obs(spans=rec))
-        secs = time.perf_counter() - t0
-        keys[label] = (int(r.distinct_states), int(r.depth),
-                       tuple(int(x) for x in r.level_sizes),
-                       int(r.generated_states))
-        tot = rec.totals()
-        rows[label] = {
-            "distinct_states": int(r.distinct_states),
-            "seconds": round(secs, 2),
-            "states_per_sec": round(
-                r.distinct_states / max(secs, 1e-9), 1),
-            "phase_seconds": {nm: t["seconds"]
-                              for nm, t in tot.items()},
-            "phase_counts": {nm: t["count"] for nm, t in tot.items()},
-            **(extra(eng) if extra else {}),
-        }
-
-    # (a) sweep overlap ON/OFF
-    for label, stage in (("sweep_stage_off", False),
-                         ("sweep_stage_on", True)):
-        timed(label, SpillEngine(
-            micro, chunk=64, store_states=False, seg=1 << 10,
-            vcap=1 << 12, sync_every=2, host_table=True, partitions=4,
-            part_cap=1 << 10, sweep_stage=stage),
-            extra=lambda e: {
-                "sweep_stage_hits": int(e.sweep_stage_hits),
-                "sweep_stage_misses": int(e.sweep_stage_misses)})
-
-    # (b) pjit vs mesh
-    timed("mesh_shard_map", ShardedEngine(
-        micro, chunk=64, store_states=False, lcap=1 << 12,
-        vcap=1 << 15))
-    timed("pjit_named_shardings", PjitShardedEngine(
-        micro, chunk=64, store_states=False, lcap=1 << 12,
-        vcap=1 << 15))
-
-    identical = len(set(keys.values())) == 1
-    on = rows["sweep_stage_on"]
-    overlap_visible = (on["sweep_stage_hits"] > 0 and
-                       on["phase_counts"].get("h2d_stage", 0) > 0 and
-                       on["phase_counts"].get("sweep_overlap", 0) > 0)
-    ok = identical and overlap_visible
-    out = {
-        "bench": "pod-scale round: host-table sweep-overlap ON/OFF + "
-                 "pjit-vs-mesh engine spans (bench.py, BENCH_r13 "
-                 "round)",
-        "platform": jax.default_backend(),
-        "honest_label": (
-            "CPU-only fallback: this container has no TPU; the "
-            "overlap structure (h2d_stage inside level_dispatch, "
-            "prestage hits, identical counts) is platform-"
-            "independent, the seconds are XLA:CPU and device_put is "
-            "a host memcpy here — the DMA overlap this buys is a TPU "
-            "measurement (standing carry-over)"
-            if jax.default_backend() == "cpu" else "TPU-measured"),
-        "status": ("ok" if ok else
-                   "FAILED: sweep-stage/pjit counts diverge or the "
-                   "overlap left no h2d_stage/sweep_overlap spans — "
-                   "the perf rows are meaningless"),
-        "counts_identical": identical,
-        "overlap_visible": overlap_visible,
-        "pjit_vs_mesh_seconds": {
-            "mesh": rows["mesh_shard_map"]["seconds"],
-            "pjit": rows["pjit_named_shardings"]["seconds"]},
-        "rows": rows,
-    }
-    tmp = out_path + ".tmp"
-    with open(tmp, "w") as fh:
-        json.dump(out, fh, indent=1)
-    os.replace(tmp, out_path)
-    return out
-
-
 def _canon_ab(out_path):
     """Orbit-sort canonicalization A/B (BENCH round 14 file, repo
     round 15): the config-#5 SHAPE — S=5 all-init, full S_5 symmetry,
@@ -1352,9 +1235,6 @@ def main():
     ceiling_ab = _ceiling_ab(os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "BENCH_r12.json"))
     gate_ok = gate_ok and ceiling_ab["status"] == "ok"
-    pjit_ab = _pjit_ab(os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "BENCH_r13.json"))
-    gate_ok = gate_ok and pjit_ab["status"] == "ok"
     canon_ab = _canon_ab(os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "BENCH_r14.json"))
     gate_ok = gate_ok and canon_ab["status"] == "ok"
@@ -1401,7 +1281,6 @@ def main():
     out["detail"]["batch_ab_status"] = batch_ab["status"]
     out["detail"]["delta_ab_status"] = delta_ab["status"]
     out["detail"]["ceiling_ab_status"] = ceiling_ab["status"]
-    out["detail"]["pjit_ab_status"] = pjit_ab["status"]
     out["detail"]["canon_ab_status"] = canon_ab["status"]
     out["detail"]["wave_mesh_ab_status"] = wave_mesh_ab["status"]
     out["detail"]["wave_mesh2d_ab_status"] = wave_mesh2d_ab["status"]

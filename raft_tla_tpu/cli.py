@@ -1302,9 +1302,9 @@ def main(argv=None):
                          "mid-write never strands the run (default 2; "
                          "1 = the historical single file)")
     pc.add_argument("--resume-portable", action="store_true",
-                    help="shape-portable resume (needs --spill): "
-                         "re-partition ANY engine family's checkpoint "
-                         "— classic, spill, or a mesh of any device "
+                    help="shape-portable resume (needs --spill or "
+                         "--pjit): re-partition a checkpoint — "
+                         "classic, spill, or a pjit mesh of any device "
                          "count — onto this engine by re-inserting "
                          "the visited key set and re-routing the "
                          "frontier (resil/portable)")

@@ -100,10 +100,9 @@ class CheckResult:
 
     Counter notes (the registry keys, obs.metrics.CHECK_COUNTER_KEYS):
 
-    - ``violations_global`` — total across the whole mesh; under a
-      multi-controller run the ``violations`` list holds only this
-      controller's shards, but this count (from the replicated scalar
-      matrix) is global.
+    - ``violations_global`` — every violation the run found, counted
+      from the per-level scalars (replicated under the pjit engine, so
+      every controller reads the same count).
     - ``levels_fused`` / ``burst_dispatches`` / ``burst_bailouts`` —
       fused-dispatch telemetry (the multi-level burst fast path):
       levels committed inside bursts, burst device calls (each is
@@ -239,7 +238,7 @@ def _leaf_name(key_path) -> str:
 
 
 # ---------------------------------------------------------------------------
-# checkpoint serializer, shared by Engine and ShardedEngine (TLC
+# checkpoint serializer, shared by Engine and SpillEngine (TLC
 # checkpoints to states/ — /root/reference/.gitignore:4; SURVEY §5).
 # A checkpoint is the full BFS wavefront: {carry pytree leaves (by
 # _leaf_name), level counters, result-so-far, and (when store_states)
@@ -253,6 +252,18 @@ def _leaf_name(key_path) -> str:
 # (ops/layout.py); a checkpoint without it holds bags in arrival order,
 # which would walk differently from a fresh run and change the answer.
 BAG_ORDER = 1
+
+
+def ckpt_refuse_removed_format(path, meta) -> None:
+    """Refuse a checkpoint of the removed shard_map mesh engines
+    (single-host, spill-composed and multi-host): their per-device
+    carry layout is read by no engine now, and its meta must not pass
+    for the classic or spill format it resembles."""
+    if meta.get("sharded"):
+        raise CheckpointError(
+            f"{path}: checkpoint of the removed shard_map mesh engine "
+            "format (meta 'sharded'); no engine reads it — re-run "
+            "without --resume")
 
 
 def ckpt_check_bag_order(path, meta) -> None:
@@ -316,7 +327,7 @@ def ckpt_write(path, carry, store_states, parents, lanes, states, res,
     publish(tmp, path, keep=keep)
 
 
-def ckpt_read(path, cfg_repr, chunk, extra_keys, sharded, spill=False,
+def ckpt_read(path, cfg_repr, chunk, extra_keys, spill=False,
               expected_format=None, spec_name=None, sym_canon=None):
     """np.load + the meta validation every engine shares.  Returns
     (npz, meta) or raises CheckpointError.
@@ -371,20 +382,13 @@ def ckpt_read(path, cfg_repr, chunk, extra_keys, sharded, spill=False,
                 f"{sym_canon} — fingerprint values are mode-specific "
                 f"(the visited table would miss every known state) — "
                 f"resume with --sym-canon {got_mode}")
-    # spill before sharded: a spill checkpoint handed to ShardedEngine
-    # must name SpillEngine, not "the single-device Engine"
+    ckpt_refuse_removed_format(path, meta)
     if bool(meta.get("spill")) != spill:
         raise CheckpointError(
             f"{path}: host-spill checkpoint — resume it with "
             "SpillEngine" if meta.get("spill")
             else f"{path}: not a SpillEngine checkpoint — resume it "
             "with the engine that wrote it")
-    if bool(meta.get("sharded")) != sharded:
-        raise CheckpointError(
-            f"{path}: sharded-engine checkpoint — resume it with "
-            "ShardedEngine on the same mesh size" if meta.get("sharded")
-            else f"{path}: single-device checkpoint — resume it with "
-            "the single-device Engine")
     if expected_format is not None:
         fkey, want, why = expected_format
         got = meta.get(fkey, 1)
@@ -412,10 +416,9 @@ def ckpt_read(path, cfg_repr, chunk, extra_keys, sharded, spill=False,
     return z, meta
 
 
-def ckpt_carry(path, z, template, to_device):
-    """Rebuild the carry pytree from archived leaves; `to_device` is
-    jnp.asarray for single-controller engines, the global-array builder
-    for multi-controller ones."""
+def ckpt_carry(path, z, template):
+    """Rebuild the carry pytree (device arrays) from archived
+    leaves."""
     leaves, _ = jax.tree_util.tree_flatten_with_path(template)
     missing = [_leaf_name(kp) for kp, _ in leaves
                if _leaf_name(kp) not in z]
@@ -427,7 +430,7 @@ def ckpt_carry(path, z, template, to_device):
             "--resume")
     return jax.tree_util.tree_unflatten(
         jax.tree_util.tree_structure(template),
-        [to_device(z[_leaf_name(kp)]) for kp, _ in leaves])
+        [jnp.asarray(z[_leaf_name(kp)]) for kp, _ in leaves])
 
 
 def ckpt_archives(z, meta, template, store_states):
@@ -705,7 +708,7 @@ class Engine:
 
     # ------------------------------------------------------------------
     # phase 1: expand + action constraints + fingerprint (also used by
-    # the driver entry point and the sharded engine)
+    # the driver entry point)
     # ------------------------------------------------------------------
 
     def _act_ok(self, parent_sv, cand_sv):
@@ -1159,9 +1162,7 @@ class Engine:
     # the loop-carried [LCAP, S, S]-shaped buffers — (3,3) minor dims
     # tile to (4,128), a 57x blowup that OOMs HBM at LCAP=2^21 — and
     # measured host dispatch is only ~0.5 ms/chunk, so per-chunk
-    # dispatch costs nothing.  The sharded engine keeps its level
-    # driver: shard_map dispatch is genuinely expensive and its
-    # per-device LB is D-fold smaller.)
+    # dispatch costs nothing.)
     # ------------------------------------------------------------------
 
     def _finalize_impl(self, carry):
@@ -1842,8 +1843,8 @@ class Engine:
         jax.block_until_ready(self._fin_jit(dummy))
 
     def _dedup_roots(self, seed_states):
-        """Shared root-admission front half (this engine, ShardedEngine
-        and SpillEngine): cfg prefix pins compile to seeds
+        """Shared root-admission front half (this engine and
+        SpillEngine): cfg prefix pins compile to seeds
         (raft.tla:1198-1234; models/golden docstring), seeds encode to
         SoA rows, and first-seen fingerprint dedup picks the root set.
         Returns (roots int32 SoA [n, ...] batch-major, rk u32 [n, W]
@@ -1985,8 +1986,8 @@ class Engine:
         resume_image — a ``resil.portable.PortableImage`` from ANY
         engine family's checkpoint (round 12 contract): the visited
         key SET rebuilds this engine's table image and the gid-ordered
-        frontier rows re-home into the level-buffer layout, so a mesh
-        or spill checkpoint resumes here (and, via
+        frontier rows re-home into the level-buffer layout, so a spill
+        checkpoint resumes here (and, via
         parallel/pjit_mesh's inherited override, onto a pod-spanning
         pjit mesh) landing on the exact counts of an uninterrupted
         run.
@@ -2431,7 +2432,7 @@ class Engine:
         z, meta = ckpt_read(path, repr(self.cfg), self.chunk,
                             ("LCAP", "VCAP", "FCAP", "OCAP",
                              "fam_caps"),
-                            sharded=False, expected_format=(
+                            expected_format=(
                                 "layout", 2, "this engine's batch-last/"
                                 "narrow-dtype storage layout"),
                             spec_name=self.ir.name,
@@ -2445,7 +2446,7 @@ class Engine:
         template = jax.eval_shape(
             lambda: self._fresh_carry(self.LCAP, self.VCAP, self.FCAP,
                                       self.OCAP))
-        carry = ckpt_carry(path, z, template, jnp.asarray)
+        carry = ckpt_carry(path, z, template)
         self._load_archives(path, z, meta, template)
         res = ckpt_result(z, meta)
         z.close()             # all arrays extracted; don't leak the fd

@@ -3,14 +3,15 @@
 The per-level host bookkeeping every engine driver runs — decode the
 stats rows, accumulate counters into the ``CheckResult`` registry,
 depth-gate all-pruned pseudo-levels, guard the int32 global-id space,
-and decide checkpoint-crossing — lived in FIVE copies (the four
-exhaustive engine drivers plus the batched-serve harvest).  One
-telemetry drift (``levels_fused`` pseudo-level counting) needed three
-review passes to fix everywhere; the MetricsRegistry killed the
-counter-drift class but not the control-flow duplication.  This module
-is the single copy: engines supply what genuinely differs per family —
-how archive rows are stored, how violation rows decode out of their
-array layout, and how per-device visited occupancy is tracked — as
+and decide checkpoint-crossing — once lived in one copy per engine
+driver.  One telemetry drift (``levels_fused`` pseudo-level counting)
+needed three review passes to fix everywhere; the MetricsRegistry
+killed the counter-drift class but not the control-flow duplication.
+This module is the single copy, called by engine/bfs (and so by the
+pjit engine, which inherits its loop), engine/spill and the
+batched-serve harvest: engines supply what genuinely differs per
+family — how archive rows are stored, how violation rows decode out
+of their array layout, and how visited occupancy is tracked — as
 callbacks, and everything else runs HERE.
 
 The contract is bit-exactness: every existing engine differential
@@ -89,19 +90,18 @@ def harvest_fused_levels(
         violations: Optional[Callable[[int, int, int], None]] = None,
         visited: Optional[Callable[[int, int], None]] = None,
         id_guard: bool = True) -> Tuple[int, int]:
-    """THE fused-burst harvest loop (the five-copy dedup).
+    """THE fused-burst harvest loop.
 
     ``stats_of(li)`` returns the level's
-    ``(n_lvl, n_viol, faults, n_expand, n_gen)`` — mesh engines sum
-    their per-device stats matrix inside it.  Per committed level the
-    loop accumulates the result counters, calls ``archive(li, n_lvl)``
-    (the callback owns its own store_states / empty-level policy),
-    calls ``violations(li, n_lvl, gid_base)`` only when the level saw
-    violations (``gid_base`` is the level's first global id — the
-    PRE-increment n_states), applies the depth gate, advances
-    ``n_states``, and finally calls ``visited(li, n_lvl)`` for
-    per-engine occupancy/flush bookkeeping.  Returns the advanced
-    ``(depth, n_states)``.
+    ``(n_lvl, n_viol, faults, n_expand, n_gen)``.  Per committed level
+    the loop accumulates the result counters, calls
+    ``archive(li, n_lvl)`` (the callback owns its own store_states /
+    empty-level policy), calls ``violations(li, n_lvl, gid_base)``
+    only when the level saw violations (``gid_base`` is the level's
+    first global id — the PRE-increment n_states), applies the depth
+    gate, advances ``n_states``, and finally calls
+    ``visited(li, n_lvl)`` for per-engine occupancy/flush bookkeeping.
+    Returns the advanced ``(depth, n_states)``.
 
     ``id_guard=False`` preserves the batched-serve semantics exactly
     (per-job ids never approach 2^31; the solo engines guard after
@@ -135,9 +135,8 @@ def harvest_fused_levels(
 
 # ---------------------------------------------------------------------------
 # shared row helpers for the single-chip burst layout ([..., L_MAX, KB]
-# batch-last ring archives — engine/bfs._burst_core's out arrays).  The
-# mesh engines keep their own per-device decodes in their callbacks;
-# bfs, spill and the batched serve share these.
+# batch-last ring archives — engine/bfs._burst_core's out arrays),
+# shared by bfs, spill and the batched serve.
 # ---------------------------------------------------------------------------
 
 def burst_archive_slice(par_h, lane_h, st_h, li: int, n_lvl: int):

@@ -29,11 +29,7 @@ arxiv 2512.21967; Graph Traversal on Tensor Cores, arxiv 2606.05081):
   a fresh 4x growth; no host-side claims array).
 
 Everything here is numpy + one jit'd membership kernel; the
-device-streamed orchestration lives in engine/spill (single chip) and
-parallel/spill_mesh (per-device tables composed with hash-partitioned
-mesh dedup — ownership uses fingerprint stream W-1 mod D, the prefix
-uses stream 0's top bits, so the two partitionings are independent and
-compose).
+device-streamed orchestration lives in engine/spill.
 
 First-seen exactness: level keys arrive already deduplicated within
 the level (the device cache is complete over the running level) and in
@@ -221,7 +217,7 @@ class HostPartitionedTable:
         self.vers[p] += 1
         return True
 
-    # -- host-side sweep (mesh composition + differential tests) -------
+    # -- host-side sweep (portable-resume re-sweep + differential tests)
 
     def member(self, keys: np.ndarray) -> np.ndarray:
         """[N, W] keys -> bool[N] already-archived (any partition)."""
@@ -262,33 +258,32 @@ class HostPartitionedTable:
 
     # -- checkpoint serialization (sparse, exact-image restore) --------
 
-    def state_dict(self, prefix: str = "hpt") -> Dict[str, np.ndarray]:
+    def state_dict(self) -> Dict[str, np.ndarray]:
         """Occupied slots + keys per partition: a resume rebuilds the
         EXACT images (no rehash drift), so resumed runs stay
         bit-identical."""
-        out = {f"{prefix}|shape": np.array(
+        out = {"hpt|shape": np.array(
             [self.P, self.W] + [self.cap(p) for p in range(self.P)],
             np.int64)}
         for p in range(self.P):
             occ = ~(self.imgs[p] == U32).all(axis=0)
             idx = np.nonzero(occ)[0].astype(np.int64)
-            out[f"{prefix}|idx{p}"] = idx
-            out[f"{prefix}|keys{p}"] = np.ascontiguousarray(
+            out[f"hpt|idx{p}"] = idx
+            out[f"hpt|keys{p}"] = np.ascontiguousarray(
                 self.imgs[p][:, idx])
         return out
 
     @classmethod
-    def from_state(cls, get, prefix: str = "hpt"
-                   ) -> "HostPartitionedTable":
+    def from_state(cls, get) -> "HostPartitionedTable":
         """Rebuild from ``state_dict`` arrays; ``get(name)`` returns the
         stored array (an npz indexer)."""
-        shape = np.asarray(get(f"{prefix}|shape"))
+        shape = np.asarray(get("hpt|shape"))
         P, W = int(shape[0]), int(shape[1])
         tbl = cls(W, partitions=P, part_cap=int(shape[2]))
         for p in range(P):
             cap = int(shape[2 + p])
-            idx = np.asarray(get(f"{prefix}|idx{p}"))
-            keys = np.asarray(get(f"{prefix}|keys{p}"))
+            idx = np.asarray(get(f"hpt|idx{p}"))
+            keys = np.asarray(get(f"hpt|keys{p}"))
             img = np.full((W, cap), U32, np.uint32)
             img[:, idx] = keys
             tbl.imgs[p] = img

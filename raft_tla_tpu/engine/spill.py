@@ -587,8 +587,8 @@ class SpillEngine(Engine):
         double-buffering discipline) — then commit the fresh keys into
         the host partitions.  Returns keep = not-seen-before [N]."""
         # chaos site: host-partition loss (this device-streamed sweep
-        # is the single-chip twin of HostPartitionedTable.sweep, which
-        # carries the same site for the mesh composition)
+        # is the twin of HostPartitionedTable.sweep, which carries the
+        # same site for the portable-resume re-sweep)
         chaos_point("host_table")
         with self._obs.span("host_sweep"):
             return self._sweep_level_keys_impl(keys)
@@ -856,11 +856,11 @@ class SpillEngine(Engine):
               resume_image=None,
               verbose: bool = False, obs=None) -> CheckResult:
         """``resume_image`` — a ``resil.portable.PortableImage`` from
-        ANY engine family's checkpoint: the visited key set rebuilds
-        this engine's table image (and host partitions) and the
-        frontier rows become one spill block, so a mesh or classic
-        checkpoint resumes here after a shape change (ROADMAP item-2
-        elastic resume)."""
+        a classic, pjit or spill checkpoint: the visited key set
+        rebuilds this engine's table image (and host partitions) and
+        the frontier rows become one spill block, so a pjit-mesh or
+        classic checkpoint resumes here after a shape change (ROADMAP
+        item-2 elastic resume)."""
         obs = self._obs = obs if obs is not None else NULL_OBS
         t0 = time.perf_counter()
         lay = self.lay
@@ -1287,9 +1287,9 @@ class SpillEngine(Engine):
     # frontier blocks + counters + archives are already host numpy.
     # Reuses the engine-family ckpt_* serializer (engine/bfs), with the
     # frontier blocks riding inside the carry pytree; ckpt_read's
-    # spill=True flag keeps classic/sharded engines from resuming these
-    # files and vice versa.  TLC checkpoints its disk queue + fingerprint
-    # set the same way (/root/reference/.gitignore:4).
+    # spill=True flag keeps the classic and pjit engines from resuming
+    # these files and vice versa.  TLC checkpoints its disk queue +
+    # fingerprint set the same way (/root/reference/.gitignore:4).
     #
     # Each checkpoint is a full (not incremental) snapshot; under
     # store_states=True the cumulative archives rewrite every time, so
@@ -1437,7 +1437,7 @@ class SpillEngine(Engine):
     def _load_spill_checkpoint(self, path):
         z, meta = ckpt_read(path, repr(self.cfg), self.chunk,
                             self._SPILL_EXTRA_KEYS,
-                            sharded=False, spill=True, expected_format=(
+                            spill=True, expected_format=(
                                 "layout", 2, "this engine's batch-last/"
                                 "narrow-dtype storage layout"),
                             spec_name=self.ir.name,

@@ -29,13 +29,13 @@ from typing import Dict, Mapping, Optional
 # iterations (vector-wide gather rounds), probe advances summed over
 # lanes, outer claim/insert/reset scatter rounds, and claims lost to a
 # lower rank (retries).  Counted by the single-device engine's chunk
-# step and burst (and the pjit engine, which inherits them); the other
-# engines report 0.
+# step and burst (and the pjit engine, which inherits them); the spill
+# engine reports 0.
 DEDUP_COUNTER_KEYS = ("dedup_walk_iters", "dedup_probe_steps",
                       "dedup_rounds", "dedup_claim_losses")
 
 # the canonical counter set every exhaustive-check engine accumulates
-# (bfs / spill / mesh / spill_mesh all share CheckResult, so the set is
+# (bfs / spill / pjit_mesh all share CheckResult, so the set is
 # structurally identical across them — tests/test_obs.py pins it)
 CHECK_COUNTER_KEYS = (
     "distinct_states", "generated_states", "depth", "overflow_faults",

@@ -5,7 +5,7 @@ Every engine driver brackets its phases with
 ``check_setup`` (everything before its driver loop, with ``compile``,
 the per-level prewarm, inside it), ``burst_dispatch``,
 ``level_dispatch``, ``harvest``, ``archive_io`` and ``checkpoint``;
-the spill and mesh engines add ``host_sweep``, ``h2d_stage`` and
+the spill engine adds ``host_sweep``, ``h2d_stage`` and
 ``sweep_overlap``, the serving layer its ``job_*``/``bucket_*`` and
 ``batched_dispatch`` spans, the simulator ``sim_dispatch``.  Spans
 are host phases only: work inside one device program (the chunk

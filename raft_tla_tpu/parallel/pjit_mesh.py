@@ -1,15 +1,12 @@
 """Pod-scale pjit frontier: the WHOLE BFS state under named shardings
 (ROADMAP item 2 — the last big perf ceiling).
 
-Every other engine tops out at one host's devices: the classic engine
-is single-chip, shard_map/pmap meshes span one controller's devices
-(MultiHostEngine spans hosts but hand-routes its exchange through
-``all_to_all`` inside shard_map).  This engine instead puts the full
-logical BFS state — frontier rows, visited-table partitions, gid
-cursors, level buffers, per-level archive staging — under
-``NamedSharding``s on a ``jax.make_mesh`` spanning ALL hosts' devices,
-and lets the compiler partition the UNCHANGED single-logical-program
-engine:
+The classic and spill engines are single-chip; this is the one engine
+that spans devices and hosts.  It puts the full logical BFS state —
+frontier rows, visited-table partitions, gid cursors, level buffers,
+per-level archive staging — under ``NamedSharding``s on a
+``jax.make_mesh`` spanning ALL hosts' devices, and lets the compiler
+partition the UNCHANGED single-logical-program engine:
 
 - the carry pytree's shardings come from **rule-matched PartitionSpec
   trees** (``match_partition_rules`` — SNIPPETS.md's pjit
@@ -24,10 +21,9 @@ engine:
 - the **hash-ownership exchange is a sharding-constraint-mediated
   collective inside ONE jit program**: a candidate's claim-scatter
   into the slot-sharded table (engine/bfs._probe_insert) IS the
-  routing step the shard_map engines spell as an explicit
-  ``all_to_all`` — ``with_sharding_constraint`` pins the table's named
+  routing step — ``with_sharding_constraint`` pins the table's named
   sharding and GSPMD emits the cross-device (ICI within a host, DCN
-  across hosts) collectives;
+  across hosts) collectives, no hand-written ``all_to_all``;
 - every host-read output (the packed scal vector, burst stats and
   ring archives) is declared REPLICATED in ``out_shardings``, so the
   per-level sync is one small all-gather and ``np.asarray`` works on
@@ -41,20 +37,21 @@ the single-device engine and therefore to the oracle
 controller processes × 2 virtual CPU devices with gloo collectives —
 the DCN stand-in).
 
-Resume rides the round-12 portable-image contract both ways: any
-engine family's checkpoint loads through ``resume_image=`` (the key
+Resume rides the round-12 portable-image contract both ways: a
+classic or spill checkpoint loads through ``resume_image=`` (the key
 SET re-inserts into the slot-sharded table — membership is a set
 property — and the gid-ordered frontier rows re-partition onto the
 batch axis), and this engine's checkpoints are written in the CLASSIC
 engine format (gathered to host, proc-0 publish), so they resume on
-the classic/spill/mesh engines through the same portable loader.
+the classic engine directly and on the spill engine through the same
+portable loader.
 
 The ceiling this moves (BASELINE.md round 14): the visited table and
 frontier scale with AGGREGATE pod HBM (+ host RAM via the spill
 engines for the archive side), not one chip — the "run configs #1-#2
 to exhaustion" substrate.
 
-Multi-controller bring-up mirrors parallel/multihost: call
+Multi-controller bring-up (parallel/multihost): call
 ``init_distributed`` (or ``jax.distributed.initialize``) on every
 host BEFORE constructing the engine, then build with
 ``devices=jax.devices()`` (the default) so the mesh spans the pod.
@@ -65,12 +62,40 @@ from __future__ import annotations
 import re
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..config import ModelConfig
-from ..engine.bfs import CheckpointError, Engine, U32MAX
+from ..engine.bfs import Engine
+
+
+# warn-once latch for uneven user chunk overrides (per process, like
+# any stacklevel warning filter — the mesh size doesn't change mid-run)
+_warned_uneven_chunk = False
+
+
+def _round_chunk_to_devices(chunk: int, n_devices: int) -> int:
+    """Round ``chunk`` up to the next multiple of the mesh size.
+
+    The frontier's batch axis shards chunk/D rows per device, so the
+    per-device row count must divide evenly.  Defaults (512, 2048)
+    already divide every power-of-two pod slice; a user override that
+    doesn't is rounded up (never down — capacities are sized FROM the
+    chunk) with a one-time warning naming both numbers."""
+    d = max(1, int(n_devices))
+    rem = int(chunk) % d
+    if rem == 0:
+        return int(chunk)
+    rounded = int(chunk) + (d - rem)
+    global _warned_uneven_chunk
+    if not _warned_uneven_chunk:
+        _warned_uneven_chunk = True
+        import warnings
+        warnings.warn(
+            f"chunk {chunk} is not a multiple of the {d}-device mesh; "
+            f"rounded up to {rounded} ({rounded // d} frontier rows "
+            "per device)", stacklevel=3)
+    return rounded
 
 
 # ---------------------------------------------------------------------------
@@ -169,7 +194,7 @@ class PjitShardedEngine(Engine):
     devices — the mesh's devices; defaults to ``jax.devices()``, which
     under a multi-controller run (``multihost.init_distributed``)
     spans every process's devices.  chunk is rounded up to a multiple
-    of the device count (mesh._round_chunk_to_devices — an uneven
+    of the device count (_round_chunk_to_devices — an uneven
     override warns once; uneven shardings would compile but waste
     tiles on every step).
 
@@ -187,7 +212,6 @@ class PjitShardedEngine(Engine):
                                   axis_types=(jax.sharding.AxisType.Auto,),
                                   devices=devices)
         self.D = len(devices)
-        from .mesh import _round_chunk_to_devices
         kw = dict(kw, chunk=_round_chunk_to_devices(
             kw.get("chunk", 512), self.D))
         super().__init__(cfg, **kw)
@@ -258,8 +282,8 @@ class PjitShardedEngine(Engine):
     # Checkpoints are written in the CLASSIC engine format: the carry
     # gathers to host (one replicated copy per controller) and process
     # 0 publishes.  That makes the file portable BOTH ways — the
-    # classic/spill/mesh engines resume it through the round-12
-    # portable loader, and this engine resumes any of theirs via
+    # classic engine resumes it directly and the spill engine through
+    # the round-12 portable loader, and this engine resumes theirs via
     # resume_image (engine/bfs Engine._resume_portable) with the carry
     # re-partitioned onto the mesh by _commit_carry below.
     # ------------------------------------------------------------------

@@ -9,10 +9,11 @@ and the supervised retry/backoff runner.
 - ``ckpt_chain`` — sha256-sidecar integrity for every checkpoint plus
   last-K rotation with atomic publish; a torn/corrupt head reads as
   "fall back to the previous valid checkpoint" with a named warning.
-- ``portable`` — engine-agnostic resume images extracted from any
-  engine family's checkpoint: the visited key set + the frontier rows
-  in gid order, re-partitioned on load so a mesh checkpoint resumes on
-  a different device count or on the spill engine.
+- ``portable`` — engine-agnostic resume images extracted from a
+  classic, pjit or spill checkpoint: the visited key set + the
+  frontier rows in gid order, re-partitioned on load so a pjit-mesh
+  checkpoint resumes on a different device count or on the spill
+  engine.
 - ``supervisor`` — catch → backend-reinit → resume-from-latest-valid
   with bounded exponential backoff + jitter; every attempt stamped
   into the run ledger and heartbeat.

@@ -1,17 +1,17 @@
 """Shape-portable resume images: engine-agnostic extraction of a
 checkpoint's BFS wavefront (ROADMAP item-2 elastic prerequisite).
 
-Every engine family's checkpoint — classic ``Engine``, ``SpillEngine``,
-``ShardedEngine``, ``SpilledShardedEngine`` — carries the same logical
-wavefront under different physical layouts: the visited-fingerprint
-SET, the frontier rows (the last committed level) in gid order, the
-run counters, and the trace archives.  This module reads any of those
-files into one normalized ``PortableImage``:
+Both checkpoint formats — the classic ``Engine``'s (which
+``PjitShardedEngine`` also writes, gathered to host) and
+``SpillEngine``'s — carry the same logical wavefront under different
+physical layouts: the visited-fingerprint SET, the frontier rows (the
+last committed level) in gid order, the run counters, and the trace
+archives.  This module reads either into one normalized
+``PortableImage``:
 
 - ``keys``  — [N, W] u32 visited fingerprints (dense tables are
-  sparsified; host-partition images are pooled; per-device shards are
-  concatenated — membership is a set property, so the physical slot
-  layout never matters);
+  sparsified; host-partition images are pooled — membership is a set
+  property, so the physical slot layout never matters);
 - ``rows``/``gids``/``con`` — frontier rows batch-major in narrow
   storage dtypes, their global ids, and the constraint mask
   (prune-not-expand: ``con=False`` rows are archived but never
@@ -19,19 +19,22 @@ files into one normalized ``PortableImage``:
 - counters (``CheckResult``), depth, ``n_states``, and the archives.
 
 A target engine re-partitions on load: the spill engine rebuilds its
-table image (and host partitions) from the key set, the sharded
-engines re-route keys and frontier rows by hash ownership
-(``key[W-1] % D`` — a pure function of content, so ANY device count
-works).  That is what makes a mesh checkpoint resumable on a different
-pod-slice shape or on the spill engine after a lost machine.
+table image (and host partitions) from the key set, the classic and
+pjit engines re-insert it into their table (the pjit mesh then
+re-partitions it onto its named shardings, so ANY device count
+works).  That is what makes a pjit-mesh checkpoint resumable on a
+different pod-slice shape or on the spill engine after a lost
+machine.
 
 Exactness: dedup needs key-set MEMBERSHIP, not slot layout, and gid
 assignment for new states is discovery-order determined by the
-frontier row order this image preserves — so a same-shape portable
-resume is bit-exact, and a cross-shape one lands on the exact counts /
-level sizes of an uninterrupted run at the target shape (each engine
-is oracle-exact; the mesh engines are mesh-size invariant by the
-content-canonical survivor policy).
+frontier row order this image preserves — so a portable resume lands
+on the exact counts / level sizes of an uninterrupted run (each
+engine is oracle-exact, and the pjit engine runs the classic engine's
+program on any mesh size).
+
+The removed shard_map mesh engines' format (meta ``sharded``) is
+refused by name (engine/bfs.ckpt_refuse_removed_format).
 """
 
 from __future__ import annotations
@@ -136,12 +139,6 @@ def validate_image(img: "PortableImage", spec_name: str,
             f"expects {W} (fp64 vs fp128 mismatch)")
 
 
-def dense_table_keys(words: List[np.ndarray]) -> np.ndarray:
-    """Public alias of the sparsifier (the spill-mesh serializer pools
-    its device shards through it)."""
-    return _dense_table_keys(words)
-
-
 def _dense_table_keys(words: List[np.ndarray]) -> np.ndarray:
     """[W] x u32[...C] dense open-addressing table -> [N, W] keys
     (all-ones aliases "empty" — the engines' accepted-risk class)."""
@@ -175,23 +172,18 @@ def load_portable_image(path: str) -> PortableImage:
     import json
 
     from ..engine.bfs import (CheckpointError, ckpt_check_bag_order,
-                              ckpt_result)
+                              ckpt_refuse_removed_format, ckpt_result)
     from .ckpt_chain import load_engine_npz
     try:
         z, used = open_validated(path, load_engine_npz)
     except IntegrityError as e:
         raise CheckpointError(str(e)) from e
     meta = json.loads(str(z["meta"]))
+    ckpt_refuse_removed_format(used, meta)
     ckpt_check_bag_order(used, meta)
-    spill = bool(meta.get("spill"))
-    sharded = bool(meta.get("sharded"))
     try:
-        if spill and sharded:
-            img = _extract_spill_mesh(z, meta)
-        elif spill:
+        if meta.get("spill"):
             img = _extract_spill(z, meta)
-        elif sharded:
-            img = _extract_sharded(z, meta)
         else:
             img = _extract_engine(z, meta)
     except KeyError as e:
@@ -248,50 +240,6 @@ def _extract_engine(z, meta) -> PortableImage:
     return img
 
 
-def _extract_sharded(z, meta) -> PortableImage:
-    img = _blank("sharded")
-    words = []
-    w = 0
-    while f"carry|vis|{w}" in z:
-        words.append(np.asarray(z[f"carry|vis|{w}"]))   # [D, VB]
-        w += 1
-    if not words:
-        raise KeyError("carry|vis|0")
-    img.keys = _dense_table_keys(words)
-    nfd = np.asarray(z["carry|n_front"])               # [D]
-    fmask = np.asarray(z["carry|fmask"])               # [D, LB]
-    gids = np.asarray(z["carry|gids"])                 # [D, LB]
-    D = nfd.shape[0]
-    fronts = {}
-    for nm in z.files:
-        if nm.startswith("carry|front|"):
-            fronts[nm.split("|", 2)[2]] = np.asarray(z[nm])
-    rows_d, gids_d, con_d = [], [], []
-    for d in range(D):
-        n = int(nfd[d])
-        if not n:
-            continue
-        rows_d.append({k: v[d, :n] for k, v in fronts.items()})
-        gids_d.append(gids[d, :n].astype(np.int32))
-        con_d.append(fmask[d, :n].astype(bool))
-    if rows_d:
-        keys0 = rows_d[0].keys()
-        rows = {k: np.concatenate([r[k] for r in rows_d])
-                for k in keys0}
-        g = np.concatenate(gids_d)
-        c = np.concatenate(con_d)
-        order = np.argsort(g, kind="stable")   # global gid order
-        img.rows = {k: np.ascontiguousarray(v[order])
-                    for k, v in rows.items()}
-        img.gids = g[order]
-        img.con = c[order]
-    else:
-        img.rows = {k: v[:0, 0] for k, v in fronts.items()}
-        img.gids = np.zeros((0,), np.int32)
-        img.con = np.zeros((0,), bool)
-    return img
-
-
 def _extract_spill(z, meta) -> PortableImage:
     img = _blank("spill")
     if meta.get("host_table"):
@@ -326,28 +274,3 @@ def _extract_spill(z, meta) -> PortableImage:
     img.con = np.ones((img.gids.shape[0],), bool)
     return img
 
-
-def _extract_spill_mesh(z, meta) -> PortableImage:
-    """The round-12 SpilledShardedEngine format writes the wavefront
-    pooled and gid-ordered already — the portable form IS the native
-    form (parallel/spill_mesh _save_checkpoint)."""
-    img = _blank("spill_mesh")
-    if meta.get("host_table"):
-        D = int(meta["D"])
-        parts = []
-        for d in range(D):
-            shape = np.asarray(z[f"carry|hpt{d}|shape"])
-            for p in range(int(shape[0])):
-                parts.append(np.asarray(z[f"carry|hpt{d}|keys{p}"]).T)
-        img.keys = np.concatenate(parts) if parts else \
-            np.zeros((0, int(meta.get("W", 2))), np.uint32)
-    else:
-        img.keys = np.ascontiguousarray(np.asarray(z["carry|keys"]))
-    rows = {}
-    for nm in z.files:
-        if nm.startswith("carry|pf|rows|"):
-            rows[nm.split("|", 3)[3]] = np.asarray(z[nm])  # batch-major
-    img.rows = rows
-    img.gids = np.asarray(z["carry|pf|g"]).astype(np.int32)
-    img.con = np.ones((img.gids.shape[0],), bool)
-    return img

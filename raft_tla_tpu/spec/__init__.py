@@ -1,6 +1,6 @@
 """Spec-agnostic frontend: the ``SpecIR`` contract (ROADMAP item 2).
 
-The five engines (bfs / spill / mesh / spill_mesh / sim) never execute
+The engines (bfs / spill / pjit_mesh / sim) never execute
 TLA+; they consume a *compiled operator surface*:
 
   * an Init-state constructor and a bit-packed SoA layout + codec

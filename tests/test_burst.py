@@ -36,18 +36,6 @@ SPILL_MICRO = ModelConfig(
                        max_client_requests=1))
 SPILL_KW = dict(chunk=64, seg=1 << 10, vcap=1 << 12, sync_every=2)
 
-# mesh micro (test_sharded's shape: VIEW-only constraints, where
-# count parity is representative-insensitive by construction)
-MESH_MICRO = ModelConfig(
-    n_servers=2, init_servers=(0, 1), values=(1,),
-    max_inflight_override=2, next_family=NEXT_ASYNC, symmetry=False,
-    constraints=("BoundedInFlightMessages", "BoundedRequestVote",
-                 "BoundedLogSize", "BoundedTerms"),
-    invariants=("ElectionSafety", "LogMatching"),
-    bounds=Bounds.make(max_log_length=1, max_timeouts=1,
-                       max_client_requests=1))
-
-
 def _counts_match(a, b):
     assert a.distinct_states == b.distinct_states
     assert a.generated_states == b.generated_states
@@ -185,25 +173,6 @@ def test_spill_burst_matches_segment_driver():
 
 
 @pytest.mark.slow
-def test_sharded_burst_matches_level_driver():
-    """The mesh engines' fused K-level driver vs the per-level
-    shard_map program (8-virtual-device CPU mesh).  Slow-marked: two
-    shard_map compiles per engine cost ~2 min on this container; the
-    default tier-1 representative for the burst is the classic +
-    spill pair above, and the existing default sharded differentials
-    run with burst=True anyway (engaging the fused path against the
-    oracle)."""
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    r_on = ShardedEngine(MESH_MICRO, chunk=64, store_states=False,
-                         burst=True).check(max_depth=10)
-    r_off = ShardedEngine(MESH_MICRO, chunk=64, store_states=False,
-                          burst=False).check(max_depth=10)
-    _counts_match(r_on, r_off)
-    assert r_on.levels_fused > 0
-    assert r_off.levels_fused == 0
-
-
-@pytest.mark.slow
 def test_spill_burst_full_parity_archives_traces():
     """Full space: spill burst on/off counts, archives, violations AND
     witness-trace replay bit-identical (the burst's gid assignment
@@ -255,53 +224,3 @@ def test_spill_burst_violation_and_checkpoint():
         resumed = e2.check(resume_from=ckpt)
     assert resumed.distinct_states == full.distinct_states
     assert resumed.level_sizes == full.level_sizes
-
-
-@pytest.mark.slow
-def test_sharded_burst_full_parity_archives():
-    """Full space on the virtual mesh: counts, violations and the
-    device-major archives bit-identical burst on/off."""
-    from collections import Counter
-
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    e_on = ShardedEngine(MESH_MICRO, chunk=64, store_states=True,
-                         burst=True)
-    r_on = e_on.check()
-    e_off = ShardedEngine(MESH_MICRO, chunk=64, store_states=True,
-                          burst=False)
-    r_off = e_off.check()
-    _counts_match(r_on, r_off)
-    assert r_on.levels_fused > 0
-    assert Counter(v.invariant for v in r_on.violations) == \
-        Counter(v.invariant for v in r_off.violations)
-    assert sorted(v.state_id for v in r_on.violations) == \
-        sorted(v.state_id for v in r_off.violations)
-    _archives_match(e_on, e_off)
-
-
-@pytest.mark.slow
-def test_spill_mesh_burst_full_parity_archives_traces():
-    """Full space, spill-composed mesh: counts, archives, violations
-    and witness-trace replay bit-identical burst on/off — including
-    the in-burst frontier compaction matching the host's
-    prune-not-expand row drop exactly."""
-    from collections import Counter
-
-    from raft_tla_tpu.parallel.spill_mesh import SpilledShardedEngine
-    e_on = SpilledShardedEngine(MESH_MICRO, chunk=64,
-                                store_states=True, lcap=1 << 11,
-                                burst=True)
-    r_on = e_on.check()
-    e_off = SpilledShardedEngine(MESH_MICRO, chunk=64,
-                                 store_states=True, lcap=1 << 11,
-                                 burst=False)
-    r_off = e_off.check()
-    _counts_match(r_on, r_off)
-    assert r_on.levels_fused > 0
-    assert Counter(v.invariant for v in r_on.violations) == \
-        Counter(v.invariant for v in r_off.violations)
-    _archives_match(e_on, e_off)
-    g = r_on.distinct_states - 1
-    ta, tb = e_on.trace(g), e_off.trace(g)
-    assert [l for l, _ in ta] == [l for l, _ in tb]
-    assert all(sa == sb for (_, sa), (_, sb) in zip(ta, tb))

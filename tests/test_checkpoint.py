@@ -39,37 +39,6 @@ def test_checkpoint_resume_identical(tmp_path):
     assert sum(len(p) for p in e2._parents) == full.distinct_states
 
 
-@pytest.mark.slow
-def test_sharded_checkpoint_resume_identical(tmp_path):
-    import jax
-
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    devs = jax.devices()
-    full = ShardedEngine(MICRO, devices=devs, chunk=8 * len(devs),
-                         store_states=True).check()
-
-    ckpt = str(tmp_path / "sharded.ckpt")
-    e1 = ShardedEngine(MICRO, devices=devs, chunk=8 * len(devs),
-                       store_states=True)
-    part = e1.check(max_depth=12, checkpoint_path=ckpt)
-    assert part.depth == 12
-    assert part.distinct_states < full.distinct_states
-
-    e2 = ShardedEngine(MICRO, devices=devs, chunk=8 * len(devs),
-                       store_states=True)
-    resumed = e2.check(resume_from=ckpt)
-    assert resumed.distinct_states == full.distinct_states
-    assert resumed.depth == full.depth
-    assert resumed.generated_states == full.generated_states
-    assert resumed.level_sizes == full.level_sizes
-    assert sum(len(p) for p in e2._parents) == full.distinct_states
-
-    # cross-engine resumes are rejected with a clear error
-    from raft_tla_tpu.engine.bfs import CheckpointError
-    with pytest.raises(CheckpointError, match="sharded-engine"):
-        Engine(MICRO, chunk=64).check(resume_from=ckpt)
-
-
 def test_checkpoint_config_mismatch(tmp_path):
     ckpt = str(tmp_path / "run.ckpt")
     Engine(MICRO, chunk=64, store_states=False).check(
@@ -110,9 +79,12 @@ def test_resume_keeps_lane_counts_and_refuses_arrival_order_bags(
         tmp_path):
     """A resumed check reports the enabled lanes an uninterrupted one
     does; a checkpoint written before bags were kept in message order
-    (its meta lacks ``bag_order``) is refused, natively and portably."""
+    (its meta lacks ``bag_order``) is refused, natively and portably —
+    and so is one of the removed shard_map mesh engines (meta
+    ``sharded``, alone or with ``spill``), by name, never read as the
+    classic or spill format it resembles."""
     import numpy as np
-    from raft_tla_tpu.engine.bfs import CheckpointError
+    from raft_tla_tpu.engine.bfs import CheckpointError, ckpt_read
     from raft_tla_tpu.resil.ckpt_chain import publish
     from raft_tla_tpu.resil.portable import load_portable_image
     full = Engine(MICRO, chunk=64, store_states=False).check(max_depth=8)
@@ -125,17 +97,35 @@ def test_resume_keeps_lane_counts_and_refuses_arrival_order_bags(
     assert resumed.lanes_enabled == full.lanes_enabled
     assert sum(full.lanes_enabled.values()) == full.generated_states - 1
 
-    with np.load(ckpt) as z:
-        data = dict(z)
-    meta = json.loads(str(data["meta"]))
-    assert meta.pop("bag_order") == 1
-    data["meta"] = np.array(json.dumps(meta))
-    tmp = str(tmp_path / "old.tmp.npz")
-    np.savez(tmp, **data)
-    old = str(tmp_path / "old.ckpt")
-    publish(tmp, old)
-    with pytest.raises(CheckpointError, match="message order"):
-        Engine(MICRO, chunk=64, store_states=False).check(
-            max_depth=8, resume_from=old)
-    with pytest.raises(CheckpointError, match="message order"):
-        load_portable_image(old)
+    def rewritten(name, edit):
+        with np.load(ckpt) as z:
+            data = dict(z)
+        meta = json.loads(str(data["meta"]))
+        edit(meta)
+        data["meta"] = np.array(json.dumps(meta))
+        tmp = str(tmp_path / f"{name}.tmp.npz")
+        np.savez(tmp, **data)
+        out = str(tmp_path / f"{name}.ckpt")
+        publish(tmp, out)
+        return out
+
+    def drop_bag_order(meta):
+        assert meta.pop("bag_order") == 1
+
+    removed = "removed shard_map mesh engine format"
+    for name, edit, match in [
+            ("old", drop_bag_order, "message order"),
+            ("mesh", lambda m: m.update(sharded=True, D=2), removed),
+            ("spill_sharded",
+             lambda m: m.update(sharded=True, spill=True, D=2),
+             removed)]:
+        bad = rewritten(name, edit)
+        with pytest.raises(CheckpointError, match=match):
+            Engine(MICRO, chunk=64, store_states=False).check(
+                max_depth=8, resume_from=bad)
+        with pytest.raises(CheckpointError, match=match):
+            load_portable_image(bad)
+        if match == removed:
+            # the spill engine's reader refuses it too
+            with pytest.raises(CheckpointError, match=match):
+                ckpt_read(bad, repr(MICRO), 64, (), spill=True)

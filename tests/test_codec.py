@@ -146,8 +146,8 @@ def test_membership_feature_lanes_match_oracle_predicates():
 def test_narrow_widen_roundtrip_and_fp_parity():
     """Engines store rows in codec.narrow_dtypes; narrowing must be
     lossless under the configured bounds and the fingerprint must be
-    bit-identical on narrow and wide rows (the sharded engine
-    fingerprints wide rows but ships narrow rows over the ICI)."""
+    bit-identical on narrow and wide rows (the engines fingerprint
+    wide rows but store and re-load narrow ones)."""
     import jax
     import numpy as np
     from raft_tla_tpu.engine.fingerprint import Fingerprinter

@@ -183,23 +183,16 @@ def test_spill_delta_off_matches_oracle():
     assert _key(r) == _oracle_key(TINY, max_depth=6)
 
 
-def test_mesh_delta_off_matches_oracle():
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    r = ShardedEngine(TINY, chunk=64, store_states=False,
-                      delta_matmul=False).check(max_depth=6)
+def test_pjit_delta_off_matches_oracle():
+    from raft_tla_tpu.parallel.pjit_mesh import PjitShardedEngine
+    r = PjitShardedEngine(TINY, chunk=64, store_states=False,
+                          delta_matmul=False).check(max_depth=6)
+    assert r.delta_matmul == 0
     assert _key(r) == _oracle_key(TINY, max_depth=6)
 
 
-def test_spill_mesh_delta_off_matches_oracle():
-    from raft_tla_tpu.parallel.spill_mesh import SpilledShardedEngine
-    r = SpilledShardedEngine(TINY, chunk=64, store_states=False,
-                             lcap=1 << 11,
-                             delta_matmul=False).check(max_depth=4)
-    assert _key(r) == _oracle_key(TINY, max_depth=4)
-
-
 def test_sim_delta_bit_identical_trajectories():
-    """The fifth engine: same seed, delta ON vs OFF — walker
+    """The sim engine: same seed, delta ON vs OFF — walker
     trajectories, counters and Bloom estimates all bit-identical
     (identical guards => identical draws => step_lanes must land the
     identical successor through the group matmul)."""
@@ -377,16 +370,6 @@ def test_spill_delta_on_off_full_space():
         rs[dm] = SpillEngine(MICRO, chunk=64, store_states=False,
                              seg=1 << 10, vcap=1 << 12, sync_every=2,
                              delta_matmul=dm).check()
-    assert _key(rs[True]) == _key(rs[False])
-
-
-@pytest.mark.slow
-def test_mesh_delta_on_off_full_space():
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    rs = {}
-    for dm in (True, False):
-        rs[dm] = ShardedEngine(TINY, chunk=64, store_states=False,
-                               delta_matmul=dm).check()
     assert _key(rs[True]) == _key(rs[False])
 
 

@@ -1,14 +1,15 @@
 """The shared level-harvest/driver core (engine/driver — ROADMAP item
 5): unit reps for the extracted loop's exact semantics (depth gate,
 id guard, burst checkpoint crossing, callback ordering) plus the
-routing reps pinning that all FIVE former copies (bfs, spill, mesh,
-spill_mesh, batched serve) actually call it — the bit-exactness of the
-re-homed call sites themselves is pinned by every existing engine
-differential (test_engine / test_spill / test_sharded /
-test_spill_mesh / test_serve run unchanged).
+routing reps pinning that every engine's harvest loop (bfs, spill,
+the pjit engine's inherited bfs loop, batched serve) actually calls
+it — the bit-exactness of the call sites themselves is pinned by
+every engine differential (test_engine / test_spill / test_pjit /
+test_serve).
 """
 
 import inspect
+import sys
 
 import numpy as np
 import pytest
@@ -124,41 +125,46 @@ def test_burst_archive_slice_copies_out_of_ring():
 
 
 # ---------------------------------------------------------------------------
-# routing reps: the five former copies all call the shared core (the
-# point of ROADMAP item 5 — control-flow duplication is dead, so a
-# drift class can no longer exist)
+# routing reps: every harvest loop calls the shared core (the point of
+# ROADMAP item 5 — control-flow duplication is dead, so a drift class
+# can no longer exist)
 # ---------------------------------------------------------------------------
 
-FIVE_CALL_SITES = [
+CALL_SITES = [
     ("raft_tla_tpu.engine.bfs", "Engine"),
     ("raft_tla_tpu.engine.spill", "SpillEngine"),
-    ("raft_tla_tpu.parallel.mesh", "ShardedEngine"),
-    ("raft_tla_tpu.parallel.spill_mesh", "SpilledShardedEngine"),
+    ("raft_tla_tpu.parallel.pjit_mesh", "PjitShardedEngine"),
     ("raft_tla_tpu.serve.batch", "BucketEngine"),
 ]
+# the method that holds each class's harvest loop (default: check)
+_LOOP = {"BucketEngine": "_harvest"}
 
 
-@pytest.mark.parametrize("modname,_cls", FIVE_CALL_SITES)
+@pytest.mark.parametrize("modname,_cls", CALL_SITES)
 def test_harvest_routes_through_driver(modname, _cls):
     import importlib
-    src = inspect.getsource(importlib.import_module(modname))
+    cls = getattr(importlib.import_module(modname), _cls)
+    # read the module that defines the loop: an engine that inherits
+    # its loop (the pjit engine runs Engine.check) is checked there,
+    # and one that grows a loop of its own is checked in its module
+    loop = _LOOP.get(_cls, "check")
+    owner = next(k for k in cls.__mro__ if loop in vars(k))
+    src = inspect.getsource(sys.modules[owner.__module__])
     assert "harvest_fused_levels" in src, \
-        f"{modname}: fused harvest no longer routes through " \
+        f"{owner.__module__}: fused harvest no longer routes through " \
         "engine/driver"
     # the tell-tale of a re-inlined copy: the pseudo-level counter
     # bump next to a local depth increment (levels_fused is accounted
     # INSIDE driver.harvest_fused_levels / the per-level drivers'
     # shared gate only)
     assert "res.levels_fused += 1" not in src, \
-        f"{modname}: a local harvest copy re-appeared"
+        f"{owner.__module__}: a local harvest copy re-appeared"
 
 
 def test_per_level_drivers_share_the_gate():
     import importlib
     for modname in ("raft_tla_tpu.engine.bfs",
-                    "raft_tla_tpu.parallel.mesh",
-                    "raft_tla_tpu.engine.spill",
-                    "raft_tla_tpu.parallel.spill_mesh"):
+                    "raft_tla_tpu.engine.spill"):
         src = inspect.getsource(importlib.import_module(modname))
         assert ("gate_level_depth" in src
                 or "harvest_fused_levels" in src), modname

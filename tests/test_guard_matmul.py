@@ -141,30 +141,8 @@ def test_spill_lane_path_matches_oracle():
     assert _engine_key(r) == _oracle_key(TINY, max_depth=10)
 
 
-@pytest.mark.slow
-def test_mesh_lane_path_matches_oracle():
-    # slow-marked (round-13 suite diet): same reasoning as the spill
-    # twin above — mesh keeps a fast default-path oracle differential
-    # in test_delta_matmul.py
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    r = ShardedEngine(TINY, chunk=64, store_states=False,
-                      guard_matmul=False).check(max_depth=10)
-    assert _engine_key(r) == _oracle_key(TINY, max_depth=10)
-
-
-@pytest.mark.slow
-def test_spill_mesh_lane_path_matches_oracle():
-    # slow-marked: the spill-composed mesh inherits its whole guard
-    # path from Engine/ShardedEngine (both covered fast above); its
-    # own ON/OFF pair runs in the slow set too
-    from raft_tla_tpu.parallel.spill_mesh import SpilledShardedEngine
-    r = SpilledShardedEngine(TINY, chunk=64, store_states=False,
-                            lcap=1 << 11, guard_matmul=False).check()
-    assert _engine_key(r) == _oracle_key(TINY)
-
-
 def test_sim_guard_matmul_bit_identical_trajectories():
-    """The fifth engine: same seed, matmul ON vs OFF — walker
+    """The sim engine: same seed, matmul ON vs OFF — walker
     trajectories, counters and Bloom estimates all bit-identical
     (guards identical => identical uniform draws => identical
     step_lanes selections)."""
@@ -343,20 +321,3 @@ def test_spill_guard_matmul_full_space_with_bursts():
     assert _key(rs[True]) == _key(rs[False])
 
 
-@pytest.mark.slow
-def test_mesh_guard_matmul_on_off_pair():
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    rs = {gm: ShardedEngine(TINY, chunk=64, store_states=False,
-                            guard_matmul=gm).check()
-          for gm in (True, False)}
-    assert _key(rs[True]) == _key(rs[False])
-
-
-@pytest.mark.slow
-def test_spill_mesh_guard_matmul_on_off_pair():
-    from raft_tla_tpu.parallel.spill_mesh import SpilledShardedEngine
-    rs = {gm: SpilledShardedEngine(TINY, chunk=64, store_states=False,
-                                   lcap=1 << 11,
-                                   guard_matmul=gm).check()
-          for gm in (True, False)}
-    assert _key(rs[True]) == _key(rs[False])

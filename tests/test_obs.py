@@ -3,8 +3,8 @@ metrics registry, ledger, heartbeat — and the cross-engine telemetry
 parity the registry exists to guarantee.
 
 The parity test is the structural guard against the PR-5 drift class
-(`levels_fused` counted differently per harvest loop): all five
-engines run the same tiny config and must emit the identical registry
+(`levels_fused` counted differently per harvest loop): every
+engine runs the same tiny config and must emit the identical registry
 key set, with the burst counters byte-equal between the ledger's final
 record, the --stats-json payload and the checkpoint meta.
 """
@@ -23,8 +23,8 @@ from raft_tla_tpu.obs import (CHECK_COUNTER_KEYS, BURST_COUNTER_KEYS,
                               SpanRecorder, check_stats)
 from raft_tla_tpu.obs.heartbeat import read_heartbeat
 
-# the same tiny config for every engine (test_sharded's micro: VIEW-
-# only constraints so count parity is representative-insensitive)
+# the same tiny config for every engine (VIEW-only constraints so
+# count parity is representative-insensitive)
 TINY = ModelConfig(
     n_servers=2, init_servers=(0, 1), values=(1,),
     max_inflight_override=2, next_family=NEXT_ASYNC, symmetry=False,
@@ -183,54 +183,46 @@ def test_obs_dispatch_record_shape(tmp_path):
 
 
 # ---------------------------------------------------------------------
-# cross-engine telemetry parity (the acceptance test): all five
-# engines, same tiny config, identical registry key sets; burst
+# cross-engine telemetry parity (the acceptance test): every
+# exhaustive engine, same tiny config, identical registry key sets; burst
 # counters consistent between ledger, --stats-json payload and
 # checkpoint meta
 # ---------------------------------------------------------------------
 
 
-def _run_with_obs(name, make_engine, tmp_path, checkpoint=True):
+def _run_with_obs(name, make_engine, tmp_path):
     led_path = str(tmp_path / f"{name}.jsonl")
     hb_path = str(tmp_path / f"{name}.hb.json")
     ckpt_path = str(tmp_path / f"{name}.ckpt")
     obs = Obs(ledger=RunLedger(led_path), heartbeat=Heartbeat(hb_path))
     obs.start()
     eng = make_engine()
-    kw = dict(checkpoint_path=ckpt_path, checkpoint_every=1) \
-        if checkpoint else {}
-    r = eng.check(obs=obs, **kw)
+    r = eng.check(obs=obs, checkpoint_path=ckpt_path, checkpoint_every=1)
     obs.finish(depth=int(r.depth), states=int(r.distinct_states))
     recs = [json.loads(x) for x in open(led_path)]
     assert recs, f"{name}: no ledger records"
     stats = check_stats(r.metrics.as_dict(), r.seconds,
                         len(r.violations), fp_bits=64)
-    meta = None
-    if checkpoint:
-        z = np.load(ckpt_path, allow_pickle=False)
+    with np.load(ckpt_path, allow_pickle=False) as z:
         meta = json.loads(str(z["meta"]))
-        z.close()
     return r, recs, stats, meta, read_heartbeat(hb_path)
 
 
 def _engine_cases():
+    import jax
+
     from raft_tla_tpu.engine.bfs import Engine
     from raft_tla_tpu.engine.spill import SpillEngine
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    from raft_tla_tpu.parallel.spill_mesh import SpilledShardedEngine
+    from raft_tla_tpu.parallel.pjit_mesh import PjitShardedEngine
 
     return {
-        "bfs": (lambda: Engine(TINY, chunk=64, store_states=False),
-                True),
-        "spill": (lambda: SpillEngine(
+        "bfs": lambda: Engine(TINY, chunk=64, store_states=False),
+        "spill": lambda: SpillEngine(
             TINY, chunk=64, store_states=False, seg=1 << 10,
-            vcap=1 << 12, sync_every=2), True),
-        "mesh": (lambda: ShardedEngine(TINY, chunk=64,
-                                       store_states=False), True),
-        # SpilledShardedEngine does not checkpoint yet (its check
-        # raises) — ledger/stats parity only
-        "spill_mesh": (lambda: SpilledShardedEngine(
-            TINY, chunk=64, store_states=False, lcap=1 << 11), False),
+            vcap=1 << 12, sync_every=2),
+        "pjit": lambda: PjitShardedEngine(
+            TINY, devices=jax.devices()[:2], chunk=64,
+            store_states=False),
     }
 
 
@@ -238,9 +230,8 @@ def _telemetry_parity(name, tmp_path):
     """One engine family on the tiny config: registry key set,
     ledger/stats/checkpoint-meta burst-counter agreement, heartbeat
     parity."""
-    make, ckpt = _engine_cases()[name]
     r, recs, stats, meta, hb = _run_with_obs(
-        name, make, tmp_path, checkpoint=ckpt)
+        name, _engine_cases()[name], tmp_path)
     # 1. the registry key set — structural identity across engines
     assert tuple(r.metrics.keys()) == CHECK_COUNTER_KEYS, name
     # 2. every DISPATCH record carries every registry key (the
@@ -256,10 +247,9 @@ def _telemetry_parity(name, tmp_path):
     for k in BURST_COUNTER_KEYS:
         assert last[k] == stats[k], (name, k)
     # ... == checkpoint meta (the third historical copy)
-    if meta is not None:
-        for k in BURST_COUNTER_KEYS:
-            assert meta[k] == stats[k], (name, k)
-        assert meta["distinct"] == stats["distinct_states"], name
+    for k in BURST_COUNTER_KEYS:
+        assert meta[k] == stats[k], (name, k)
+    assert meta["distinct"] == stats["distinct_states"], name
     # 4. heartbeat final depth == the run's reported depth
     assert hb["depth"] == r.depth == stats["depth"], name
     assert hb["states_enqueued"] == r.distinct_states, name
@@ -277,18 +267,10 @@ def _telemetry_parity(name, tmp_path):
         (w.distinct_states, w.depth, tuple(w.level_sizes)), name
 
 
-@pytest.mark.parametrize("name", ["bfs", "spill"])
+@pytest.mark.parametrize("name", ["bfs", "spill", "pjit"])
 def test_telemetry_parity_engine(name, tmp_path):
-    """Fast representatives (tier-1 budget, round-13 suite diet): the
-    single-device families.  The mesh variants below run the same body
-    slow-marked — the MetricsRegistry single-source design plus the
-    mesh count differentials elsewhere keep the fast signal."""
-    _telemetry_parity(name, tmp_path)
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("name", ["mesh", "spill_mesh"])
-def test_telemetry_parity_engine_mesh_slow(name, tmp_path):
+    """Every exhaustive engine family: the two single-device ones and
+    the multi-device pjit engine on a 2-device mesh."""
     _telemetry_parity(name, tmp_path)
 
 
@@ -524,7 +506,7 @@ def test_second_traced_check_runs_no_prewarm(dedup_runs):
 
 
 def test_telemetry_parity_sim_engine(tmp_path):
-    """The fifth engine family: the sim ledger's per-dispatch records
+    """The sim engine family: the sim ledger's per-dispatch records
     carry exactly the canonical SIM_DISPATCH_KEYS, consistent with the
     SimResult the run returns."""
     from raft_tla_tpu.sim.walker import SimEngine

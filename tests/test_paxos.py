@@ -2,10 +2,10 @@
 
 Mirrors the Raft test architecture: the plain-Python oracle
 (spec/paxos/model.py) is the semantics anchor; the engines must match
-it bit-for-bit through the UNMODIFIED bfs/spill/mesh/sim stack.  Fast
+it bit-for-bit through the UNMODIFIED bfs/spill/sim stack.  Fast
 tier-1 representatives here are the oracle step-for-step differential
 and one engine-vs-oracle count parity run (sub-5s each); full-space
-and mesh/spill duplicates are slow-marked (tier-1 budget, ROADMAP
+and spill duplicates are slow-marked (tier-1 budget, ROADMAP
 standing constraint).
 """
 
@@ -287,15 +287,13 @@ def test_checkpoint_refuses_spec_mismatch(tmp_path):
     meta = dict(spec="paxos", cfg="whatever", chunk=128)
     np.savez(path, meta=np.array(json.dumps(meta)))
     with pytest.raises(CheckpointError, match="spec 'paxos'"):
-        ckpt_read(path, "whatever", 128, (), sharded=False,
-                  spec_name="raft")
+        ckpt_read(path, "whatever", 128, (), spec_name="raft")
     # legacy meta (no spec key) == raft; passes the spec gate and
     # proceeds to the ordinary validation (here: missing base keys)
     meta2 = dict(cfg="whatever", chunk=128)
     np.savez(path, meta=np.array(json.dumps(meta2)))
     with pytest.raises(CheckpointError, match="older engine"):
-        ckpt_read(path, "whatever", 128, (), sharded=False,
-                  spec_name="raft")
+        ckpt_read(path, "whatever", 128, (), spec_name="raft")
 
 
 @pytest.mark.smoke
@@ -320,7 +318,7 @@ def test_ir_fingerprints_are_stable_and_distinct():
 
 
 # ---------------------------------------------------------------------------
-# full-space / mesh / spill duplicates (slow tier)
+# full-space / spill duplicates (slow tier)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
@@ -332,27 +330,6 @@ def test_engine_vs_oracle_spill_full_space():
     assert (r.distinct_states, r.generated_states, r.depth) == \
         (ro.distinct_states, ro.generated_states, ro.depth)
     assert r.level_sizes == ro.level_sizes
-
-
-@pytest.mark.slow
-def test_engine_vs_oracle_mesh_full_space():
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    ro = explore(CFG)
-    eng = ShardedEngine(CFG, chunk=64, store_states=False)
-    r = eng.check()
-    assert (r.distinct_states, r.generated_states, r.depth) == \
-        (ro.distinct_states, ro.generated_states, ro.depth)
-
-
-@pytest.mark.slow
-def test_engine_vs_oracle_spill_mesh_full_space():
-    from raft_tla_tpu.parallel.spill_mesh import SpilledShardedEngine
-    ro = explore(CFG)
-    eng = SpilledShardedEngine(CFG, chunk=64, store_states=False,
-                               lcap=1 << 12)
-    r = eng.check()
-    assert (r.distinct_states, r.generated_states, r.depth) == \
-        (ro.distinct_states, ro.generated_states, ro.depth)
 
 
 @pytest.mark.slow

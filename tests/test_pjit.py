@@ -148,36 +148,78 @@ def test_pjit_checkpoint_is_classic_format_and_archives_resume(
     e1.check(max_depth=DEPTH)
 
 
-def test_pjit_portable_resume_from_mesh_checkpoint(tmp_path, pj):
-    """Round-12 portable contract at pod shape: a mesh
-    (ShardedEngine) checkpoint — archives included — re-partitions
-    onto the pjit mesh via resume_image and lands on the exact
-    counts, archives and witness traces of an uninterrupted run (the
-    acceptance rep).  Resumes onto the module-shared pjit engine: its
-    compiled programs are capacity-compatible, so the rep costs no
-    extra engine compile."""
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
+def test_pjit_violation_matches_classic():
+    """A violation found on the mesh decodes to the classic engine's
+    state id and state, and its witness trace replays identically
+    (the violation rows come back through the replicated harvest)."""
+    import jax
+    cfg = MICRO.with_(invariants=MICRO.invariants +
+                      ("FirstBecomeLeader",))
+    got = {}
+    for name, eng in (
+            ("classic", Engine(cfg, chunk=64, store_states=True)),
+            ("pjit", PjitShardedEngine(cfg, devices=jax.devices()[:2],
+                                       chunk=64, store_states=True))):
+        r = eng.check(stop_on_violation=True)
+        assert r.violations, name
+        v = r.violations[0]
+        got[name] = (v.invariant, v.state_id, v.state,
+                     [(lbl, sv) for lbl, sv in eng.trace(v.state_id)])
+    assert got["pjit"][0] == "FirstBecomeLeader"
+    assert got["pjit"] == got["classic"]
+
+
+def test_dryrun_multichip_on_virtual_devices(capsys):
+    """The multi-chip dry-run entry point on 2 of conftest's virtual
+    CPU devices: the pjit engine's phase-1 step on the flagship model
+    and a micro model to fixpoint at the oracle's exact counts."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "graft_entry", os.path.join(REPO, "__graft_entry__.py"))
+    entry = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(entry)
+    entry.dryrun_multichip(2)
+    assert "== oracle" in capsys.readouterr().out
+
+
+def test_pjit_portable_resume_from_spill_checkpoint(tmp_path, pj):
+    """Round-12 portable contract at pod shape: a spill-engine
+    checkpoint — archives included — re-partitions onto the pjit mesh
+    via resume_image and lands on the exact counts, archives and
+    witness traces of an uninterrupted run (the acceptance rep).  The
+    spill engine drops constraint-pruned frontier rows, which the
+    pjit (classic) frontier layout needs: MICRO's frontier is whole
+    only up to depth 2, and a later checkpoint is refused by name.
+    Resumes onto the module-shared pjit engine: its compiled programs
+    are capacity-compatible, so the rep costs no extra pjit compile."""
+    from raft_tla_tpu.engine.bfs import CheckpointError
+    from raft_tla_tpu.engine.spill import SpillEngine
     from raft_tla_tpu.resil.portable import load_portable_image
     eng, r_full = pj
-    ck = str(tmp_path / "mesh.ckpt")
-    mesh = ShardedEngine(MICRO, chunk=64, store_states=True,
-                         lcap=1 << 12, vcap=1 << 15)
-    mesh.check(max_depth=6, checkpoint_path=ck)
+    sp = SpillEngine(MICRO, chunk=64, seg=1 << 12, store_states=True)
+    pruned = str(tmp_path / "spill6.ckpt")
+    sp.check(max_depth=6, checkpoint_path=pruned)
+    with pytest.raises(CheckpointError, match="not contiguous"):
+        eng.check(max_depth=DEPTH,
+                  resume_image=load_portable_image(pruned))
+    ck = str(tmp_path / "spill2.ckpt")
+    sp.check(max_depth=2, checkpoint_path=ck)
     img = load_portable_image(ck)
+    assert img.source_format == "spill" and img.depth == 2
     res = eng.check(max_depth=DEPTH, resume_image=img)
     assert res.distinct_states == r_full.distinct_states
     assert res.depth == r_full.depth
     assert list(res.level_sizes) == list(r_full.level_sizes)
     assert res.generated_states == r_full.generated_states
     # archives ported whole: every state has its row (pre-checkpoint
-    # rows keep the MESH engine's device-major gid order — portable
-    # archives preserve the source engine's id assignment, so label-
-    # for-label equality with the classic engine is only defined for
-    # counts, not row order) and a witness chain replays root-first
+    # rows keep the SPILL engine's gid order — portable archives
+    # preserve the source engine's id assignment, so label-for-label
+    # equality with the classic engine is only defined for counts,
+    # not row order) and a witness chain replays root-first
     assert sum(len(p) for p in eng._parents) == res.distinct_states
     labels = [l for l, _ in eng.trace(res.distinct_states - 1)]
     assert labels[0] == "Init" and len(labels) == DEPTH + 1
-    # NOTE: eng (the module-shared pjit engine) now holds mesh-ordered
+    # NOTE: eng (the module-shared pjit engine) now holds spill-ordered
     # archives; keep this test LAST among the fast per-gid users of
     # the fixture (e1 stays untouched)
 

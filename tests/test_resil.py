@@ -75,22 +75,12 @@ def classic_ref(classic):
 
 
 @pytest.fixture(scope="module")
-def sm2():
+def pjit2():
     import jax
 
-    from raft_tla_tpu.parallel.spill_mesh import SpilledShardedEngine
-    return SpilledShardedEngine(MICRO, devices=jax.devices()[:2],
-                                chunk=16, store_states=True,
-                                lcap=1 << 10, burst_levels=2)
-
-
-@pytest.fixture(scope="module")
-def mesh2():
-    import jax
-
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    return ShardedEngine(MICRO, devices=jax.devices()[:2], chunk=16,
-                         store_states=True, burst_levels=2)
+    from raft_tla_tpu.parallel.pjit_mesh import PjitShardedEngine
+    return PjitShardedEngine(MICRO, devices=jax.devices()[:2], chunk=16,
+                             store_states=True, burst_levels=2)
 
 
 # ---------------------------------------------------------------------------
@@ -266,9 +256,12 @@ def test_supervised_chaos_spill_dispatch_and_archive(tmp_path):
     assert _labels(eng2.trace(res.distinct_states - 1)) == ref_trace
 
 
-def test_supervised_chaos_sharded_mesh(mesh2, tmp_path):
-    eng = mesh2
-    ck = str(tmp_path / "mesh.ckpt")
+def test_supervised_chaos_pjit(pjit2, tmp_path):
+    """Multi-device rep: the pjit engine on a 2-device mesh recovers a
+    dispatch fault through its (classic-format) checkpoint,
+    bit-exact including the witness trace."""
+    eng = pjit2
+    ck = str(tmp_path / "pjit.ckpt")
     ref = eng.check(max_depth=6)
     ref_trace = _labels(eng.trace(ref.distinct_states - 1))
     chaos.install("dispatch:at=2")
@@ -281,118 +274,68 @@ def test_supervised_chaos_sharded_mesh(mesh2, tmp_path):
     assert _labels(eng2.trace(res.distinct_states - 1)) == ref_trace
 
 
-def test_supervised_chaos_spill_mesh_and_native_resume(sm2, tmp_path):
-    """SpilledShardedEngine rep (ROADMAP item-5 closure): the engine
-    now checkpoints — supervised chaos recovery is bit-exact, and a
-    plain partial+resume lands on identical counts, gids and witness
-    traces (the shared recovery contract)."""
-    eng = sm2
-    ck = str(tmp_path / "sm.ckpt")
-    ref = eng.check(max_depth=6)
-    gid = ref.distinct_states - 1
-    ref_trace = _labels(eng.trace(gid))
-    chaos.install("dispatch:at=2")
-    res, eng2, attempts = supervised_check(
-        lambda: eng, retries=1, backoff=0.01, checkpoint_path=ck,
-        checkpoint_every=1, max_depth=6, sleep=lambda s: None,
-        reinit=False)
-    assert attempts == 2
-    _same(res, ref)
-    assert _labels(eng2.trace(gid)) == ref_trace
-    chaos.uninstall()
-    # plain interrupt/resume, no chaos: counts + archives + traces
-    ck2 = str(tmp_path / "sm2.ckpt")
-    eng.check(max_depth=4, checkpoint_path=ck2, checkpoint_every=1)
-    resumed = eng.check(max_depth=6, resume_from=ck2)
-    _same(resumed, ref)
-    assert sum(len(p) for p in eng._parents) == ref.distinct_states
-    assert _labels(eng.trace(gid)) == ref_trace
-    # format pin: the file is the pooled portable form with the
-    # spill+sharded gates set (the wrong-D refusal itself is pinned
-    # by the slow cross-shape duplicate)
-    meta = json.loads(str(np.load(ck2)["meta"]))
-    assert meta["D"] == 2 and meta["spill"] and meta["sharded"]
-
-
 # ---------------------------------------------------------------------------
 # shape-portable resume (resil/portable)
 # ---------------------------------------------------------------------------
 
-def test_portable_resume_classic_and_mesh_cross_family(classic,
-                                                       classic_ref,
-                                                       mesh2, sm2,
-                                                       tmp_path):
+def test_portable_resume_classic_and_spill_cross_family(classic,
+                                                        classic_ref,
+                                                        pjit2,
+                                                        tmp_path):
     """The elastic-resume contract, fast reps: a classic-Engine
-    checkpoint and a 2-device mesh checkpoint both resume on the
-    spill-composed mesh by re-partitioning the visited image and
-    frontier on load — final counts/level sizes/depth equal the
-    uninterrupted run (the spill-engine and cross-device-count
-    targets run in the slow duplicate)."""
+    checkpoint resumes on the 2-device pjit mesh and on the spill
+    engine by re-partitioning the visited image and frontier on load —
+    final counts/level sizes/depth equal the uninterrupted run (the
+    cross-device-count targets run in the slow duplicate)."""
+    from raft_tla_tpu.engine.spill import SpillEngine
     ref, _ref_trace = classic_ref
     ck = str(tmp_path / "classic.ckpt")
     classic.check(max_depth=5, checkpoint_path=ck)
     img = load_portable_image(ck)
     assert img.source_format == "engine" and img.depth == 5
-    res = sm2.check(max_depth=8, resume_image=img)
+    res = pjit2.check(max_depth=8, resume_image=img)
     _same(res, ref)
-    assert sum(len(p) for p in sm2._parents) == ref.distinct_states
-    # mesh source: counts are mesh-size invariant, so the cross-family
-    # continuation must land on the same totals
-    ckm = str(tmp_path / "mesh.ckpt")
-    mesh2.check(max_depth=5, checkpoint_path=ckm)
-    img_m = load_portable_image(ckm)
-    assert img_m.source_format == "sharded"
-    res_m = sm2.check(max_depth=8, resume_image=img_m)
-    assert (res_m.distinct_states, res_m.depth) == \
-        (ref.distinct_states, ref.depth)
-    assert res_m.level_sizes == ref.level_sizes
+    assert sum(len(p) for p in pjit2._parents) == ref.distinct_states
+    sp = SpillEngine(MICRO, chunk=64, seg=1 << 12, store_states=True)
+    res_sp = sp.check(max_depth=8, resume_image=load_portable_image(ck))
+    _same(res_sp, ref)
+    assert sum(len(p) for p in sp._parents) == ref.distinct_states
     # target gates: wrong config refuses by name
     img_bad = load_portable_image(ck)
     img_bad.cfg_repr = "nope"
     with pytest.raises(CheckpointError, match="different model "
                                               "config"):
-        sm2.check(resume_image=img_bad)
+        pjit2.check(resume_image=img_bad)
 
 
 @pytest.mark.slow
 def test_portable_resume_mesh_to_other_mesh_sizes(tmp_path):
-    """Mesh D=2 checkpoint re-partitions onto D=4 meshes (classic and
-    spill-composed) AND onto the single-chip spill engine: the
-    different-device-count / different-engine elastic resume the
-    ROADMAP item-2 prerequisite names."""
+    """A 2-device pjit checkpoint re-partitions onto a 4-device pjit
+    mesh (natively — the file is classic format — and through the
+    portable image) AND onto the single-chip spill engine: counts and
+    witness traces do not depend on the mesh size."""
     import jax
 
     from raft_tla_tpu.engine.spill import SpillEngine
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
-    from raft_tla_tpu.parallel.spill_mesh import SpilledShardedEngine
+    from raft_tla_tpu.parallel.pjit_mesh import PjitShardedEngine
     devs = jax.devices()
-    e2 = ShardedEngine(MICRO, devices=devs[:2], chunk=16,
-                       store_states=True)
+    e2 = PjitShardedEngine(MICRO, devices=devs[:2], chunk=16,
+                           store_states=True)
     full = e2.check(max_depth=12)
-    ck = str(tmp_path / "mesh2.ckpt")
+    gid = full.distinct_states - 1
+    full_trace = _labels(e2.trace(gid))
+    ck = str(tmp_path / "pjit2.ckpt")
     e2.check(max_depth=6, checkpoint_path=ck)
-    img = load_portable_image(ck)
-    e4 = ShardedEngine(MICRO, devices=devs[:4], chunk=16,
-                       store_states=True)
-    res4 = e4.check(max_depth=12, resume_image=img)
+    e4 = PjitShardedEngine(MICRO, devices=devs[:4], chunk=16,
+                           store_states=True)
+    _same(e4.check(max_depth=12, resume_from=ck), full)
+    assert _labels(e4.trace(gid)) == full_trace
+    res4 = e4.check(max_depth=12, resume_image=load_portable_image(ck))
     _same(res4, full)
-    sm4 = SpilledShardedEngine(MICRO, devices=devs[:4], chunk=16,
-                               store_states=True, lcap=1 << 10)
-    res_sm = sm4.check(max_depth=12, resume_image=img)
-    assert (res_sm.distinct_states, res_sm.depth,
-            res_sm.level_sizes) == (full.distinct_states, full.depth,
-                                    full.level_sizes)
-    # exact same-shape resume refuses a wrong-D native load with a
-    # pointer to the portable path
+    assert _labels(e4.trace(gid)) == full_trace
     sp = SpillEngine(MICRO, chunk=64, seg=1 << 12, store_states=True)
-    res_sp = sp.check(max_depth=12, resume_image=img)
+    res_sp = sp.check(max_depth=12, resume_image=load_portable_image(ck))
     _same(res_sp, full)
-    sm2 = SpilledShardedEngine(MICRO, devices=devs[:2], chunk=16,
-                               store_states=True, lcap=1 << 10)
-    ck_sm = str(tmp_path / "sm2.ckpt")
-    sm2.check(max_depth=6, checkpoint_path=ck_sm)
-    with pytest.raises(CheckpointError, match="portable"):
-        sm4.check(resume_from=ck_sm)
 
 
 @pytest.mark.slow

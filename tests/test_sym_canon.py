@@ -247,15 +247,13 @@ def test_ckpt_read_refuses_cross_mode(tmp_path):
                CheckResult(), meta)
     with pytest.raises(CheckpointError,
                        match=r"--sym-canon minperm.*resolved sort"):
-        ckpt_read(path, repr(MICRO), 64, (), sharded=False,
-                  sym_canon="sort")
+        ckpt_read(path, repr(MICRO), 64, (), sym_canon="sort")
     # a legacy checkpoint (no sym_canon key) reads as minperm
     meta.pop("sym_canon")
     ckpt_write(path, {"x": np.zeros(4, np.int32)}, False, [], [], [],
                CheckResult(), meta)
     with pytest.raises(CheckpointError, match="--sym-canon minperm"):
-        ckpt_read(path, repr(MICRO), 64, (), sharded=False,
-                  sym_canon="sort")
+        ckpt_read(path, repr(MICRO), 64, (), sym_canon="sort")
 
 
 @pytest.mark.slow
@@ -281,36 +279,29 @@ def test_checkpoint_refuses_cross_mode_resume(tmp_path):
 
 @pytest.mark.smoke
 def test_mesh_chunk_rounds_up_to_devices():
-    from raft_tla_tpu.parallel import mesh
-    assert mesh._round_chunk_to_devices(512, 8) == 512
-    mesh._warned_uneven_chunk = False
+    from raft_tla_tpu.parallel import pjit_mesh
+    assert pjit_mesh._round_chunk_to_devices(512, 8) == 512
+    pjit_mesh._warned_uneven_chunk = False
     with pytest.warns(UserWarning, match="not a multiple"):
-        assert mesh._round_chunk_to_devices(20, 8) == 24
+        assert pjit_mesh._round_chunk_to_devices(20, 8) == 24
     # warn-once: the second uneven call is silent
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        assert mesh._round_chunk_to_devices(20, 8) == 24
-    mesh._warned_uneven_chunk = False
+        assert pjit_mesh._round_chunk_to_devices(20, 8) == 24
+    pjit_mesh._warned_uneven_chunk = False
 
 
-def test_sharded_engine_rounds_chunk():
+def test_pjit_engine_rounds_chunk():
     import jax as _jax
-    from raft_tla_tpu.parallel import mesh
-    from raft_tla_tpu.parallel.mesh import ShardedEngine
+    from raft_tla_tpu.parallel import pjit_mesh
     from raft_tla_tpu.parallel.pjit_mesh import PjitShardedEngine
     devs = _jax.devices()
-    mesh._warned_uneven_chunk = False
-    with pytest.warns(UserWarning, match="rounded up"):
-        eng = ShardedEngine(MICRO, devices=devs,
-                            chunk=len(devs) * 8 - 1)
-    assert eng.chunk == len(devs) * 8
-    assert eng.BL == 8
-    mesh._warned_uneven_chunk = False
+    pjit_mesh._warned_uneven_chunk = False
     with pytest.warns(UserWarning, match="rounded up"):
         pe = PjitShardedEngine(MICRO, devices=devs,
                                chunk=len(devs) * 8 - 1)
     assert pe.chunk == len(devs) * 8
-    mesh._warned_uneven_chunk = False
+    pjit_mesh._warned_uneven_chunk = False
 
 
 def test_sim_bloom_stays_canonical_at_s5():

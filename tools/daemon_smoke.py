@@ -83,9 +83,20 @@ def start_daemon(spool, tmp, exec_dir, extra=()):
            "--ledger", os.path.join(tmp, "ledger.jsonl"),
            "--heartbeat", os.path.join(tmp, "hb.json"),
            "--registry", os.path.join(tmp, "reg"), *extra]
-    return subprocess.Popen(cmd, cwd=REPO, env=env,
-                            stdout=subprocess.PIPE,
-                            stderr=subprocess.PIPE, text=True)
+    # the daemon writes to files, not pipes: nothing here drains a
+    # pipe while the daemon runs, and XLA:CPU's log lines (one per
+    # executable the compile cache loads) fill one and block it
+    logs = []
+    for suffix in (".out", ".err"):
+        fd, path = tempfile.mkstemp(prefix="daemon.", suffix=suffix,
+                                    dir=tmp)
+        logs.append((fd, path))
+    proc = subprocess.Popen(cmd, cwd=REPO, env=env, stdout=logs[0][0],
+                            stderr=logs[1][0])
+    for fd, _path in logs:
+        os.close(fd)              # the child holds its own copies
+    proc.log_paths = [path for _fd, path in logs]
+    return proc
 
 
 def wait_for(pred, what, timeout_s=420):
@@ -99,11 +110,15 @@ def wait_for(pred, what, timeout_s=420):
 
 def wait_exit(proc, what, timeout_s=420):
     try:
-        out, err = proc.communicate(timeout=timeout_s)
+        proc.wait(timeout=timeout_s)
     except subprocess.TimeoutExpired:
         proc.kill()
         raise AssertionError(f"daemon did not exit: {what}")
-    return proc.returncode, out, err
+    logs = []
+    for path in proc.log_paths:
+        with open(path) as fh:
+            logs.append(fh.read())
+    return (proc.returncode, *logs)
 
 
 def ledger_records(tmp):

@@ -11,10 +11,11 @@ validates the artifacts against the contracts the obs layer promises:
   --stats-json ones (the registry is the single source — any split
   would be the levels_fused drift class);
 - the Chrome-trace timeline satisfies the catapult trace_event schema
-  Perfetto validates: every event has ph/ts/dur/name, ph == "X",
+  Perfetto validates: every span event has ph/ts/dur/name, ph == "X",
   no negative timestamps or durations, and events on one (pid, tid)
   nest properly (no partial overlap — every inner span closed inside
-  its enclosing span);
+  its enclosing span); a counter event (ph == "C", the check's
+  program counters) has a name, a non-negative ts and integer args;
 - the heartbeat's final depth equals the run's reported depth and its
   status is "finished".
 
@@ -52,6 +53,12 @@ def load_trace_events(path):
 
 def validate_spans(events):
     """catapult trace_event schema + proper nesting."""
+    counters = [ev for ev in events if ev.get("ph") == "C"]
+    events = [ev for ev in events if ev.get("ph") != "C"]
+    for ev in counters:
+        if "name" not in ev or not ev.get("ts", -1) >= 0 or not all(
+                isinstance(v, int) for v in ev.get("args", {}).values()):
+            fail(f"malformed counter event: {ev}")
     if not events:
         fail("timeline has no span events")
     for ev in events:

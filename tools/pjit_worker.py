@@ -15,7 +15,7 @@ opts (all optional): {"max_depth": int, "chunk": int, "lcap": int,
                       "stop_on_violation": bool}
 resume_portable — a checkpoint path loaded through
 resil.portable.load_portable_image and re-partitioned onto this mesh
-(the round-12 contract: a mesh/classic checkpoint resumes at pod
+(the round-12 contract: a classic/spill checkpoint resumes at pod
 shape).  Caller must set
 XLA_FLAGS=--xla_force_host_platform_device_count=N and
 JAX_PLATFORMS=cpu before the interpreter starts.
